@@ -1,50 +1,32 @@
-"""JAX version-portability layer.
+"""The single import point for JAX's mesh, sharding and runtime APIs.
 
-Every use of a JAX API whose surface moved between 0.4.x and >= 0.5 goes
-through this module — callers never touch ``jax.sharding.AxisType``,
-``jax.shard_map``, ``AbstractMesh`` or ``jax.make_mesh`` directly. Policy
-(also recorded in ROADMAP.md): the repo supports the *installed* JAX floor
-(0.4.37, pinned in this container) **and** the current >= 0.5 API; each
-shim resolves its implementation once at import time by inspecting the
-installed signature, so per-call overhead is a plain function call.
+Callers never touch ``jax.sharding.AxisType``, ``jax.shard_map``,
+``AbstractMesh``, ``jax.lax.axis_size`` or ``jax.distributed`` directly:
+they import the names below (dgolint DGL001 and DGL007 enforce it). The
+repo supports one JAX line, the installed one (0.9); when a release moves
+one of these APIs, the change lands here and nowhere else.
 
-Shims provided:
+Names provided:
 
-* ``shard_map(f, *, mesh, in_specs, out_specs, check_vma=False)`` —
-  resolves to top-level ``jax.shard_map`` when present (>= 0.5, kwarg
-  ``check_vma``; some intermediate releases keep ``check_rep``) or to
-  ``jax.experimental.shard_map.shard_map`` (0.4.x, kwarg ``check_rep``).
-* ``AxisType`` — re-export of ``jax.sharding.AxisType`` or a stand-in enum
-  with the same member names (0.4.x meshes have no axis types; the shim
-  lets call sites pass them unconditionally).
-* ``make_mesh(axis_shapes, axis_names, *, axis_types=None)`` — drops the
-  ``axis_types`` kwarg on JAX versions whose ``jax.make_mesh`` lacks it.
-* ``abstract_mesh(axis_shapes, axis_names)`` — ``AbstractMesh`` grew a
-  positional-signature change (0.4.x wants one ``((name, size), ...)``
-  tuple; >= 0.5 wants ``(sizes, names)``).
-* ``mesh_from_devices(devices, axis_names, *, axis_types=None)`` — the
-  ``Mesh(devices, names, axis_types=...)`` constructor kwarg, dropped when
-  unsupported.
+* ``shard_map(f, *, mesh, in_specs, out_specs, check_vma=False)``;
+* ``AxisType``, ``axis_size``;
+* ``make_mesh(axis_shapes, axis_names, *, axis_types=None, devices=None)``;
+* ``mesh_from_devices(devices, axis_names, *, axis_types=None)``;
+* ``abstract_mesh(axis_shapes, axis_names)``;
+* ``pure_callback(callback, result_shape_dtypes, *args)`` with
+  element-at-a-time vmap semantics;
 * ``distributed_initialize`` / ``process_index`` / ``process_count`` /
-  ``is_multiprocess`` — the multi-process runtime surface.  The names are
-  stable across both supported lines, but the CPU cross-process collectives
-  backend selection (``jax_cpu_collectives_implementation``) and the
-  ``initialize`` kwarg set are not; routing every call site through here
-  keeps the variance in one file (dgolint DGL007 enforces it, the same way
-  DGL001 does for the mesh/shard_map names above).
+  ``is_multiprocess`` — the multi-process runtime surface.
 """
 from __future__ import annotations
 
-import enum
-import inspect
 from typing import Any, Callable, Sequence
 
 import jax
-from jax.sharding import AbstractMesh, Mesh
+from jax.sharding import AbstractMesh, AxisType, Mesh
 
 __all__ = [
     "AxisType",
-    "HAS_NATIVE_AXIS_TYPE",
     "abstract_mesh",
     "axis_size",
     "distributed_initialize",
@@ -57,195 +39,60 @@ __all__ = [
     "shard_map",
 ]
 
-
-def _kwarg_names(fn: Callable[..., Any]) -> set[str]:
-    try:
-        return set(inspect.signature(fn).parameters)
-    except (TypeError, ValueError):  # builtins / C extensions: assume modern
-        return set()
-
-
-# ---------------------------------------------------------------------------
-# AxisType
-# ---------------------------------------------------------------------------
-
-try:
-    from jax.sharding import AxisType  # type: ignore[attr-defined]
-    HAS_NATIVE_AXIS_TYPE = True
-except ImportError:
-    HAS_NATIVE_AXIS_TYPE = False
-
-    class AxisType(enum.Enum):  # type: ignore[no-redef]
-        """Stand-in for ``jax.sharding.AxisType`` on JAX 0.4.x.
-
-        0.4.x meshes are untyped (everything behaves like ``Auto``); the
-        members exist so call sites can pass axis types unconditionally and
-        the mesh shims below can discard them.
-        """
-
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-
-
-# ---------------------------------------------------------------------------
-# shard_map
-# ---------------------------------------------------------------------------
-
-if hasattr(jax, "shard_map"):
-    _shard_map_impl = jax.shard_map
-else:  # JAX 0.4.x: experimental namespace only
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-_SHARD_MAP_KWARGS = _kwarg_names(_shard_map_impl)
-if "check_vma" in _SHARD_MAP_KWARGS:
-    _CHECK_KW = "check_vma"
-elif "check_rep" in _SHARD_MAP_KWARGS:
-    _CHECK_KW = "check_rep"
-else:
-    _CHECK_KW = None
+axis_size = jax.lax.axis_size
 
 
 def shard_map(f: Callable[..., Any], *, mesh, in_specs, out_specs,
               check_vma: bool = False):
-    """Version-portable ``shard_map``.
-
-    ``check_vma`` follows the modern spelling; it is translated to
-    ``check_rep`` on JAX versions that predate the rename (the semantics —
-    "verify outputs are replicated where out_specs claim" — are the same).
-    """
-    kwargs: dict[str, Any] = {}
-    if _CHECK_KW is not None:
-        kwargs[_CHECK_KW] = check_vma
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **kwargs)
+    """``jax.shard_map`` with the repo's default of no VMA checking."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
-if hasattr(jax.lax, "axis_size"):
-    axis_size = jax.lax.axis_size
-else:
-    def axis_size(axis_name) -> jax.Array:
-        """``jax.lax.axis_size`` for JAX versions that predate it.
-
-        ``psum(1)`` over the axis counts its participants; under shard_map
-        the collective folds to a compile-time constant.
-        """
-        return jax.lax.psum(1, axis_name)
-
-
-# ---------------------------------------------------------------------------
-# pure_callback
-# ---------------------------------------------------------------------------
-# 0.4.x batches callbacks under vmap via ``vectorized=False`` (loop per
-# element); >= 0.5 renames that contract to ``vmap_method="sequential"`` and
-# eventually removes ``vectorized``.  Resolve the spelling once.
-
-_PURE_CALLBACK_KWARGS = _kwarg_names(jax.pure_callback)
-if "vmap_method" in _PURE_CALLBACK_KWARGS:
-    def pure_callback(callback, result_shape_dtypes, *args):
-        """Version-portable ``jax.pure_callback`` with element-at-a-time
-        vmap semantics (the host callback only ever sees unbatched args)."""
-        return jax.pure_callback(callback, result_shape_dtypes, *args,
-                                 vmap_method="sequential")
-else:
-    def pure_callback(callback, result_shape_dtypes, *args):
-        """Version-portable ``jax.pure_callback`` with element-at-a-time
-        vmap semantics (the host callback only ever sees unbatched args)."""
-        return jax.pure_callback(callback, result_shape_dtypes, *args,
-                                 vectorized=False)
-
-
-# ---------------------------------------------------------------------------
-# mesh constructors
-# ---------------------------------------------------------------------------
-
-_MAKE_MESH_HAS_AXIS_TYPES = "axis_types" in _kwarg_names(jax.make_mesh)
-_MESH_HAS_AXIS_TYPES = "axis_types" in _kwarg_names(Mesh.__init__) or (
-    # 0.5+ exposes (*args, **kwargs) via a util wrapper; probe the doc'd attr
-    "axis_types" in getattr(Mesh, "__slots__", ())
-    or hasattr(Mesh, "_axis_types")
-)
+def pure_callback(callback, result_shape_dtypes, *args):
+    """``jax.pure_callback`` with element-at-a-time vmap semantics (the
+    host callback only ever sees unbatched args)."""
+    return jax.pure_callback(callback, result_shape_dtypes, *args,
+                             vmap_method="sequential")
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
-              axis_types: Sequence["AxisType"] | None = None,
+              axis_types: Sequence[AxisType] | None = None,
               devices=None) -> Mesh:
-    """``jax.make_mesh`` with ``axis_types`` dropped when unsupported."""
-    kwargs: dict[str, Any] = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if axis_types is not None and _MAKE_MESH_HAS_AXIS_TYPES:
-        kwargs["axis_types"] = tuple(axis_types)
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
+    """``jax.make_mesh`` (which caches: equal geometries give one mesh)."""
+    return jax.make_mesh(
+        tuple(axis_shapes), tuple(axis_names),
+        axis_types=None if axis_types is None else tuple(axis_types),
+        devices=devices)
 
 
 def mesh_from_devices(devices, axis_names: Sequence[str], *,
-                      axis_types: Sequence["AxisType"] | None = None) -> Mesh:
-    """``Mesh(device_array, names)`` with ``axis_types`` when supported."""
-    if axis_types is not None and _MESH_HAS_AXIS_TYPES:
-        try:
-            return Mesh(devices, tuple(axis_names),
-                        axis_types=tuple(axis_types))
-        except TypeError:
-            pass  # probe lied (wrapped __init__) — fall through
-    return Mesh(devices, tuple(axis_names))
-
-
-_ABSTRACT_MESH_PARAMS = list(inspect.signature(
-    AbstractMesh.__init__).parameters)
-# 0.4.x: __init__(self, shape_tuple, axis_types=None) with shape_tuple a
-# ((name, size), ...) tuple; >= 0.5: __init__(self, axis_sizes, axis_names, *,
-# axis_types=...).
-_ABSTRACT_MESH_LEGACY = (len(_ABSTRACT_MESH_PARAMS) >= 2
-                         and _ABSTRACT_MESH_PARAMS[1] == "shape_tuple")
+                      axis_types: Sequence[AxisType] | None = None) -> Mesh:
+    """``Mesh(device_array, names, axis_types=...)``."""
+    if axis_types is None:
+        return Mesh(devices, tuple(axis_names))
+    return Mesh(devices, tuple(axis_names), axis_types=tuple(axis_types))
 
 
 def abstract_mesh(axis_shapes: Sequence[int],
                   axis_names: Sequence[str]) -> AbstractMesh:
-    """Version-portable ``AbstractMesh`` constructor."""
-    if _ABSTRACT_MESH_LEGACY:
-        return AbstractMesh(tuple(zip(axis_names, axis_shapes)))
+    """``AbstractMesh(sizes, names)``."""
     return AbstractMesh(tuple(axis_shapes), tuple(axis_names))
 
 
 # ---------------------------------------------------------------------------
 # multi-process runtime
 # ---------------------------------------------------------------------------
-# ``jax.distributed.initialize`` and the process-topology queries keep their
-# names on both supported lines, but two things vary: which kwargs
-# ``initialize`` accepts, and how CPU cross-process collectives are enabled
-# (0.4.37 needs ``jax_cpu_collectives_implementation`` set to "gloo" before
-# the runtime comes up; newer lines rename/default it).  Resolve the kwarg
-# set once; treat the collectives knob as best-effort.
-
-_DIST_INIT_KWARGS = _kwarg_names(jax.distributed.initialize)
-
 
 def distributed_initialize(coordinator_address: str, num_processes: int,
-                           process_id: int, *,
-                           cpu_collectives: str | None = "gloo") -> None:
-    """Version-portable ``jax.distributed.initialize`` for CPU fleets.
-
-    Selects the ``cpu_collectives`` backend when the installed JAX exposes
-    the config option (required for cross-process CPU collectives on
-    0.4.x; a no-op where the option is absent or already defaulted), then
-    brings up the distributed runtime.  Must run before any computation —
-    device state is frozen at first use.
-    """
-    if cpu_collectives is not None:
-        try:
-            jax.config.update("jax_cpu_collectives_implementation",
-                              cpu_collectives)
-        except (AttributeError, ValueError):
-            pass  # option renamed/absent on this line: rely on its default
-    kwargs: dict[str, Any] = {
-        "coordinator_address": coordinator_address,
-        "num_processes": num_processes,
-        "process_id": process_id,
-    }
-    kwargs = {k: v for k, v in kwargs.items()
-              if not _DIST_INIT_KWARGS or k in _DIST_INIT_KWARGS}
-    jax.distributed.initialize(**kwargs)
+                           process_id: int) -> None:
+    """Bring up ``jax.distributed`` for a fleet whose coordinator address,
+    size and rank are all given, so JAX looks nothing up. Must run before
+    any computation — device state is frozen at first use. CPU fleets use
+    JAX's default cross-process collectives (gloo)."""
+    jax.distributed.initialize(coordinator_address=coordinator_address,
+                               num_processes=num_processes,
+                               process_id=process_id)
 
 
 def process_index() -> int:

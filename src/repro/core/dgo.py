@@ -47,7 +47,7 @@ import numpy as np
 
 from repro.core.cache import get_cache
 
-from repro.core.encoding import Encoding, decode
+from repro.core.encoding import Encoding, decode, decode_np
 from repro.core.population import (
     generate_population,
     schedule_tables,
@@ -538,7 +538,10 @@ def _sequential_result(f: Callable[[np.ndarray], float],
     structure of the paper's Fig. 6. Used as the speedup denominator.
 
     ``f`` follows the host convention ``np.ndarray -> float`` (the solver
-    facade adapts jax objectives via ``Problem.host_fn``).  ``max_iters``
+    facade adapts jax objectives via ``Problem.host_fn``).  Children are
+    decoded onto the same float32 lattice as every engine
+    (``encoding.decode_np``), so the reference and the engines evaluate
+    the same points.  ``max_iters``
     caps TOTAL iterations across the whole resolution schedule — the same
     runaway guard the device engines carry.
     """
@@ -552,12 +555,6 @@ def _sequential_result(f: Callable[[np.ndarray], float],
     def np_g2b(g):
         return np.cumsum(g) % 2
 
-    def np_decode(b, enc):
-        lv = b.reshape(enc.n_vars, enc.bits)
-        weights = 2 ** np.arange(enc.bits - 1, -1, -1)
-        level = (lv * weights).sum(axis=-1).astype(np.float64)
-        return enc.lo + level * ((enc.hi - enc.lo) / (enc.levels - 1))
-
     def np_encode(x, enc):
         level = np.clip(np.round((x - enc.lo) / (enc.hi - enc.lo)
                                  * (enc.levels - 1)), 0, enc.levels - 1)
@@ -567,7 +564,7 @@ def _sequential_result(f: Callable[[np.ndarray], float],
 
     t_start = time.perf_counter()
     bits = np_encode(np.asarray(x0, np.float64), enc0)
-    val = float(f(np_decode(bits, enc0)))
+    val = float(f(decode_np(bits, enc0)))
     evals, iters = 1, 0
     trace = [val]
     best_run_val, best_run_bits, best_run_enc = val, bits, enc0
@@ -576,8 +573,8 @@ def _sequential_result(f: Callable[[np.ndarray], float],
     for res in cfg.resolutions():
         enc = enc0.with_bits(res)
         if enc.bits != prev_enc.bits:
-            bits = np_encode(np_decode(bits, prev_enc), enc)
-            val = float(f(np_decode(bits, enc)))
+            bits = np_encode(decode_np(bits, prev_enc), enc)
+            val = float(f(decode_np(bits, enc)))
         n = enc.n_bits
         table = segment_table(n)
         improved = True
@@ -592,7 +589,7 @@ def _sequential_result(f: Callable[[np.ndarray], float],
                 mask = np.zeros(n, np.int8)
                 mask[table[c, 0]: table[c, 1]] = 1
                 child = np_g2b(gray ^ mask)       # O(N) transform
-                v = float(f(np_decode(child, enc)))
+                v = float(f(decode_np(child, enc)))
                 evals += 1
                 if v < best_val:
                     best_val, best_bits = v, child
@@ -615,7 +612,7 @@ def _sequential_result(f: Callable[[np.ndarray], float],
         if max_iters is not None and iters >= max_iters:
             break
 
-    return DGOResult(x=jnp.asarray(np_decode(best_run_bits, best_run_enc)),
+    return DGOResult(x=jnp.asarray(decode_np(best_run_bits, best_run_enc)),
                      value=jnp.float32(best_run_val),
                      bits=jnp.asarray(best_run_bits),
                      evaluations=evals, iterations=iters,

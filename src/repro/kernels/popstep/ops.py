@@ -4,7 +4,7 @@
 ``population_step_ids`` — an arbitrary id subset (the per-shard /
 virtual-processing path used by ``core.distributed``) -> (val, global id).
 
-Both handle Gray pre-encoding of the parent (O(N), once), segment-table
+Both handle the parent's per-variable levels (O(N), once), segment-table
 lookup, and padding the child count to the tile size; the per-child
 O(P*N + P*cost(f)) work runs fused in the kernel.
 
@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.encoding import Encoding, binary_to_gray, pack_bits
+from repro.core.encoding import Encoding
 from repro.core.population import segment_table
 from repro.kernels.popstep.kernel import popstep
 
@@ -54,6 +54,14 @@ def resolve_interpret(interpret: bool | None) -> bool:
     ``interpret=False`` explicitly only for single-tile populations on
     GPU, or after the fold is made associative-reduction-safe."""
     return backend() != "tpu" if interpret is None else interpret
+
+
+def _parent_levels(parent_bits: jax.Array, enc: Encoding) -> jax.Array:
+    """(N,) 0/1 parent -> (n_vars,) int32 levels, one MSB-first field each."""
+    b = parent_bits.reshape(enc.n_vars, enc.bits).astype(jnp.int32)
+    weights = jnp.left_shift(1, jnp.arange(enc.bits - 1, -1, -1,
+                                           dtype=jnp.int32))
+    return jnp.sum(b * weights, axis=-1, dtype=jnp.int32)
 
 
 def _tile(pop: int, tile_p: int) -> int:
@@ -215,7 +223,6 @@ def population_step(f_batch: Callable[[jax.Array], jax.Array],
     interpret = resolve_interpret(interpret)
     tile_p = _resolve_tile(tile_p, f_batch, enc, interpret)
     n = enc.n_bits
-    w = (n + 31) // 32
     pop = enc.population
     t = _tile(pop, tile_p)
     table = np.asarray(segment_table(n))
@@ -224,10 +231,9 @@ def population_step(f_batch: Callable[[jax.Array], jax.Array],
     ends = jnp.asarray(np.pad(table[:, 1], (0, pad)))
 
     f_tile, consts = _convert_objective(f_batch, t, enc.n_vars)
-    parent_gray = pack_bits(binary_to_gray(parent_bits), w)
-    return popstep(parent_gray, starts, ends, None, consts, f_tile=f_tile,
-                   n_bits=n, n_vars=enc.n_vars, bits=enc.bits,
-                   lo=enc.lo, hi=enc.hi, pop=pop, tile_p=t, n_words=w,
+    return popstep(_parent_levels(parent_bits, enc), starts, ends, None,
+                   consts, f_tile=f_tile, n_vars=enc.n_vars, bits=enc.bits,
+                   lo=enc.lo, hi=enc.hi, pop=pop, tile_p=t,
                    interpret=interpret)
 
 
@@ -247,7 +253,6 @@ def population_step_ids(f_batch: Callable[[jax.Array], jax.Array],
     interpret = resolve_interpret(interpret)
     tile_p = _resolve_tile(tile_p, f_batch, enc, interpret)
     n = enc.n_bits
-    w = (n + 31) // 32
     k = child_ids.shape[0]
     t = _tile(k, tile_p)
     pad = (-k) % t
@@ -259,9 +264,8 @@ def population_step_ids(f_batch: Callable[[jax.Array], jax.Array],
     ok = jnp.pad(ok, (0, pad))
 
     f_tile, consts = _convert_objective(f_batch, t, enc.n_vars)
-    parent_gray = pack_bits(binary_to_gray(parent_bits), w)
-    mn, row = popstep(parent_gray, starts, ends, ok, consts, f_tile=f_tile,
-                      n_bits=n, n_vars=enc.n_vars, bits=enc.bits,
-                      lo=enc.lo, hi=enc.hi, pop=k, tile_p=t, n_words=w,
+    mn, row = popstep(_parent_levels(parent_bits, enc), starts, ends, ok,
+                      consts, f_tile=f_tile, n_vars=enc.n_vars,
+                      bits=enc.bits, lo=enc.lo, hi=enc.hi, pop=k, tile_p=t,
                       interpret=interpret)
     return mn, ids[jnp.minimum(row, k - 1)]

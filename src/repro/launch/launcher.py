@@ -12,7 +12,9 @@ serve CLI, ``python -c ...``) so the target's interpreter starts clean::
 
 ``--devices N`` pins ``--xla_force_host_platform_device_count=N`` (a real
 N-device mesh of *virtual* CPU devices — they time-slice the physical
-cores, so this scales the topology, not the FLOPs; see docs/scaling.md).
+cores, so this scales the topology, not the FLOPs; see docs/scaling.md)
+and ``JAX_PLATFORMS=cpu``, as ``--processes`` does: a machine's chips
+are reached by one plain process, not through the launcher.
 ``--processes K`` additionally spawns K workers, each a JAX process in one
 ``jax.distributed`` fleet whose global mesh spans all ``K * N`` devices;
 workers bring the runtime up through ``repro.compat.distributed_initialize``
@@ -90,8 +92,14 @@ def build_env(base_env: dict | None = None, *, devices: int | None = None,
     when the probe finds nothing); the ``coordinator``/``num_processes``/
     ``process_id`` triple exports the worker-coordination variables for
     ``maybe_initialize_from_env``.
+
+    Virtual devices and fleets are CPU tools: either one pins
+    ``JAX_PLATFORMS=cpu``, so K workers (or a bench's per-size children)
+    never contend for an accelerator that one process at a time may hold.
     """
     env = dict(os.environ if base_env is None else base_env)
+    if devices is not None or coordinator is not None:
+        env["JAX_PLATFORMS"] = "cpu"
     if devices is not None:
         env["XLA_FLAGS"] = _set_device_flag(env.get("XLA_FLAGS", ""),
                                             devices)

@@ -62,6 +62,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import REGISTRY, get_arch, reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_model, lm_decode, lm_prefill
 
 
@@ -221,11 +222,13 @@ def _report(sched, problems, best: float, wall_s: float,
     return out
 
 
-def _run_serving_loop(args, problems, rps: float | None):
+def _run_serving_loop(args, problems, rps: float | None,
+                      n_requests: int | None = None):
     """One serving run: open loop at ``rps`` (Poisson arrivals for
     ``--duration`` seconds) or, with ``rps=None``, closed loop
-    (``restarts * waves`` requests up front).  Returns
-    ``(sched, handles, wall_s, submitted)``."""
+    (``n_requests`` requests up front, ``restarts * waves`` by default;
+    request ``i`` is ``problems[i % len(problems)]`` with seed
+    ``--seed + i``).  Returns ``(sched, handles, wall_s, submitted)``."""
     import numpy as np
 
     from repro.core.solver import SolveRequest
@@ -293,7 +296,9 @@ def _run_serving_loop(args, problems, rps: float | None):
                 arr.join()
             sched.drain()
         else:
-            for _ in range(args.restarts * args.waves):
+            if n_requests is None:
+                n_requests = args.restarts * args.waves
+            for _ in range(n_requests):
                 submit_next()
             sched.drain()
         wall_s = time.perf_counter() - t_start
@@ -383,6 +388,7 @@ def serve_dgo(args) -> None:
             row["saturated"] = wall_s > 1.15 * args.duration
             row["submitted"] = submitted
             sweep.append(row)
+        _exit_on_failures(args, sum(r["failed"] for r in sweep))
         unsat = [r["offered_rps"] for r in sweep if not r["saturated"]]
         achieved = [r["achieved_rps"] for r in sweep
                     if r["achieved_rps"] is not None]
@@ -404,10 +410,20 @@ def serve_dgo(args) -> None:
                 if h.done() and h.error is None), default=float("inf"))
     checkpoints = (_persist_winners(args.ckpt_dir, handles, submitted)
                    if args.ckpt_dir else None)
-    _report(sched, problems, best, wall_s, checkpoints)
+    out = _report(sched, problems, best, wall_s, checkpoints)
+    _exit_on_failures(args, out["failed"])
 
 
-def main():
+def _exit_on_failures(args, failed: int) -> None:
+    """A failed request is an error of the run unless faults were
+    injected on purpose (``--fault-rate`` / ``--fault-latency-rate``)."""
+    if failed and _make_fault_plan(args) is None:
+        raise SystemExit(f"serve: {failed} request(s) failed with no "
+                         f"fault injection asked for")
+
+
+def build_parser() -> argparse.ArgumentParser:
+    """The serve CLI's argument parser (LM decode and ``--dgo``)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None, choices=list(REGISTRY))
     ap.add_argument("--dgo", action="store_true",
@@ -485,7 +501,13 @@ def main():
     ap.add_argument("--gen-len", type=int, default=16)
     ap.add_argument("--waves", type=int, default=2)
     ap.add_argument("--seed", type=int, default=0)
+    return ap
+
+
+def main():
+    ap = build_parser()
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.dgo:
         serve_dgo(args)

@@ -100,6 +100,19 @@ def test_build_env_exports_fleet_triple():
     assert ENV_COORDINATOR not in build_env({}, tcmalloc=False)
 
 
+def test_build_env_pins_cpu_for_virtual_devices_and_fleets():
+    """Virtual devices and fleet workers never reach for an accelerator:
+    K processes contending for one chip would fail or hang."""
+    tpu = {"JAX_PLATFORMS": "tpu"}
+    assert build_env(tpu, devices=4, tcmalloc=False)["JAX_PLATFORMS"] == "cpu"
+    fleet = build_env(tpu, tcmalloc=False, coordinator="127.0.0.1:9",
+                      num_processes=2, process_id=0)
+    assert fleet["JAX_PLATFORMS"] == "cpu"
+    # neither asked for: the platform choice is left alone
+    assert build_env(tpu, tcmalloc=False)["JAX_PLATFORMS"] == "tpu"
+    assert "JAX_PLATFORMS" not in build_env({}, tcmalloc=False)
+
+
 # ---------------------------------------------------------------------------
 # target/payload handling + CLI validation
 # ---------------------------------------------------------------------------

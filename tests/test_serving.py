@@ -361,3 +361,68 @@ def test_unwritable_tile_cache_env_warns(monkeypatch, capsys, tmp_path):
     err = capsys.readouterr().err
     assert "REPRO_POPSTEP_TILE_CACHE" in err
     assert "re-tunes" in err and "dgolint" in err
+
+
+@pytest.mark.parametrize("fault_args,exits", [
+    ([], True),                          # a real failure fails the run
+    (["--fault-rate", "0.5"], False),    # asked-for chaos does not
+])
+def test_serve_dgo_exit_code_on_failed_requests(monkeypatch, capsys,
+                                                fault_args, exits):
+    """``serve --dgo`` reports ``"failed": N`` and exits non-zero for it,
+    unless a FaultPlan was asked for on the command line."""
+    import json
+
+    from repro.launch import serve
+    from repro.serving import scheduler as scheduler_mod
+
+    def dispatch_fails(*args, **kwargs):
+        raise RuntimeError("device lost")
+
+    monkeypatch.setattr(scheduler_mod.Scheduler, "warmup",
+                        lambda self, problems, max_iters=None: 0)
+    monkeypatch.setattr(scheduler_mod, "solve_many", dispatch_fails)
+    args = serve.build_parser().parse_args(
+        ["--dgo", "--problems", "quadratic:2", "--restarts", "2",
+         "--waves", "1", "--max-iters", "8", "--no-pipeline",
+         "--max-retries", "0", "--retry-backoff-s", "0", *fault_args])
+    if exits:
+        with pytest.raises(SystemExit) as err:
+            serve.serve_dgo(args)
+        assert err.value.code not in (0, None)
+    else:
+        serve.serve_dgo(args)
+    report = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert report["failed"] == 2 and report["completed"] == 0
+
+
+@pytest.mark.parametrize("from_env", [False, True])
+def test_enable_compile_cache_directory(monkeypatch, tmp_path, from_env):
+    """The persistent compile cache sits at a fixed ``<checkout>/.jax_cache``
+    unless ``JAX_COMPILATION_CACHE_DIR`` names one, which is left to JAX."""
+    from pathlib import Path
+
+    import jax
+
+    from repro.launch import compile_cache
+
+    root = Path(__file__).resolve().parents[1]
+    assert compile_cache.CHECKOUT_CACHE_DIR == root / ".jax_cache"
+    assert ".jax_cache/" in (root / ".gitignore").read_text().split()
+    saved = (jax.config.jax_compilation_cache_dir,
+             jax.config.jax_persistent_cache_min_compile_time_secs)
+    try:
+        if from_env:
+            want = str(tmp_path / "cache")
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", want)
+        else:
+            want = str(compile_cache.CHECKOUT_CACHE_DIR)
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        assert compile_cache.enable_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == (
+            saved[0] if from_env else want)
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+    finally:
+        jax.config.update("jax_compilation_cache_dir", saved[0])
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          saved[1])

@@ -61,9 +61,9 @@ _COMPAT_NAMES = {"shard_map", "AxisType", "AbstractMesh", "axis_size"}
 class CompatBypassRule(Rule):
     """Version-moved JAX APIs must be imported via ``repro.compat``.
 
-    ``shard_map``, ``AxisType``, ``AbstractMesh`` and ``axis_size`` all
-    changed homes between JAX 0.4.37 and >=0.5; the CI matrix only stays
-    green because every use goes through the shim.  Flags (a) any
+    ``shard_map``, ``AxisType``, ``AbstractMesh`` and ``axis_size`` have
+    all moved between JAX releases; routing every use through the one
+    module keeps the next move a one-file change.  Flags (a) any
     ``from jax... import <name>`` / ``import jax.experimental.shard_map``
     and (b) attribute chains rooted at ``jax`` ending in one of the
     names, everywhere except ``src/repro/compat.py``.
