@@ -1,0 +1,100 @@
+"""Every cell of BENCHMARK.json resolves by name to its files, and the file
+keeps to the benchmark's contract. Cells, mixes and metrics that later
+changes add are checked by these same tests."""
+import json
+import re
+import sys
+from pathlib import Path
+
+import pytest
+
+BENCH = Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(BENCH))
+
+import e2e  # noqa: E402
+import harness  # noqa: E402
+
+SPEC = harness.load_benchmark()
+CELLS = [w["name"] for w in SPEC["workloads"]]
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+WIDTH = re.compile(r"(hidden|intermediate|latent|state|projection|head)"
+                   r"|(_dim|_rank)$")
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_cell_resolves_to_its_files(cell):
+    c = harness.load_cell(cell)
+    assert c.config["name"] == c.workload["config"]
+    assert c.traffic["loop"] in ("open", "closed")
+    assert c.chips in (1, 4)
+    assert c.config["entry"] in ("serve",)
+    assert c.config["dtype"] == "float32"
+    assert set(c.config["limits"]) == {"f_gap", "x_gap", "iters_gap"}
+    assert any(m["name"] == "setup_s" for m in c.end_to_end)
+    assert len(c.end_to_end) >= 2 and c.per_layer
+    for m in c.end_to_end:
+        assert m["name"] in e2e.METRICS
+    for m in c.per_layer:
+        assert callable(harness.metric_reader(m["name"]))
+
+
+@pytest.mark.parametrize("metric", [m["name"] for m in SPEC["per_layer"]])
+def test_per_layer_metric_lists_cells_that_report_what_it_moves(metric):
+    [m] = [x for x in SPEC["per_layer"] if x["name"] == metric]
+    moves = [x for x in SPEC["end_to_end"] if x["name"] == m["moves"]]
+    assert moves, f"{metric} moves an unknown metric {m['moves']!r}"
+    for cell in m.get("workloads", CELLS):
+        assert cell in CELLS
+        assert harness.reports(moves[0], cell), \
+            f"{cell} does not report {m['moves']}, which {metric} moves"
+
+
+def test_contract_keys_names_and_limits():
+    assert set(SPEC) == {"command", "paths", "run_seconds", "configs",
+                         "workloads", "end_to_end", "per_layer"}
+    assert SPEC["command"][0] == "python3" and len(SPEC["command"]) <= 32
+    for p in SPEC["paths"]:
+        assert (BENCH.parent / p).is_dir()
+    assert 1 <= SPEC["run_seconds"] <= 51
+    assert isinstance(SPEC["run_seconds"], int)
+    keys = {"configs": {"name", "source", "file", "reduced", "why"},
+            "workloads": {"name", "config", "traffic", "chips", "why"}}
+    for group, want in keys.items():
+        for e in SPEC[group]:
+            assert set(e) == want
+            assert NAME.match(e["name"]) and len(e["why"]) <= 200
+    for e in SPEC["configs"]:
+        assert e["file"].startswith("bench/configs/")
+        assert len(e["source"]) <= 200 and not any(
+            WIDTH.search(k) for k in e["reduced"])
+        assert json.loads((BENCH.parent / e["file"]).read_text())[
+            "reduced"] == e["reduced"]
+    for e in SPEC["end_to_end"]:
+        assert set(e) - {"workloads"} == {"name", "unit", "better", "bound",
+                                          "source"}
+        assert e["source"] in ("host_clock", "device_trace")
+        assert 0.01 <= e["bound"] <= 0.25
+    for e in SPEC["per_layer"]:
+        assert set(e) - {"workloads"} == {"name", "unit", "better", "source",
+                                          "layer", "moves"}
+        assert e["source"] in ("device_trace", "program_span",
+                               "program_counter", "host_clock")
+    for e in SPEC["end_to_end"] + SPEC["per_layer"]:
+        assert NAME.match(e["name"]) and UNIT.match(e["unit"])
+        assert e["better"] in ("lower", "higher")
+    names = [e["name"] for g in ("end_to_end", "per_layer")
+             for e in SPEC[g]]
+    assert len(names) == len(set(names))
+    four = sum(w["chips"] == 4 for w in SPEC["workloads"])
+    assert four <= max(1, len(SPEC["workloads"]) // 2)
+    for e in SPEC["per_layer"]:
+        assert "\n" not in e["layer"] and len(e["layer"]) <= 200
+    assert (BENCH.parent / "BENCHMARK.json").stat().st_size <= 64 * 1024
+
+
+def test_a_missing_cell_or_file_is_an_error():
+    with pytest.raises(KeyError):
+        harness.load_cell("no-such-cell")
+    with pytest.raises(FileNotFoundError):
+        harness.metric_reader("no_such_metric")
