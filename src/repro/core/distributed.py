@@ -65,6 +65,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
+from repro import tracing
 from repro.compat import axis_size, process_index, shard_map
 from repro.core.cache import get_cache
 from repro.core.encoding import Encoding, decode
@@ -134,6 +135,16 @@ def _axis_prod(mesh: Mesh, axis_names: Sequence[str]) -> int:
     return n
 
 
+def _named(fn: Callable, name: str) -> Callable:
+    """``fn`` under ``name``: jit names its program ``jit_<name>``, which
+    is how a profile of the device finds the program after a refactor."""
+    def named(*args):
+        return fn(*args)
+
+    named.__name__ = named.__qualname__ = name
+    return named
+
+
 def _parent_vals(f: Callable[[jax.Array], jax.Array],
                  xs: jax.Array) -> jax.Array:
     """Evaluate lattice-snapped parents ``xs`` (R, n_vars) row by row
@@ -149,7 +160,8 @@ def _parent_vals(f: Callable[[jax.Array], jax.Array],
     cached executable makes ``vals0`` width-invariant by construction;
     the cost is R tiny dispatches once per wave, noise against the
     iteration loop."""
-    ev = _PARENT_EVALS.get(("parent_eval", f), lambda: jax.jit(f))
+    ev = _PARENT_EVALS.get(("parent_eval", f),
+                           lambda: jax.jit(_named(f, "dgo_parent_eval")))
     return jnp.stack([ev(x) for x in xs]).astype(jnp.float32)
 
 
@@ -820,15 +832,19 @@ def _build_shard_step_batched(f_batch: Callable[[jax.Array], jax.Array],
             valid = (ids < pop) & alive
             ids_c = jnp.minimum(ids, pop - 1)
             b = ids.shape[0]
-            children = jnp.bitwise_xor(parent_bits[:, None, :],
-                                       pat[ids_c][None])  # (R, b, N)
-            flat = children.reshape(n_restarts * b, -1).astype(jnp.float32)
-            xs = enc.lo + (flat @ wmat) * scale           # (R*b, n_vars)
-            vals = jnp.where(valid[None, :],
-                             f_batch(xs).reshape(n_restarts, b), jnp.inf)
-            v = jnp.min(vals, axis=1)                     # (R,)
-            gid = jnp.min(jnp.where(vals == v[:, None], ids_c[None], pop),
-                          axis=1)
+            with tracing.scope("children"):
+                children = jnp.bitwise_xor(parent_bits[:, None, :],
+                                           pat[ids_c][None])  # (R, b, N)
+                flat = children.reshape(n_restarts * b, -1)
+            with tracing.scope("decode"):
+                xs = enc.lo + (flat.astype(jnp.float32) @ wmat) * scale
+            with tracing.scope("evaluate"):
+                fx = f_batch(xs).reshape(n_restarts, b)   # (R, b)
+            with tracing.scope("select"):
+                vals = jnp.where(valid[None, :], fx, jnp.inf)
+                v = jnp.min(vals, axis=1)                 # (R,)
+                gid = jnp.min(jnp.where(vals == v[:, None], ids_c[None],
+                                        pop), axis=1)
             return v, gid
 
         def one_step(parent_bits: jax.Array,   # (R, N) int8
@@ -843,34 +859,37 @@ def _build_shard_step_batched(f_batch: Callable[[jax.Array], jax.Array],
                     best_val, best_id = carry  # (R,), (R,)
                     v, gid = local_best_block(
                         parent_bits, base + b * block + jnp.arange(block))
-                    better = jnp.logical_or(
-                        v < best_val, (v == best_val) & (gid < best_id))
-                    return (jnp.where(better, v, best_val),
-                            jnp.where(better, gid, best_id)), None
+                    with tracing.scope("select"):
+                        better = jnp.logical_or(
+                            v < best_val, (v == best_val) & (gid < best_id))
+                        return (jnp.where(better, v, best_val),
+                                jnp.where(better, gid, best_id)), None
 
                 init = (jnp.full((n_restarts,), jnp.inf, jnp.float32),
                         jnp.full((n_restarts,), pop, jnp.int32))
                 (local_val, local_id), _ = jax.lax.scan(
                     eval_block, init, jnp.arange(n_blocks))
 
-            # one packed gather for ALL R restarts (ids exact in f32, see
-            # the single-restart builder)
-            packed = jnp.stack([local_val, local_id.astype(jnp.float32)])
-            for ax in pop_axes:
-                packed = jax.lax.all_gather(packed, ax)
-            packed = packed.reshape(-1, 2, n_restarts)
-            all_vals = packed[:, 0, :]                    # (S, R)
-            all_ids = packed[:, 1, :].astype(jnp.int32)
-            win_val = jnp.min(all_vals, axis=0)           # (R,)
-            win_id = jnp.min(jnp.where(all_vals == win_val[None], all_ids,
-                                       pop), axis=0)
+            with tracing.scope("select"):
+                # one packed gather for ALL R restarts (ids exact in f32,
+                # see the single-restart builder)
+                packed = jnp.stack([local_val,
+                                    local_id.astype(jnp.float32)])
+                for ax in pop_axes:
+                    packed = jax.lax.all_gather(packed, ax)
+                packed = packed.reshape(-1, 2, n_restarts)
+                all_vals = packed[:, 0, :]                # (S, R)
+                all_ids = packed[:, 1, :].astype(jnp.int32)
+                win_val = jnp.min(all_vals, axis=0)       # (R,)
+                win_id = jnp.min(jnp.where(all_vals == win_val[None],
+                                           all_ids, pop), axis=0)
 
-            improved = win_val < parent_val               # (R,)
-            win_bits = jnp.bitwise_xor(
-                parent_bits, pat[jnp.minimum(win_id, pop - 1)])
-            new_bits = jnp.where(improved[:, None], win_bits,
-                                 parent_bits).astype(jnp.int8)
-            new_val = jnp.where(improved, win_val, parent_val)
+                improved = win_val < parent_val           # (R,)
+                win_bits = jnp.bitwise_xor(
+                    parent_bits, pat[jnp.minimum(win_id, pop - 1)])
+                new_bits = jnp.where(improved[:, None], win_bits,
+                                     parent_bits).astype(jnp.int8)
+                new_val = jnp.where(improved, win_val, parent_val)
             return new_bits, new_val, improved
 
         return one_step
@@ -896,7 +915,8 @@ def _build_shard_schedule_step_batched(
                      parent_val: jax.Array,    # (R,) f32
                      it: jax.Array,            # () i32 — rotation round
                      res_idx: jax.Array):      # () i32 — schedule position
-            pat = tables.patterns[res_idx]
+            with tracing.scope("children"):
+                pat = tables.patterns[res_idx]
             pop = tables.pop[res_idx]
             # dynamic per-resolution chunk: same live-population assignment
             # as the single-restart schedule step (see its comment)
@@ -909,15 +929,20 @@ def _build_shard_schedule_step_batched(
                 valid = (offs < chunk_r) & (ids < pop) & alive
                 ids_c = jnp.minimum(ids, p_max - 1)
                 b = offs.shape[0]
-                children = jnp.bitwise_xor(parent_bits[:, None, :],
-                                           pat[ids_c][None])  # (R, b, n_max)
-                flat = children.reshape(n_restarts * b, -1)
-                xs = tables.decode(flat, res_idx)
-                vals = jnp.where(valid[None, :],
-                                 f_batch(xs).reshape(n_restarts, b), jnp.inf)
-                v = jnp.min(vals, axis=1)                     # (R,)
-                gid = jnp.min(jnp.where(vals == v[:, None], ids_c[None],
-                                        p_max), axis=1)
+                with tracing.scope("children"):
+                    children = jnp.bitwise_xor(
+                        parent_bits[:, None, :],
+                        pat[ids_c][None])                 # (R, b, n_max)
+                    flat = children.reshape(n_restarts * b, -1)
+                with tracing.scope("decode"):
+                    xs = tables.decode(flat, res_idx)
+                with tracing.scope("evaluate"):
+                    fx = f_batch(xs).reshape(n_restarts, b)   # (R, b)
+                with tracing.scope("select"):
+                    vals = jnp.where(valid[None, :], fx, jnp.inf)
+                    v = jnp.min(vals, axis=1)                 # (R,)
+                    gid = jnp.min(jnp.where(vals == v[:, None], ids_c[None],
+                                            p_max), axis=1)
                 return v, gid
 
             if n_blocks == 1:
@@ -926,32 +951,35 @@ def _build_shard_schedule_step_batched(
                 def eval_block(carry, b):
                     best_val, best_id = carry  # (R,), (R,)
                     v, gid = local_best_block(b * block + jnp.arange(block))
-                    better = jnp.logical_or(
-                        v < best_val, (v == best_val) & (gid < best_id))
-                    return (jnp.where(better, v, best_val),
-                            jnp.where(better, gid, best_id)), None
+                    with tracing.scope("select"):
+                        better = jnp.logical_or(
+                            v < best_val, (v == best_val) & (gid < best_id))
+                        return (jnp.where(better, v, best_val),
+                                jnp.where(better, gid, best_id)), None
 
                 init = (jnp.full((n_restarts,), jnp.inf, jnp.float32),
                         jnp.full((n_restarts,), p_max, jnp.int32))
                 (local_val, local_id), _ = jax.lax.scan(
                     eval_block, init, jnp.arange(n_blocks))
 
-            packed = jnp.stack([local_val, local_id.astype(jnp.float32)])
-            for ax in pop_axes:
-                packed = jax.lax.all_gather(packed, ax)
-            packed = packed.reshape(-1, 2, n_restarts)
-            all_vals = packed[:, 0, :]                        # (S, R)
-            all_ids = packed[:, 1, :].astype(jnp.int32)
-            win_val = jnp.min(all_vals, axis=0)               # (R,)
-            win_id = jnp.min(jnp.where(all_vals == win_val[None], all_ids,
-                                       p_max), axis=0)
+            with tracing.scope("select"):
+                packed = jnp.stack([local_val,
+                                    local_id.astype(jnp.float32)])
+                for ax in pop_axes:
+                    packed = jax.lax.all_gather(packed, ax)
+                packed = packed.reshape(-1, 2, n_restarts)
+                all_vals = packed[:, 0, :]                    # (S, R)
+                all_ids = packed[:, 1, :].astype(jnp.int32)
+                win_val = jnp.min(all_vals, axis=0)           # (R,)
+                win_id = jnp.min(jnp.where(all_vals == win_val[None],
+                                           all_ids, p_max), axis=0)
 
-            improved = win_val < parent_val                   # (R,)
-            win_bits = jnp.bitwise_xor(
-                parent_bits, pat[jnp.minimum(win_id, p_max - 1)])
-            new_bits = jnp.where(improved[:, None], win_bits,
-                                 parent_bits).astype(jnp.int8)
-            new_val = jnp.where(improved, win_val, parent_val)
+                improved = win_val < parent_val               # (R,)
+                win_bits = jnp.bitwise_xor(
+                    parent_bits, pat[jnp.minimum(win_id, p_max - 1)])
+                new_bits = jnp.where(improved[:, None], win_bits,
+                                     parent_bits).astype(jnp.int8)
+                new_val = jnp.where(improved, win_val, parent_val)
             return new_bits, new_val, improved
 
         return one_step
@@ -1047,37 +1075,44 @@ def make_distributed_engine_batched(
                  stalls, it_in_res, pos, trace) = s
                 live = live_of(stalls, it_in_res)            # (R,)
                 nb, nv, improved = one_step(bits, vals, it_in_res, res_idx)
-                bits = jnp.where(live[:, None], nb, bits)
-                vals = jnp.where(live, nv, vals)
-                pos = pos + live.astype(jnp.int32)
-                trace = trace.at[rows, jnp.clip(pos, 0, t_max - 1)].set(vals)
-                stalls = jnp.where(live & improved, 0,
-                                   stalls + live.astype(jnp.int32))
-                better = vals < best_vals
-                best_vals = jnp.where(better, vals, best_vals)
-                best_bits = jnp.where(better[:, None], bits, best_bits)
-                best_res = jnp.where(better, res_idx, best_res)
+                with tracing.scope("select"):
+                    bits = jnp.where(live[:, None], nb, bits)
+                    vals = jnp.where(live, nv, vals)
+                with tracing.scope("trace"):
+                    pos = pos + live.astype(jnp.int32)
+                    trace = trace.at[rows, jnp.clip(pos, 0, t_max - 1)].set(
+                        vals)
+                with tracing.scope("select"):
+                    stalls = jnp.where(live & improved, 0,
+                                       stalls + live.astype(jnp.int32))
+                    better = vals < best_vals
+                    best_vals = jnp.where(better, vals, best_vals)
+                    best_bits = jnp.where(better[:, None], bits, best_bits)
+                    best_res = jnp.where(better, res_idx, best_res)
                 return (res_idx, bits, vals, best_vals, best_bits,
                         best_res, stalls, it_in_res + 1, pos, trace)
 
             def escalate(s):
                 (res_idx, bits, vals, best_vals, best_bits, best_res,
                  stalls, it_in_res, pos, trace) = s
-                nxt = jnp.minimum(res_idx + 1, n_res - 1)
-                bits2 = tables.reencode(bits, res_idx, nxt)  # paper step 5
-                vals2 = f_batch(tables.decode(bits2, nxt)).astype(
-                    jnp.float32)
-                better = vals2 < best_vals
-                best_vals = jnp.where(better, vals2, best_vals)
-                best_bits = jnp.where(better[:, None], bits2, best_bits)
-                best_res = jnp.where(better, nxt, best_res)
-                return (nxt, bits2, vals2, best_vals, best_bits, best_res,
-                        jnp.zeros_like(stalls), jnp.int32(0), pos, trace)
+                with tracing.scope("escalate"):
+                    nxt = jnp.minimum(res_idx + 1, n_res - 1)
+                    bits2 = tables.reencode(bits, res_idx, nxt)  # step 5
+                    vals2 = f_batch(tables.decode(bits2, nxt)).astype(
+                        jnp.float32)
+                    better = vals2 < best_vals
+                    best_vals = jnp.where(better, vals2, best_vals)
+                    best_bits = jnp.where(better[:, None], bits2, best_bits)
+                    best_res = jnp.where(better, nxt, best_res)
+                    return (nxt, bits2, vals2, best_vals, best_bits,
+                            best_res, jnp.zeros_like(stalls), jnp.int32(0),
+                            pos, trace)
 
             def body(s):
                 return jax.lax.cond(res_done(s), escalate, iterate, s)
 
-            trace0 = jnp.tile(vals0[:, None], (1, t_max))
+            with tracing.scope("trace"):
+                trace0 = jnp.tile(vals0[:, None], (1, t_max))
             s0 = (jnp.int32(0), bits0, vals0, vals0, bits0,
                   jnp.zeros((n_restarts,), jnp.int32),
                   jnp.zeros((n_restarts,), jnp.int32), jnp.int32(0),
@@ -1085,8 +1120,9 @@ def make_distributed_engine_batched(
             s = jax.lax.while_loop(cond, body, s0)
             (_, bits, vals, best_vals, best_bits, best_res, _, _, pos,
              trace) = s
-            idx = jnp.arange(t_max)[None, :]
-            trace = jnp.where(idx <= pos[:, None], trace, vals[:, None])
+            with tracing.scope("trace"):
+                idx = jnp.arange(t_max)[None, :]
+                trace = jnp.where(idx <= pos[:, None], trace, vals[:, None])
             return bits, vals, best_vals, best_bits, best_res, pos, trace
 
         replicated = P()
@@ -1095,7 +1131,7 @@ def make_distributed_engine_batched(
             in_specs=(replicated,) * 5,
             out_specs=(replicated,) * 7,
             check_vma=False)
-        return jax.jit(mapped)
+        return jax.jit(_named(mapped, "dgo_wave_engine"))
 
     plan = _shard_plan(enc.population, mesh, pop_axes, virtual_block)
     prepare = _build_shard_step_batched(f_batch, enc, plan, pop_axes,
@@ -1121,22 +1157,27 @@ def make_distributed_engine_batched(
             bits, vals, stalls, it, iters, trace = s
             live = live_of(stalls, iters)                 # (R,)
             nb, nv, improved = one_step(bits, vals, it)
-            bits = jnp.where(live[:, None], nb, bits)
-            vals = jnp.where(live, nv, vals)
-            iters = iters + live.astype(jnp.int32)
-            trace = trace.at[:, it + 1].set(
-                jnp.where(live, vals, trace[:, it]))
-            stalls = jnp.where(live & improved, 0,
-                               stalls + live.astype(jnp.int32))
+            with tracing.scope("select"):
+                bits = jnp.where(live[:, None], nb, bits)
+                vals = jnp.where(live, nv, vals)
+                iters = iters + live.astype(jnp.int32)
+            with tracing.scope("trace"):
+                trace = trace.at[:, it + 1].set(
+                    jnp.where(live, vals, trace[:, it]))
+            with tracing.scope("select"):
+                stalls = jnp.where(live & improved, 0,
+                                   stalls + live.astype(jnp.int32))
             return bits, vals, stalls, it + 1, iters, trace
 
-        trace0 = jnp.tile(vals0[:, None], (1, max_iters + 1))
+        with tracing.scope("trace"):
+            trace0 = jnp.tile(vals0[:, None], (1, max_iters + 1))
         s0 = (bits0, vals0,
               jnp.zeros((n_restarts,), jnp.int32), jnp.int32(0),
               jnp.zeros((n_restarts,), jnp.int32), trace0)
         bits, vals, _, _, iters, trace = jax.lax.while_loop(cond, body, s0)
-        idx = jnp.arange(max_iters + 1)[None, :]
-        trace = jnp.where(idx <= iters[:, None], trace, vals[:, None])
+        with tracing.scope("trace"):
+            idx = jnp.arange(max_iters + 1)[None, :]
+            trace = jnp.where(idx <= iters[:, None], trace, vals[:, None])
         return bits, vals, iters, trace
 
     replicated = P()
@@ -1145,7 +1186,7 @@ def make_distributed_engine_batched(
         in_specs=(replicated,) * 5,
         out_specs=(replicated,) * 4,
         check_vma=False)
-    return jax.jit(mapped)
+    return jax.jit(_named(mapped, "dgo_wave_engine"))
 
 
 class BatchedResult(NamedTuple):
@@ -1178,23 +1219,31 @@ def _prefetch(*arrs) -> None:
 class PendingBatched:
     """One in-flight batched dispatch from :func:`_submit_batched`: the
     engine call has returned, but its device arrays may still be
-    computing.  :meth:`finish` blocks on the host fetch and runs the
-    post-processing that turns raw engine outputs into a
-    :class:`BatchedResult`.  The submit/finish split is the serving
-    pipeline's lever (``core.solver.submit_wave`` wraps it per wave):
-    the caller assembles and dispatches the NEXT wave while the device
-    still executes this one.
+    computing.  :meth:`finish` blocks on the host fetch (``fetch()``)
+    and runs the post-processing (``post(fetched)``) that turns raw
+    engine outputs into a :class:`BatchedResult`; afterwards ``fetch``
+    holds the fetch's ``(wall, thread-CPU)`` seconds.  The submit/finish
+    split is the serving pipeline's lever (``core.solver.submit_wave``
+    wraps it per wave): the caller assembles and dispatches the NEXT
+    wave while the device still executes this one.
     """
 
-    __slots__ = ("_finish",)
+    __slots__ = ("_fetch", "_post", "fetch")
 
-    def __init__(self, finish):
-        self._finish = finish
+    def __init__(self, fetch, post):
+        self._fetch = fetch
+        self._post = post
+        self.fetch = (0.0, 0.0)
 
     def finish(self) -> BatchedResult:
         """Block on the device results and assemble the result.  A
         device-side error surfaces here, at the fetch, not at submit."""
-        return self._finish()
+        start = tracing.now()
+        with tracing.span("finalize.fetch"):
+            fetched = self._fetch()
+        self.fetch = tracing.since(start)
+        with tracing.span("finalize.post"):
+            return self._post(fetched)
 
 
 def _run_batched(f: Callable[[jax.Array], jax.Array],
@@ -1275,38 +1324,48 @@ def _submit_batched(f: Callable[[jax.Array], jax.Array],
     # shared per-row executable — width-invariant, so a wave slot's
     # trace[0] is bitwise its per-request solve's (see _parent_vals)
     enc0 = enc.with_bits(schedule[0])
-    vals0 = _parent_vals(f, decode(encode(x0s, enc0), enc0))
+    with tracing.span("submit_wave.parent_vals"):
+        vals0 = _parent_vals(f, decode(encode(x0s, enc0), enc0))
     # request batches land on the (possibly process-spanning) mesh here:
     # one explicit replicated put per wave, shared by both schedule paths
-    x0s, vals0, quorum_mask, active, slot_iters = _place_inputs(
-        mesh, x0s, vals0, quorum_mask, active, slot_iters)
+    with tracing.span("submit_wave.place"):
+        x0s, vals0, quorum_mask, active, slot_iters = _place_inputs(
+            mesh, x0s, vals0, quorum_mask, active, slot_iters)
 
     if len(schedule) == 1:
-        engine = _batched_engine_for(f, enc0, mesh,
-                                     n_restarts, pop_axes, max_iters,
-                                     virtual_block)
-        bits, vals, iters, trace = engine(x0s, vals0, quorum_mask, active,
-                                          slot_iters)
-        _prefetch(iters, trace)
+        with tracing.span("submit_wave.engine"):
+            engine = _batched_engine_for(f, enc0, mesh,
+                                         n_restarts, pop_axes, max_iters,
+                                         virtual_block)
+            bits, vals, iters, trace = engine(x0s, vals0, quorum_mask,
+                                              active, slot_iters)
+            _prefetch(iters, trace)
 
-        def finish() -> BatchedResult:
-            iters_h, trace_np = jax.device_get((iters, trace))
+        def fetch():
+            return jax.device_get((iters, trace))
+
+        def post(fetched) -> BatchedResult:
+            iters_h, trace_np = fetched
             return BatchedResult(
                 bits=bits, values=vals, iterations=iters,
                 trace=trace_np[:, : int(iters_h.max()) + 1],
                 best=int(jnp.argmin(vals)))
-        return PendingBatched(finish)
+        return PendingBatched(fetch, post)
 
-    engine = _batched_engine_for(f, enc0, mesh,
-                                 n_restarts, pop_axes, max_iters,
-                                 virtual_block, res_bits=schedule)
-    (_, _, best_vals, best_bits, best_res, iters, trace) = engine(
-        x0s, vals0, quorum_mask, active, slot_iters)
-    _prefetch(iters, trace, best_bits, best_res, best_vals)
+    with tracing.span("submit_wave.engine"):
+        engine = _batched_engine_for(f, enc0, mesh,
+                                     n_restarts, pop_axes, max_iters,
+                                     virtual_block, res_bits=schedule)
+        (_, _, best_vals, best_bits, best_res, iters, trace) = engine(
+            x0s, vals0, quorum_mask, active, slot_iters)
+        _prefetch(iters, trace, best_bits, best_res, best_vals)
 
-    def finish() -> BatchedResult:
-        iters_h, trace_h, bits_h, res_h, vals_h, act_h = jax.device_get(
+    def fetch():
+        return jax.device_get(
             (iters, trace, best_bits, best_res, best_vals, active))
+
+    def post(fetched) -> BatchedResult:
+        iters_h, trace_h, bits_h, res_h, vals_h, act_h = fetched
 
         # per-restart monotone histories, truncated to the longest run
         # and padded past each restart's own end with its final best.
@@ -1332,4 +1391,4 @@ def _submit_batched(f: Callable[[jax.Array], jax.Array],
             bits=bits, values=jnp.asarray(vals_h, jnp.float32),
             iterations=iters, trace=mono,
             best=int(np.argmin(vals_h)), best_xs=best_xs)
-    return PendingBatched(finish)
+    return PendingBatched(fetch, post)

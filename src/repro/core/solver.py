@@ -45,6 +45,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.compat import AxisType, make_mesh, pure_callback
 from repro.core import objectives as objectives_registry
 from repro.core.cache import get_cache
@@ -877,6 +878,10 @@ class PendingWave:
     executes this one (``repro.serving.pipeline``).  Results are bitwise
     identical to a blocking :func:`solve_many` call — :meth:`finalize`
     IS the tail of ``solve_many``'s wave loop.
+
+    After :meth:`finalize`, ``fetch`` holds the ``(wall, thread-CPU)``
+    seconds it blocked on the device's results and ``host`` those of the
+    rest: result post-processing and per-slot assembly.
     """
 
     def __init__(self, reqs, pending, enc0: Encoding, schedule: tuple,
@@ -888,25 +893,34 @@ class PendingWave:
         self._width = width
         self._on_nonfinite = on_nonfinite
         self._contexts = contexts
+        self.fetch = (0.0, 0.0)
+        self.host = (0.0, 0.0)
 
     def finalize(self) -> list[SolveResult]:
         """Block on the device results and assemble one
         :class:`SolveResult` per (active) request.  Raises whatever the
         dispatch raised — a device-side error surfaces HERE, at the
         fetch, not at submit."""
+        start = tracing.now()
         res = self._pending.finish()
-        # one host fetch per wave-level array, not one per slot
-        bits_h = (None if res.best_xs is not None
-                  else jax.device_get(res.bits))
-        iters_h = np.asarray(res.iterations)
-        out: list[SolveResult] = []
-        for slot, req in enumerate(self._reqs):
-            result = _slot_result(res, bits_h, iters_h, slot, self._enc0,
-                                  self._schedule, self._width)
-            if req.problem.signature is not None:
-                result.extras["problem_signature"] = req.problem.signature
-            out.append(_apply_result_hygiene(
-                result, self._on_nonfinite, self._contexts[slot]))
+        with tracing.span("finalize.assemble"):
+            # one host fetch per wave-level array, not one per slot
+            bits_h = (None if res.best_xs is not None
+                      else jax.device_get(res.bits))
+            iters_h = np.asarray(res.iterations)
+            out: list[SolveResult] = []
+            for slot, req in enumerate(self._reqs):
+                result = _slot_result(res, bits_h, iters_h, slot,
+                                      self._enc0, self._schedule,
+                                      self._width)
+                if req.problem.signature is not None:
+                    result.extras["problem_signature"] = \
+                        req.problem.signature
+                out.append(_apply_result_hygiene(
+                    result, self._on_nonfinite, self._contexts[slot]))
+        total = tracing.since(start)
+        self.fetch = self._pending.fetch
+        self.host = (total[0] - self.fetch[0], total[1] - self.fetch[1])
         return out
 
 
@@ -929,39 +943,43 @@ def submit_wave(requests, *, mesh=None, pop_axes=("data",),
     """
     from repro.core import distributed
 
-    reqs = [_as_request(r) for r in requests]
-    if not reqs:
-        raise ValueError("submit_wave needs at least one request")
-    mesh = resolve_mesh(mesh)
-    sigs = {engine_signature(req.problem, mesh=mesh, pop_axes=pop_axes,
-                             virtual_block=virtual_block,
-                             max_bits=max_bits, bits_step=bits_step)
-            for req in reqs}
-    if len(sigs) > 1:
-        raise ValueError(
-            f"submit_wave requests span {len(sigs)} engine signatures; "
-            f"one wave serves one signature (use solve_many to group)")
-    width = pad_to if pad_to is not None else len(reqs)
-    if width < len(reqs):
-        raise ValueError(f"pad_to={pad_to} smaller than the "
-                         f"{len(reqs)}-request wave")
-    prob: Problem = reqs[0].problem
-    schedule = tuple(_resolution_schedule(prob.encoding, max_bits,
-                                          bits_step))
-    enc0 = prob.encoding.with_bits(schedule[0])
-    x0s = [_request_x0(req.problem, req) for req in reqs]
-    caps = [req.max_iters if req.max_iters is not None
-            else _DEFAULT_REQUEST_ITERS for req in reqs]
-    n_pad = width - len(reqs)
-    if n_pad:                     # padding: clones of slot 0,
-        x0s += [x0s[0]] * n_pad   # masked inactive, zero budget
-        caps += [0] * n_pad
-    active = np.arange(width) < len(reqs)
-    # static cap sizes the trace buffer only (slots gate on their
-    # own cap); rounded up so cap mixes don't churn the compile key
-    cap = max(64, -(-max(caps) // 64) * 64)
+    with tracing.span("submit_wave.prepare"):
+        reqs = [_as_request(r) for r in requests]
+        if not reqs:
+            raise ValueError("submit_wave needs at least one request")
+        mesh = resolve_mesh(mesh)
+        sigs = {engine_signature(req.problem, mesh=mesh,
+                                 pop_axes=pop_axes,
+                                 virtual_block=virtual_block,
+                                 max_bits=max_bits, bits_step=bits_step)
+                for req in reqs}
+        if len(sigs) > 1:
+            raise ValueError(
+                f"submit_wave requests span {len(sigs)} engine "
+                f"signatures; one wave serves one signature (use "
+                f"solve_many to group)")
+        width = pad_to if pad_to is not None else len(reqs)
+        if width < len(reqs):
+            raise ValueError(f"pad_to={pad_to} smaller than the "
+                             f"{len(reqs)}-request wave")
+        prob: Problem = reqs[0].problem
+        schedule = tuple(_resolution_schedule(prob.encoding, max_bits,
+                                              bits_step))
+        enc0 = prob.encoding.with_bits(schedule[0])
+        x0s = [_request_x0(req.problem, req) for req in reqs]
+        caps = [req.max_iters if req.max_iters is not None
+                else _DEFAULT_REQUEST_ITERS for req in reqs]
+        n_pad = width - len(reqs)
+        if n_pad:                     # padding: clones of slot 0,
+            x0s += [x0s[0]] * n_pad   # masked inactive, zero budget
+            caps += [0] * n_pad
+        active = np.arange(width) < len(reqs)
+        # static cap sizes the trace buffer only (slots gate on their
+        # own cap); rounded up so cap mixes don't churn the compile key
+        cap = max(64, -(-max(caps) // 64) * 64)
+        x0 = jnp.stack(x0s)
     pending = distributed._submit_batched(
-        prob.jax_fn, enc0, mesh, jnp.stack(x0s),
+        prob.jax_fn, enc0, mesh, x0,
         pop_axes=tuple(pop_axes), max_iters=cap,
         virtual_block=virtual_block, quorum_mask=quorum_mask,
         res_bits=schedule, active=active, slot_iters=caps)

@@ -180,14 +180,27 @@ def _persist_winners(ckpt_dir: str, handles, submitted: int) -> list[str]:
     return paths
 
 
-def _report(sched, problems, best: float, wall_s: float,
+def _report(sched, problems, handles, wall_s: float,
             checkpoints: list[str] | None = None) -> dict:
     from repro.core import cache
+    from repro.serving.metrics import percentile
 
     m = sched.metrics()
+    answered = [h for h in handles if h.done() and h.error is None]
+    best = min((float(h.result().best_f) for h in answered),
+               default=float("inf"))
 
     def _ms(key):
         return round(m[key], 1) if m[key] is not None else None
+
+    def _per_wave_ms(key):
+        # host phase seconds per timed wave (the pipelined scheduler's;
+        # None on --no-pipeline, whose blocking waves time no phase)
+        return (round(1e3 * m[key] / m["timed_waves"], 2)
+                if m["timed_waves"] else None)
+
+    def _p95_ms(spans):
+        return round(1e3 * percentile(spans, 95), 1) if spans else None
 
     # engine caches only: memo tables (solver.problem) would otherwise
     # inflate "engines built"/"hits" by one per request spec/submission
@@ -207,7 +220,16 @@ def _report(sched, problems, best: float, wall_s: float,
         "latency_p50_ms": _ms("latency_p50_ms"),
         "latency_p95_ms": _ms("latency_p95_ms"),
         "latency_p99_ms": _ms("latency_p99_ms"),
+        # where a request's wait went: queued until its last pop, then
+        # from its wave's submission to its answer
+        "queue_wait_p95_ms": _p95_ms(
+            [h.popped_at - h.submitted_at for h in answered]),
+        "in_flight_p95_ms": _p95_ms(
+            [h.completed_at - h.dispatched_at for h in answered]),
         "waves": m["waves"],
+        "dispatch_ms_per_wave": _per_wave_ms("dispatch_s"),
+        "fetch_wait_ms_per_wave": _per_wave_ms("fetch_wait_s"),
+        "finalize_ms_per_wave": _per_wave_ms("finalize_host_s"),
         "bucket_fill": (round(m["fill_fraction"], 3)
                         if m["fill_fraction"] is not None else None),
         "cache_engines_built": eng["built"],
@@ -373,10 +395,7 @@ def serve_dgo(args) -> None:
         for rps in points:
             sched, handles, wall_s, submitted = _run_serving_loop(
                 args, problems, rps)
-            best = min((float(h.result().best_f) for h in handles
-                        if h.done() and h.error is None),
-                       default=float("inf"))
-            row = _report(sched, problems, best, wall_s)
+            row = _report(sched, problems, handles, wall_s)
             row["rps"] = rps
             row["offered_rps"] = rps
             row["achieved_rps"] = row["runs_per_s"]
@@ -406,11 +425,9 @@ def serve_dgo(args) -> None:
 
     sched, handles, wall_s, submitted = _run_serving_loop(
         args, problems, args.rps)
-    best = min((float(h.result().best_f) for h in handles
-                if h.done() and h.error is None), default=float("inf"))
     checkpoints = (_persist_winners(args.ckpt_dir, handles, submitted)
                    if args.ckpt_dir else None)
-    out = _report(sched, problems, best, wall_s, checkpoints)
+    out = _report(sched, problems, handles, wall_s, checkpoints)
     _exit_on_failures(args, out["failed"])
 
 

@@ -1,6 +1,6 @@
-"""Serving observability: latency percentiles, wave/bucket counters, and
-the compile-cache snapshot — one ``snapshot()`` dict the CLI prints and
-tests assert on.
+"""Serving observability: latency percentiles, wave/bucket counters,
+per-wave host phase times, and the compile-cache snapshot — one
+``snapshot()`` dict the CLI prints and tests assert on.
 """
 from __future__ import annotations
 
@@ -32,6 +32,22 @@ def percentile(values, q: float) -> float:
 
 
 @dataclasses.dataclass
+class PhaseTime:
+    """One host phase of a wave, summed over the timed waves: wall
+    seconds, thread-CPU seconds (``time.thread_time``), and the largest
+    single wave's wall seconds."""
+
+    wall_s: float = 0.0
+    cpu_s: float = 0.0
+    max_s: float = 0.0
+
+    def add(self, wall_s: float, cpu_s: float) -> None:
+        self.wall_s += wall_s
+        self.cpu_s += cpu_s
+        self.max_s = max(self.max_s, wall_s)
+
+
+@dataclasses.dataclass
 class ServingMetrics:
     """Counters + latency samples for one scheduler's lifetime."""
 
@@ -45,7 +61,6 @@ class ServingMetrics:
     nonfinite: int = 0        # results flagged non-finite (extras["finite"])
     slots: int = 0          # total wave slots dispatched (active + padded)
     padded_slots: int = 0   # inactive padding slots
-    busy_s: float = 0.0     # wall seconds inside dispatches
     backoff_s: float = 0.0  # wall seconds slept waiting out retry backoff
     # pipeline depth accounting (record_inflight, one sample per wave
     # entering the dispatch stage): the synchronous scheduler always
@@ -54,19 +69,36 @@ class ServingMetrics:
     submitted_waves: int = 0   # successfully dispatched waves sampled
     overlapped_waves: int = 0  # submissions landing behind >= 1 in flight
     peak_in_flight: int = 0    # deepest observed in-flight depth
+    # host phase times of the successful waves the pipelined scheduler
+    # finalized (record_phases; the synchronous scheduler blocks inside
+    # solve_many and times no phase): ``dispatch`` from the start of the
+    # pop to submit_wave's return, ``fetch_wait`` the block on the
+    # device's results, ``finalize_host`` the result post-processing,
+    # per-slot assembly and handle completion
+    timed_waves: int = 0
+    dispatch: PhaseTime = dataclasses.field(default_factory=PhaseTime)
+    fetch_wait: PhaseTime = dataclasses.field(default_factory=PhaseTime)
+    finalize_host: PhaseTime = dataclasses.field(default_factory=PhaseTime)
 
     def __post_init__(self):
         self._latencies: deque[float] = deque(maxlen=LATENCY_WINDOW)
 
-    def record_wave(self, n_active: int, width: int, elapsed_s: float):
+    def record_wave(self, n_active: int, width: int):
         self.waves += 1
         self.slots += width
         self.padded_slots += width - n_active
-        self.busy_s += elapsed_s
 
-    def record_failed_wave(self, elapsed_s: float):
+    def record_phases(self, dispatch: tuple[float, float],
+                      fetch_wait: tuple[float, float],
+                      finalize_host: tuple[float, float]):
+        """One timed wave's ``(wall, thread-CPU)`` seconds per phase."""
+        self.timed_waves += 1
+        self.dispatch.add(*dispatch)
+        self.fetch_wait.add(*fetch_wait)
+        self.finalize_host.add(*finalize_host)
+
+    def record_failed_wave(self):
         self.failed_waves += 1
-        self.busy_s += elapsed_s
 
     def record_completion(self, latency_s: float):
         self.completed += 1
@@ -105,7 +137,7 @@ class ServingMetrics:
 
     def snapshot(self) -> dict:
         """Everything a serving endpoint reports: request/wave counters,
-        bucket fill, latency percentiles, throughput over busy time, and
+        bucket fill, per-wave host phase times, latency percentiles, and
         the compile-cache subsystem snapshot (``core.cache.snapshot()``)."""
         from repro.core import cache
 
@@ -123,10 +155,8 @@ class ServingMetrics:
             "padded_slots": self.padded_slots,
             "fill_fraction": ((self.slots - self.padded_slots) / self.slots
                               if self.slots else None),
-            "busy_s": self.busy_s,
             "backoff_s": self.backoff_s,
-            "runs_per_s": (self.completed / self.busy_s
-                           if self.busy_s > 0 else None),
+            "timed_waves": self.timed_waves,
             # pipeline health: how often submissions overlapped an
             # in-flight wave, and the deepest depth reached (1 == fully
             # synchronous; see record_inflight)
@@ -146,6 +176,11 @@ class ServingMetrics:
             # workload's signature diversity outgrew the engine cache
             "cache_evictions": cache_snap["totals"]["evictions"],
         }
+        for name in ("dispatch", "fetch_wait", "finalize_host"):
+            phase = getattr(self, name)
+            out[f"{name}_s"] = phase.wall_s
+            out[f"{name}_cpu_s"] = phase.cpu_s
+            out[f"{name}_max_s"] = phase.max_s
         # snapshot the deque first: a monitoring thread may poll while
         # the dispatch thread appends completions
         latencies = list(self._latencies)

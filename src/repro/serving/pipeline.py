@@ -44,9 +44,13 @@ fault-plan polls    ``FaultPlan`` decisions — pure functions of
                     ``(seed, kind, index-or-seq)`` with seqs assigned at
                     queue submit — stay deterministic under threading.
 ``metrics_``        split by counter: wave/completion/failure counters
-                    are written by whichever thread finalizes (worker on
-                    the pipelined path), bisect/backoff/inflight by the
-                    scheduler thread; each counter has one writer.
+                    and the phase times are written by whichever thread
+                    finalizes (worker on the pipelined path),
+                    bisect/backoff/inflight by the scheduler thread;
+                    each counter has one writer.
+handle stamps       ``popped_at``/``dispatched_at``/``wave``: scheduler
+                    thread only, before the wave reaches the worker;
+                    read once the handle is terminal.
 ``_thread``         scheduler (control) thread only, via
                     :meth:`start`/:meth:`close`.
 ==================  ====================================================
@@ -67,6 +71,7 @@ import threading
 import time
 from collections import deque
 
+from repro import tracing
 from repro.core.solver import submit_wave
 from repro.serving.scheduler import Scheduler
 
@@ -75,14 +80,17 @@ class _InFlight:
     """One submitted-but-unfinalized wave, queued for the worker in
     dispatch order."""
 
-    __slots__ = ("bucket", "width", "sig", "pending", "t0")
+    __slots__ = ("bucket", "width", "sig", "pending", "t0", "wave",
+                 "dispatch")
 
-    def __init__(self, bucket, width, sig, pending, t0):
+    def __init__(self, bucket, width, sig, pending, t0, wave, dispatch):
         self.bucket = bucket
         self.width = width
         self.sig = sig
         self.pending = pending
         self.t0 = t0
+        self.wave = wave            # dispatch index, the spans' wave id
+        self.dispatch = dispatch    # (wall, thread-CPU) s: pop + submit
 
 
 class PipelinedScheduler(Scheduler):
@@ -199,32 +207,40 @@ class PipelinedScheduler(Scheduler):
             prior = len(self._inflight)
             if prior >= self.max_in_flight:
                 return False
+        start = tracing.now()
         popped = self._next_bucket()
         if popped is None:
             return False
         bucket, width, sig = popped
         self._dispatches += 1
+        wave = self._dispatches
         seqs = frozenset(h.seq for h in bucket)
         t0 = time.perf_counter()
-        try:
-            if self.faults is not None:
-                self.faults.before_dispatch(self._dispatches, seqs)
-            if self.injector is not None:
-                self.injector.maybe_fail(self._dispatches)
-            pending = submit_wave(
-                [h.request for h in bucket], mesh=self.mesh,
-                pop_axes=self.pop_axes, virtual_block=self.virtual_block,
-                max_bits=self.max_bits, bits_step=self.bits_step,
-                pad_to=width)
-        except Exception as err:            # noqa: BLE001 — submit-side
-            # failures (fault plan, injector, tracing) are absorbed here
-            # on the scheduler thread; fetch-side ones on the worker
-            self.metrics_.record_failed_wave(time.perf_counter() - t0)
-            self._register_failure(sig, bucket, err)
-            return True
+        with tracing.span("dispatch", wave=wave, n=len(bucket),
+                          width=width):
+            try:
+                if self.faults is not None:
+                    self.faults.before_dispatch(wave, seqs)
+                if self.injector is not None:
+                    self.injector.maybe_fail(wave)
+                pending = submit_wave(
+                    [h.request for h in bucket], mesh=self.mesh,
+                    pop_axes=self.pop_axes,
+                    virtual_block=self.virtual_block,
+                    max_bits=self.max_bits, bits_step=self.bits_step,
+                    pad_to=width)
+            except Exception as err:        # noqa: BLE001 — submit-side
+                # failures (fault plan, injector, tracing) are absorbed
+                # here on the scheduler thread; fetch-side ones on the
+                # worker
+                self.metrics_.record_failed_wave()
+                self._register_failure(sig, bucket, err)
+                return True
+        dispatch = tracing.since(start)
+        self._stamp_dispatched(bucket, wave)
         with self._flight:
-            self._inflight.append(_InFlight(bucket, width, sig,
-                                            pending, t0))
+            self._inflight.append(_InFlight(bucket, width, sig, pending,
+                                            t0, wave, dispatch))
             self._flight.notify_all()
         self.metrics_.record_inflight(prior + 1)
         return True
@@ -295,21 +311,25 @@ class PipelinedScheduler(Scheduler):
 
     def _finalize(self, flight: _InFlight) -> None:
         """Block on one wave's device results and run the base class's
-        terminal bookkeeping (completion, retry/backoff/bisection)."""
-        try:
-            results = flight.pending.finalize()
-        except Exception as err:            # noqa: BLE001 — the serving
-            # loop survives any dispatch failure by requeueing its bucket
-            self.metrics_.record_failed_wave(
-                time.perf_counter() - flight.t0)
-            self._register_failure(flight.sig, flight.bucket, err)
-            return
-        # wave wall time spans submit -> results consumed; overlapped
-        # waves overlap their busy_s, so wall-clock throughput is the
-        # caller's (completed / wall), not completed / busy_s
-        elapsed = time.perf_counter() - flight.t0
-        self._note_success(flight.sig)      # the bucket recovered
-        self._complete_bucket(flight.bucket, results)
-        self.metrics_.record_wave(len(flight.bucket), flight.width,
-                                  elapsed)
+        terminal bookkeeping (completion, retry/backoff/bisection), then
+        record the wave's host phase times."""
+        with tracing.span("finalize", wave=flight.wave):
+            try:
+                results = flight.pending.finalize()
+            except Exception as err:        # noqa: BLE001 — the serving
+                # loop survives any dispatch failure by requeueing it
+                self.metrics_.record_failed_wave()
+                self._register_failure(flight.sig, flight.bucket, err)
+                return
+            # the straggler policy's wave time: submit -> results consumed
+            elapsed = time.perf_counter() - flight.t0
+            self._note_success(flight.sig)  # the bucket recovered
+            start = tracing.now()
+            self._complete_bucket(flight.bucket, results)
+            complete = tracing.since(start)
+        host = flight.pending.host
+        self.metrics_.record_wave(len(flight.bucket), flight.width)
+        self.metrics_.record_phases(
+            flight.dispatch, flight.pending.fetch,
+            (host[0] + complete[0], host[1] + complete[1]))
         self._note_dispatch_time(elapsed)

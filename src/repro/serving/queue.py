@@ -71,6 +71,14 @@ class RequestHandle:
     (None = no deadline); an expired handle fails with
     :class:`DeadlineExceeded` at the next pop — or inside ``result()``,
     whose wait never outlives the deadline.
+
+    Timestamps, all on ``time.perf_counter``: ``submitted_at``;
+    ``popped_at``, the scheduler's last pop of the request into a wave;
+    ``dispatched_at``, when that wave's submission returned (on the
+    blocking scheduler, when the whole wave returned); and
+    ``completed_at``. ``wave`` is the scheduler's dispatch index of that
+    wave, the ``wave`` id of its ``dgo.dispatch``/``dgo.finalize`` spans.
+    A requeued request carries its last pop and dispatch.
     """
 
     _UNSET = object()
@@ -79,6 +87,9 @@ class RequestHandle:
         self.request = request
         self.seq = seq
         self.submitted_at = time.perf_counter()
+        self.popped_at: float | None = None
+        self.dispatched_at: float | None = None
+        self.wave: int | None = None
         self.completed_at: float | None = None
         self.deadline_at: float | None = (
             None if request.deadline_s is None

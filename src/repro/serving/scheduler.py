@@ -49,6 +49,7 @@ from typing import Iterable, Sequence
 
 import numpy as np
 
+from repro import tracing
 from repro.core.solver import (
     NonFiniteResult, SolveRequest, engine_signature, solve_many,
 )
@@ -259,51 +260,65 @@ class Scheduler:
     def _next_bucket(self) -> tuple[list[RequestHandle], int, tuple] | None:
         """Pop + shape the next dispatchable bucket: skip backed-off
         signatures, apply the armed quarantine-probe limit (excess
-        members requeued), snap the width.  Returns
-        ``(bucket, width, sig)`` or None when nothing is poppable."""
-        now = time.perf_counter()
-        blocked = {sig for sig, (_, release)
-                   in self._backoff_snapshot().items() if release > now}
-        width = self.effective_wave_size()
-        bucket = self.queue.pop_bucket(width, key=self.signature,
-                                       token=self, exclude=blocked)
-        self._last_popped = bool(bucket)
-        if not bucket:
-            return None
-        sig = bucket[0].signature
-        limit = self._bisect_limit(sig)
-        if limit is not None and len(bucket) > limit:
-            # quarantine probe: retry only half of the failed bucket, so
-            # a poison request is isolated in at most log2(W) probes
-            for handle in bucket[limit:]:
-                self.queue.requeue(handle)
-            bucket = bucket[:limit]
-            width = self._snap_width(limit)
-            self.metrics_.record_bisect()
-        return bucket, width, sig
+        members requeued), snap the width, stamp the members'
+        ``popped_at``.  Returns ``(bucket, width, sig)`` or None when
+        nothing is poppable."""
+        with tracing.span("pop"):
+            now = time.perf_counter()
+            blocked = {sig for sig, (_, release)
+                       in self._backoff_snapshot().items() if release > now}
+            width = self.effective_wave_size()
+            bucket = self.queue.pop_bucket(width, key=self.signature,
+                                           token=self, exclude=blocked)
+            self._last_popped = bool(bucket)
+            if not bucket:
+                return None
+            popped_at = time.perf_counter()
+            sig = bucket[0].signature
+            limit = self._bisect_limit(sig)
+            if limit is not None and len(bucket) > limit:
+                # quarantine probe: retry only half of the failed bucket,
+                # so a poison request is isolated in at most log2(W) probes
+                for handle in bucket[limit:]:
+                    self.queue.requeue(handle)
+                bucket = bucket[:limit]
+                width = self._snap_width(limit)
+                self.metrics_.record_bisect()
+            for handle in bucket:
+                handle.popped_at = popped_at
+            return bucket, width, sig
+
+    def _stamp_dispatched(self, bucket: list[RequestHandle],
+                          wave: int) -> None:
+        """The bucket's wave ``wave`` was submitted: stamp its members."""
+        dispatched_at = time.perf_counter()
+        for handle in bucket:
+            handle.dispatched_at = dispatched_at
+            handle.wave = wave
 
     def _complete_bucket(self, bucket: list[RequestHandle],
                          results) -> int:
         """Terminal bookkeeping for one successful dispatch: apply the
         fault plan's result corruption, the per-handle non-finite policy,
         and complete the handles.  Returns the completion count."""
-        if self.faults is not None:
-            results = self.faults.corrupt_results(
-                [h.seq for h in bucket], results)
-        completed = 0
-        for handle, result in zip(bucket, results):
-            if not result.extras.get("finite", True):
-                self.metrics_.record_nonfinite()
-                if self.on_nonfinite == "raise":
-                    handle._fail(NonFiniteResult(
-                        f"request {handle.seq} produced a non-finite "
-                        f"result", result))
-                    self.metrics_.record_failure()
-                    continue
-            handle._complete(result)
-            self.metrics_.record_completion(handle.latency_s)
-            completed += 1
-        return completed
+        with tracing.span("complete"):
+            if self.faults is not None:
+                results = self.faults.corrupt_results(
+                    [h.seq for h in bucket], results)
+            completed = 0
+            for handle, result in zip(bucket, results):
+                if not result.extras.get("finite", True):
+                    self.metrics_.record_nonfinite()
+                    if self.on_nonfinite == "raise":
+                        handle._fail(NonFiniteResult(
+                            f"request {handle.seq} produced a non-finite "
+                            f"result", result))
+                        self.metrics_.record_failure()
+                        continue
+                handle._complete(result)
+                self.metrics_.record_completion(handle.latency_s)
+                completed += 1
+            return completed
 
     def run_wave(self) -> int:
         """Serve one signature bucket; returns the number of requests
@@ -314,27 +329,34 @@ class Scheduler:
             return 0
         bucket, width, sig = popped
         self._dispatches += 1
+        wave = self._dispatches
         seqs = frozenset(h.seq for h in bucket)
         t0 = time.perf_counter()
-        try:
-            if self.faults is not None:
-                self.faults.before_dispatch(self._dispatches, seqs)
-            if self.injector is not None:
-                self.injector.maybe_fail(self._dispatches)
-            results = solve_many(
-                [h.request for h in bucket], mesh=self.mesh,
-                pop_axes=self.pop_axes, virtual_block=self.virtual_block,
-                max_bits=self.max_bits, bits_step=self.bits_step,
-                pad_to=width)
-        except Exception as err:            # noqa: BLE001 — the serving
-            # loop survives any dispatch failure by requeueing its bucket
-            self.metrics_.record_failed_wave(time.perf_counter() - t0)
-            self._register_failure(sig, bucket, err)
-            return 0
+        # blocking: the wave's submission, fetch and assembly all nest
+        # inside this dispatch span (no dgo.finalize on this path)
+        with tracing.span("dispatch", wave=wave, n=len(bucket),
+                          width=width):
+            try:
+                if self.faults is not None:
+                    self.faults.before_dispatch(wave, seqs)
+                if self.injector is not None:
+                    self.injector.maybe_fail(wave)
+                results = solve_many(
+                    [h.request for h in bucket], mesh=self.mesh,
+                    pop_axes=self.pop_axes,
+                    virtual_block=self.virtual_block,
+                    max_bits=self.max_bits, bits_step=self.bits_step,
+                    pad_to=width)
+            except Exception as err:        # noqa: BLE001 — the serving
+                # loop survives any dispatch failure by requeueing it
+                self.metrics_.record_failed_wave()
+                self._register_failure(sig, bucket, err)
+                return 0
         elapsed = time.perf_counter() - t0
+        self._stamp_dispatched(bucket, wave)
         self._note_success(sig)             # the bucket recovered
         completed = self._complete_bucket(bucket, results)
-        self.metrics_.record_wave(len(bucket), width, elapsed)
+        self.metrics_.record_wave(len(bucket), width)
         self.metrics_.record_inflight(1)    # synchronous: depth always 1
         self._note_dispatch_time(elapsed)
         return completed

@@ -90,6 +90,8 @@ class _GatedPending:
     """A PendingWave stand-in whose finalize blocks on an Event, so the
     test controls exactly when the worker can retire a wave."""
 
+    fetch = host = (0.0, 0.0)       # PendingWave's phase times, untimed
+
     def __init__(self, reqs, pad_to, gate):
         self.reqs = reqs
         self.pad_to = pad_to

@@ -315,14 +315,30 @@ def test_percentile():
 
 def test_metrics_snapshot_shape():
     m = ServingMetrics()
-    m.record_wave(n_active=3, width=4, elapsed_s=0.5)
+    m.record_wave(n_active=3, width=4)
+    m.record_phases(dispatch=(0.02, 0.015), fetch_wait=(0.004, 0.001),
+                    finalize_host=(0.01, 0.008))
+    m.record_phases(dispatch=(0.03, 0.02), fetch_wait=(0.002, 0.0),
+                    finalize_host=(0.005, 0.005))
     m.record_completion(0.1)
     m.record_completion(0.3)
     snap = m.snapshot()
     assert snap["completed"] == 2
     assert snap["slots"] == 4 and snap["padded_slots"] == 1
     assert snap["fill_fraction"] == pytest.approx(0.75)
-    assert snap["runs_per_s"] == pytest.approx(4.0)
+    # per-wave host phases: wall and thread-CPU sums over the timed
+    # waves, and the largest single wave (throughput over "busy" time,
+    # a sum of overlapping waves, is gone)
+    assert "runs_per_s" not in snap and "busy_s" not in snap
+    assert snap["timed_waves"] == 2
+    assert snap["dispatch_s"] == pytest.approx(0.05)
+    assert snap["dispatch_cpu_s"] == pytest.approx(0.035)
+    assert snap["dispatch_max_s"] == pytest.approx(0.03)
+    assert snap["fetch_wait_s"] == pytest.approx(0.006)
+    assert snap["fetch_wait_max_s"] == pytest.approx(0.004)
+    assert snap["finalize_host_s"] == pytest.approx(0.015)
+    assert snap["finalize_host_cpu_s"] == pytest.approx(0.013)
+    assert snap["finalize_host_max_s"] == pytest.approx(0.01)
     assert snap["latency_p50_ms"] == pytest.approx(200.0)
     # the cache snapshot rides along for the serving endpoint
     assert set(snap["cache"]) == {"caches", "totals"}
