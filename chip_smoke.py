@@ -161,9 +161,7 @@ def serve_closed_loop(specs: str, n_per_spec: int, max_iters: int) -> list:
     problems = serve._parse_problem_specs(args)
     sched, handles, wall_s, submitted = serve._run_serving_loop(
         args, problems, None, n_requests=n_per_spec * len(problems))
-    best = min((float(h.result().best_f) for h in handles
-                if h.done() and h.error is None), default=float("inf"))
-    report = serve._report(sched, problems, best, wall_s)
+    report = serve._report(sched, problems, handles, wall_s)
     for h in handles:
         if h.error is not None:
             print(f"request {h.seq} failed: {h.error!r} "

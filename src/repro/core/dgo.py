@@ -47,7 +47,7 @@ import numpy as np
 
 from repro.core.cache import get_cache
 
-from repro.core.encoding import Encoding, decode, decode_np
+from repro.core.encoding import Encoding, decode, decode_np, encode_np
 from repro.core.population import (
     generate_population,
     schedule_tables,
@@ -555,15 +555,8 @@ def _sequential_result(f: Callable[[np.ndarray], float],
     def np_g2b(g):
         return np.cumsum(g) % 2
 
-    def np_encode(x, enc):
-        level = np.clip(np.round((x - enc.lo) / (enc.hi - enc.lo)
-                                 * (enc.levels - 1)), 0, enc.levels - 1)
-        level = level.astype(np.int64)
-        shifts = np.arange(enc.bits - 1, -1, -1)
-        return ((level[:, None] >> shifts) & 1).reshape(-1).astype(np.int8)
-
     t_start = time.perf_counter()
-    bits = np_encode(np.asarray(x0, np.float64), enc0)
+    bits = encode_np(np.asarray(x0, np.float64), enc0)
     val = float(f(decode_np(bits, enc0)))
     evals, iters = 1, 0
     trace = [val]
@@ -573,7 +566,7 @@ def _sequential_result(f: Callable[[np.ndarray], float],
     for res in cfg.resolutions():
         enc = enc0.with_bits(res)
         if enc.bits != prev_enc.bits:
-            bits = np_encode(decode_np(bits, prev_enc), enc)
+            bits = encode_np(decode_np(bits, prev_enc), enc)
             val = float(f(decode_np(bits, enc)))
         n = enc.n_bits
         table = segment_table(n)

@@ -68,7 +68,9 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro import tracing
 from repro.compat import axis_size, process_index, shard_map
 from repro.core.cache import get_cache
-from repro.core.encoding import Encoding, decode
+from repro.core.encoding import (
+    Encoding, decode, decode_np, encode, encode_np,
+)
 from repro.core.population import generate_children, segment_patterns
 from repro.kernels.popstep.ops import backend, population_step_ids
 
@@ -145,24 +147,29 @@ def _named(fn: Callable, name: str) -> Callable:
     return named
 
 
-def _parent_vals(f: Callable[[jax.Array], jax.Array],
-                 xs: jax.Array) -> jax.Array:
-    """Evaluate lattice-snapped parents ``xs`` (R, n_vars) row by row
-    through ONE shared jitted ``(n_vars,) -> ()`` executable.
+def _parent_values(f: Callable[[jax.Array], jax.Array], enc: Encoding,
+                   x0s: jax.Array, active: jax.Array) -> jax.Array:
+    """The objective at each start point of a wave, snapped to the lattice
+    of ``enc`` — traced inside the wave engine, under ``dgo.parent_eval``.
 
-    The batched engines' initial parent evaluation is the one objective
-    call whose batch width would otherwise follow the wave width R, and
-    XLA's fusion choices vary with batch width (batch-1 matvec paths), so
-    an in-engine ``f_batch(parents)`` at R=1 vs R=2 can drift by a ULP
-    for reduction-heavy objectives (the subspace-lm tuning family) —
-    breaking the serving contract that a wave slot is bitwise identical
-    to its per-request solve.  Evaluating every parent through the same
-    cached executable makes ``vals0`` width-invariant by construction;
-    the cost is R tiny dispatches once per wave, noise against the
-    iteration loop."""
-    ev = _PARENT_EVALS.get(("parent_eval", f),
-                           lambda: jax.jit(_named(f, "dgo_parent_eval")))
-    return jnp.stack([ev(x) for x in xs]).astype(jnp.float32)
+    The rows go through ``lax.map``, whose body is the objective at shape
+    ``(n_vars,)``: the one objective call of an engine that would otherwise
+    follow the wave width R.  XLA's fusion choices vary with batch width
+    (batch-1 matvec paths), so a batched ``f_batch(parents)`` at R=1 vs R=2
+    can drift by a ULP for reduction-heavy objectives (the subspace-lm
+    tuning family), breaking the serving contract that a wave slot is
+    bitwise its per-request solve.  Inactive (padding) rows skip the
+    objective and read +inf: their results are discarded."""
+    with tracing.scope("parent_eval"):
+        snapped = decode(encode(x0s, enc), enc)
+
+        def row(args):
+            x, live = args
+            return jax.lax.cond(
+                live, lambda x: jnp.asarray(f(x), jnp.float32),
+                lambda x: jnp.float32(jnp.inf), x)
+
+        return jax.lax.map(row, (snapped, active))
 
 
 class _ShardPlan(NamedTuple):
@@ -483,7 +490,6 @@ def make_distributed_engine(f_batch: Callable[[jax.Array], jax.Array],
     dispatch and ONE compilation.  The schedule path always uses the
     hoisted-pattern "fused" inner (``inner`` must be None or "fused").
     """
-    from repro.core.encoding import encode
     from repro.core.population import schedule_tables
 
     schedule = _resolve_res_bits(enc, res_bits)
@@ -622,11 +628,6 @@ def make_distributed_engine(f_batch: Callable[[jax.Array], jax.Array],
 # and hit/miss counters surface in BENCH_distributed.json
 _ENGINES = get_cache("distributed.engine")
 
-# the per-row initial-parent evaluators (_parent_vals) memoize separately:
-# they are not engine compilations, and the ".engine" suffix is how serving
-# reports/tests count engines built
-_PARENT_EVALS = get_cache("distributed.parent_eval")
-
 
 def _step_for(f, enc, mesh, pop_axes, virtual_block, inner, interpret,
               tile_p):
@@ -656,9 +657,9 @@ def _batched_engine_for(f, enc, mesh, n_restarts, pop_axes, max_iters,
     return _ENGINES.get(
         ("batched", f, enc, mesh, n_restarts, pop_axes, max_iters,
          virtual_block, res_bits),
-        lambda: make_distributed_engine_batched(jax.vmap(f), enc, mesh,
-                                                n_restarts, pop_axes,
-                                                max_iters, virtual_block,
+        lambda: make_distributed_engine_batched(f, enc, mesh, n_restarts,
+                                                pop_axes, max_iters,
+                                                virtual_block,
                                                 res_bits=res_bits))
 
 
@@ -668,7 +669,6 @@ def _run_fixed_resolution(f, enc, mesh, x0, pop_axes, max_iters,
     """One fixed-resolution distributed run at ``enc.bits``; returns
     ``(bits, val, history)`` — the per-resolution unit the host driver
     chains (the device driver folds the whole schedule instead)."""
-    from repro.core.encoding import encode
 
     n_shards = _axis_prod(mesh, pop_axes)
 
@@ -988,7 +988,7 @@ def _build_shard_schedule_step_batched(
 
 
 def make_distributed_engine_batched(
-        f_batch: Callable[[jax.Array], jax.Array],
+        f: Callable[[jax.Array], jax.Array],
         enc: Encoding,
         mesh: Mesh,
         n_restarts: int,
@@ -1009,13 +1009,13 @@ def make_distributed_engine_batched(
     wave, which is what lets the serving scheduler promise per-request
     results identical to individual solves.
 
-    The caller supplies ``vals0`` (R,) f32, the objective at each snapped
-    start point, evaluated OUTSIDE the engine through one shared per-row
-    executable (:func:`_parent_vals`) — in-engine evaluation would make
-    ``trace[0]`` depend on the compiled batch width.
+    ``f`` is the objective of one point, ``(n_vars,) -> ()``.  The engine
+    snaps each start point to the first lattice and evaluates it row by
+    row (:func:`_parent_values`), so ``trace[0]`` does not depend on the
+    compiled batch width; the population steps evaluate ``vmap(f)``.
 
     Fixed resolution (``res_bits`` None or a single entry): returns
-    ``engine(x0s (R, n_vars), vals0, quorum_mask, active, slot_iters) ->
+    ``engine(x0s (R, n_vars), quorum_mask, active, slot_iters) ->
     (bits (R,N), vals (R,), iters (R,), trace (R, max_iters+1))``.
     Restarts that stall (or hit their slot cap) stop mutating — their
     bits/val/trace freeze and their iteration counter stops — while the
@@ -1026,17 +1026,17 @@ def make_distributed_engine_batched(
     batch escalates in lockstep inside the same while_loop — when every
     active restart has stalled or hit its per-resolution slot cap (or the
     static per-resolution cap is hit), all restarts re-encode onto the
-    next lattice and resume.  Returns ``engine(x0s, vals0, quorum_mask,
-    active, slot_iters) -> (bits (R, n_max), vals (R,), best_vals (R,),
+    next lattice and resume.  Returns ``engine(x0s, quorum_mask, active,
+    slot_iters) -> (bits (R, n_max), vals (R,), best_vals (R,),
     best_bits (R, n_max), best_res (R,), iters (R,),
     trace (R, len(res_bits)*max_iters + 1))`` where ``best_*`` track each
     restart's best parent across resolutions and ``trace`` holds the raw
     per-iteration values (escalation re-encodes not recorded).  Still ONE
     compilation and ONE dispatch for the entire batch and schedule.
     """
-    from repro.core.encoding import encode
     from repro.core.population import schedule_tables
 
+    f_batch = jax.vmap(f)
     schedule = _resolve_res_bits(enc, res_bits)
     if len(schedule) > 1:
         tables = schedule_tables(enc.n_vars, schedule, enc.lo, enc.hi)
@@ -1048,10 +1048,11 @@ def make_distributed_engine_batched(
         t_max = n_res * max_iters + 1
         rows = jnp.arange(n_restarts)
 
-        def shard_schedule_engine(x0s, vals0, quorum_mask, active,
-                                  slot_iters):
+        def shard_schedule_engine(x0s, quorum_mask, active, slot_iters):
             r0 = jnp.int32(0)
             bits0 = tables.encode(x0s, r0)                   # (R, n_max)
+            vals0 = _parent_values(f, enc.with_bits(schedule[0]), x0s,
+                                   active)
             one_step = prepare(quorum_mask)
             stall_limit = jnp.where(jnp.all(quorum_mask), 1, n_shards)
 
@@ -1128,7 +1129,7 @@ def make_distributed_engine_batched(
         replicated = P()
         mapped = shard_map(
             shard_schedule_engine, mesh=mesh,
-            in_specs=(replicated,) * 5,
+            in_specs=(replicated,) * 4,
             out_specs=(replicated,) * 7,
             check_vma=False)
         return jax.jit(_named(mapped, "dgo_wave_engine"))
@@ -1139,8 +1140,9 @@ def make_distributed_engine_batched(
 
     n_shards = plan.n_shards
 
-    def shard_engine(x0s, vals0, quorum_mask, active, slot_iters):
+    def shard_engine(x0s, quorum_mask, active, slot_iters):
         bits0 = encode(x0s, enc)                          # (R, N)
+        vals0 = _parent_values(f, enc, x0s, active)
         one_step = prepare(quorum_mask)
         # same stall rule as the single-restart engine, per restart
         stall_limit = jnp.where(jnp.all(quorum_mask), 1, n_shards)
@@ -1183,20 +1185,21 @@ def make_distributed_engine_batched(
     replicated = P()
     mapped = shard_map(
         shard_engine, mesh=mesh,
-        in_specs=(replicated,) * 5,
+        in_specs=(replicated,) * 4,
         out_specs=(replicated,) * 4,
         check_vma=False)
     return jax.jit(_named(mapped, "dgo_wave_engine"))
 
 
 class BatchedResult(NamedTuple):
-    """Result of the batched engine (R concurrent restarts)."""
+    """Result of the batched engine (R concurrent restarts), on the host:
+    every field is a numpy array fetched in the wave's one transfer."""
 
-    bits: jax.Array        # (R, N) int8 — final-resolution string per restart
-    values: jax.Array      # (R,) f32 — best value per restart
-    iterations: jax.Array  # (R,) i32 — population steps taken, per restart
+    bits: np.ndarray       # (R, N) int8 — final-resolution string per restart
+    values: np.ndarray     # (R,) f32 — best value per restart
+    iterations: np.ndarray  # (R,) i32 — population steps taken, per restart
     trace: np.ndarray      # (R, T) f32 — monotone value history per restart
-    best: int              # index of the winning restart
+    best: int              # index of the winning (active) restart
     best_xs: np.ndarray | None = None   # (R, n_vars) — schedule path only:
     #                       each restart's best point at its own resolution
 
@@ -1214,6 +1217,12 @@ def _prefetch(*arrs) -> None:
             a.copy_to_host_async()
         except AttributeError:      # non-jax leaf / backend without
             pass                    # async transfers: finish() fetches
+
+
+def _best_active(vals: np.ndarray, active: np.ndarray) -> int:
+    """The winning restart among the active slots: padding slots read
+    +inf from the start and their results are discarded."""
+    return int(np.argmin(np.where(active, vals, np.inf)))
 
 
 class PendingBatched:
@@ -1268,7 +1277,7 @@ def _run_batched(f: Callable[[jax.Array], jax.Array],
 def _submit_batched(f: Callable[[jax.Array], jax.Array],
                     enc: Encoding,
                     mesh: Mesh,
-                    x0s: jax.Array,
+                    x0s,
                     pop_axes: Sequence[str] = ("data",),
                     max_iters: int = 256,
                     virtual_block: int = 256,
@@ -1292,80 +1301,64 @@ def _submit_batched(f: Callable[[jax.Array], jax.Array],
     concurrent requests amortize the per-iteration reduce and the dispatch
     to near single-run wall-clock (see benchmarks/bench_distributed.py).
 
-    Returns WITHOUT blocking: JAX dispatch is asynchronous, so the
-    engine call hands back in-flight device arrays and every host fetch
-    (plus the schedule path's history post-processing) is deferred to
-    ``PendingBatched.finish()``.
+    A wave touches the device twice: the engine call, which takes the
+    host arrays as they are and snaps and evaluates the start points
+    itself, and ONE ``device_get`` of every output the results need.
+    Everything between is numpy on the host.  Returns WITHOUT blocking:
+    JAX dispatch is asynchronous, so the engine call hands back in-flight
+    device arrays and the fetch (plus the schedule path's history
+    post-processing) is deferred to ``PendingBatched.finish()``.
     """
-    from repro.core.encoding import decode_np, encode
-
-    x0s = jnp.asarray(x0s, jnp.float32)
+    x0s = np.asarray(x0s, np.float32)
     if x0s.ndim != 2:
         raise ValueError(f"x0s must be (R, n_vars), got {x0s.shape}")
     n_restarts = x0s.shape[0]
     pop_axes = tuple(pop_axes)
     n_shards = _axis_prod(mesh, pop_axes)
-    if quorum_mask is None:
-        quorum_mask = jnp.ones((n_shards,), bool)
-    if active is None:
-        active = jnp.ones((n_restarts,), bool)
-    else:
-        active = jnp.asarray(active, bool)
-    if slot_iters is None:
-        slot_iters = jnp.full((n_restarts,), max_iters, jnp.int32)
-    else:
-        slot_iters = jnp.asarray(slot_iters, jnp.int32)
+    quorum_mask = (np.ones((n_shards,), bool) if quorum_mask is None
+                   else np.asarray(quorum_mask, bool))
+    active = (np.ones((n_restarts,), bool) if active is None
+              else np.asarray(active, bool))
+    slot_iters = (np.full((n_restarts,), max_iters, np.int32)
+                  if slot_iters is None
+                  else np.asarray(slot_iters, np.int32))
     if active.shape != (n_restarts,) or slot_iters.shape != (n_restarts,):
         raise ValueError(
             f"active/slot_iters must be ({n_restarts},), got "
             f"{active.shape}/{slot_iters.shape}")
     schedule = _resolve_res_bits(enc, res_bits)
-    # initial parent values, snapped to the starting lattice, via ONE
-    # shared per-row executable — width-invariant, so a wave slot's
-    # trace[0] is bitwise its per-request solve's (see _parent_vals)
     enc0 = enc.with_bits(schedule[0])
-    with tracing.span("submit_wave.parent_vals"):
-        vals0 = _parent_vals(f, decode(encode(x0s, enc0), enc0))
-    # request batches land on the (possibly process-spanning) mesh here:
-    # one explicit replicated put per wave, shared by both schedule paths
+    # process-spanning meshes need an explicit replicated put per wave;
+    # on one process jit transfers the host arrays itself
     with tracing.span("submit_wave.place"):
-        x0s, vals0, quorum_mask, active, slot_iters = _place_inputs(
-            mesh, x0s, vals0, quorum_mask, active, slot_iters)
+        args = _place_inputs(mesh, x0s, quorum_mask, active, slot_iters)
 
     if len(schedule) == 1:
         with tracing.span("submit_wave.engine"):
             engine = _batched_engine_for(f, enc0, mesh,
                                          n_restarts, pop_axes, max_iters,
                                          virtual_block)
-            bits, vals, iters, trace = engine(x0s, vals0, quorum_mask,
-                                              active, slot_iters)
-            _prefetch(iters, trace)
-
-        def fetch():
-            return jax.device_get((iters, trace))
+            outs = engine(*args)                 # bits, vals, iters, trace
+            _prefetch(*outs)
 
         def post(fetched) -> BatchedResult:
-            iters_h, trace_np = fetched
+            bits_h, vals_h, iters_h, trace_h = fetched
             return BatchedResult(
-                bits=bits, values=vals, iterations=iters,
-                trace=trace_np[:, : int(iters_h.max()) + 1],
-                best=int(jnp.argmin(vals)))
-        return PendingBatched(fetch, post)
+                bits=bits_h, values=vals_h, iterations=iters_h,
+                trace=trace_h[:, : int(iters_h.max()) + 1],
+                best=_best_active(vals_h, active))
+        return PendingBatched(lambda: jax.device_get(outs), post)
 
     with tracing.span("submit_wave.engine"):
         engine = _batched_engine_for(f, enc0, mesh,
                                      n_restarts, pop_axes, max_iters,
                                      virtual_block, res_bits=schedule)
-        (_, _, best_vals, best_bits, best_res, iters, trace) = engine(
-            x0s, vals0, quorum_mask, active, slot_iters)
-        _prefetch(iters, trace, best_bits, best_res, best_vals)
-
-    def fetch():
-        return jax.device_get(
-            (iters, trace, best_bits, best_res, best_vals, active))
+        # best_vals, best_bits, best_res, iters, trace
+        outs = engine(*args)[2:]
+        _prefetch(*outs)
 
     def post(fetched) -> BatchedResult:
-        iters_h, trace_h, bits_h, res_h, vals_h, act_h = fetched
+        vals_h, bits_h, res_h, iters_h, trace_h = fetched
 
         # per-restart monotone histories, truncated to the longest run
         # and padded past each restart's own end with its final best.
@@ -1375,7 +1368,7 @@ def _submit_batched(f: Callable[[jax.Array], jax.Array],
         t_len = int(iters_h.max()) + 1
         mono = np.repeat(trace_h[:, :1], t_len, axis=1)
         best_xs = np.zeros((n_restarts, enc.n_vars), np.float32)
-        for r in np.flatnonzero(act_h):
+        for r in np.flatnonzero(active):
             h = np.minimum.accumulate(trace_h[r, : int(iters_h[r]) + 1])
             mono[r, : len(h)] = h
             mono[r, len(h):] = h[-1]
@@ -1385,10 +1378,8 @@ def _submit_batched(f: Callable[[jax.Array], jax.Array],
             b = schedule[int(res_h[r])]
             best_xs[r] = decode_np(bits_h[r][: enc.n_vars * b],
                                    enc.with_bits(b))
-        enc_final = enc.with_bits(schedule[-1])
-        bits = encode(jnp.asarray(best_xs, jnp.float32), enc_final)
         return BatchedResult(
-            bits=bits, values=jnp.asarray(vals_h, jnp.float32),
-            iterations=iters, trace=mono,
-            best=int(np.argmin(vals_h)), best_xs=best_xs)
-    return PendingBatched(fetch, post)
+            bits=encode_np(best_xs, enc.with_bits(schedule[-1])),
+            values=vals_h, iterations=iters_h, trace=mono,
+            best=_best_active(vals_h, active), best_xs=best_xs)
+    return PendingBatched(lambda: jax.device_get(outs), post)

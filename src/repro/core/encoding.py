@@ -70,6 +70,19 @@ def decode(bits: jax.Array, enc: Encoding) -> jax.Array:
     return enc.lo + level * (span / (enc.levels - 1))
 
 
+def encode_np(x, enc: Encoding) -> np.ndarray:
+    """Numpy twin of :func:`encode` (bitwise equal on float32 inputs): the
+    same float32 arithmetic on the host, for results already fetched."""
+    x = np.asarray(x)
+    span = enc.hi - enc.lo
+    max_level = enc.levels - 1
+    level = np.round((x - enc.lo) / span * max_level)
+    level = np.clip(level, 0, max_level).astype(np.uint32)
+    shifts = np.arange(enc.bits - 1, -1, -1, dtype=np.uint32)
+    bits = (level[..., None] >> shifts) & np.uint32(1)
+    return bits.reshape(*x.shape[:-1], enc.n_bits).astype(np.int8)
+
+
 def decode_np(bits, enc: Encoding) -> np.ndarray:
     """Numpy twin of :func:`decode` for host-side result assembly (no op
     dispatch — the solver facade uses it on already-fetched bit strings)."""

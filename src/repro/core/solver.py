@@ -642,7 +642,7 @@ class Batched(Strategy):
         enc0 = problem.encoding
         if x0 is None:
             x0 = problem.random_x0(key, batch=self.restarts)
-        x0s = jnp.asarray(x0, jnp.float32)
+        x0s = np.asarray(x0, np.float32)
         if x0s.ndim != 2:
             raise ValueError(f"batched starts must be (R, n_vars), "
                              f"got {x0s.shape}")
@@ -657,18 +657,14 @@ class Batched(Strategy):
             max_iters=mi, virtual_block=self.virtual_block,
             quorum_mask=self.quorum_mask, res_bits=tuple(schedule))
         winner = res.best
-        if res.best_xs is not None:           # schedule path: best points
-            best_x = jnp.asarray(res.best_xs[winner])
-        else:                                 # fixed resolution: decode
-            best_x = jnp.asarray(
-                decode_np(jax.device_get(res.bits)[winner], enc0))
         return SolveResult(
-            best_x=best_x,
-            best_f=res.values[winner],
-            iterations=int(np.asarray(res.iterations).max()),
+            best_x=jnp.asarray(_best_x(res, winner, enc0)),
+            best_f=jnp.asarray(res.values[winner]),
+            iterations=int(res.iterations.max()),
             trace=res.trace[winner],
-            extras={"bits": res.bits, "values": res.values,
-                    "restart_iterations": res.iterations,
+            extras={"bits": jnp.asarray(res.bits),
+                    "values": jnp.asarray(res.values),
+                    "restart_iterations": jnp.asarray(res.iterations),
                     "trace": res.trace, "best": winner,
                     "schedule": tuple(schedule)})
 
@@ -832,31 +828,34 @@ def _check_request_x0(prob: Problem, x0) -> None:
             f"problem {prob.name!r}, got {shape}")
 
 
-def _request_x0(prob: Problem, req: SolveRequest) -> jax.Array:
-    """The request's start point — pinned, or the SAME seed-derived draw a
-    per-request ``solve(Batched(restarts=1), seed=...)`` would make."""
+def _request_x0(prob: Problem, req: SolveRequest) -> np.ndarray:
+    """The request's start point on the host — pinned, or the SAME
+    seed-derived draw a per-request ``solve(Batched(restarts=1),
+    seed=...)`` would make (the one device call a request can bring)."""
     if req.x0 is not None:
         _check_request_x0(prob, req.x0)
-        return jnp.asarray(req.x0, jnp.float32)
+        return np.asarray(req.x0, np.float32)
     key = jax.random.PRNGKey(int(req.seed))
-    return prob.random_x0(key, batch=1)[0]
+    return np.asarray(prob.random_x0(key, batch=1))[0]
 
 
-def _slot_result(res, bits_h, iters_h, slot: int, enc0: Encoding,
-                 schedule: tuple, wave_size: int) -> SolveResult:
+def _best_x(res, slot: int, enc0: Encoding) -> np.ndarray:
+    """A restart's best point: carried by the schedule path, decoded
+    from its final bits on the fixed-resolution path."""
+    if res.best_xs is not None:
+        return res.best_xs[slot]
+    return decode_np(res.bits[slot], enc0)
+
+
+def _slot_result(res, slot: int, enc0: Encoding, schedule: tuple,
+                 wave_size: int) -> SolveResult:
     """Per-slot SolveResult assembly — the same post-processing
     ``Batched._solve`` applies to its winner, applied to one slot, so a
-    bucketed request's result is bitwise the per-request one.  ``bits_h``
-    is the wave's bits fetched ONCE (None on the schedule path, which
-    carries decoded best points already); ``iters_h`` the wave's
-    iteration counters, also fetched once."""
-    if res.best_xs is not None:           # schedule path: best points
-        best_x = jnp.asarray(res.best_xs[slot])
-    else:                                 # fixed resolution: decode
-        best_x = jnp.asarray(decode_np(bits_h[slot], enc0))
-    iters = int(iters_h[slot])
+    bucketed request's result is bitwise the per-request one.  Every
+    field is a numpy slice of the wave's fetched arrays."""
+    iters = int(res.iterations[slot])
     return SolveResult(
-        best_x=best_x,
+        best_x=_best_x(res, slot, enc0),
         best_f=res.values[slot],
         iterations=iters,
         trace=res.trace[slot][: iters + 1],
@@ -904,15 +903,10 @@ class PendingWave:
         start = tracing.now()
         res = self._pending.finish()
         with tracing.span("finalize.assemble"):
-            # one host fetch per wave-level array, not one per slot
-            bits_h = (None if res.best_xs is not None
-                      else jax.device_get(res.bits))
-            iters_h = np.asarray(res.iterations)
             out: list[SolveResult] = []
             for slot, req in enumerate(self._reqs):
-                result = _slot_result(res, bits_h, iters_h, slot,
-                                      self._enc0, self._schedule,
-                                      self._width)
+                result = _slot_result(res, slot, self._enc0,
+                                      self._schedule, self._width)
                 if req.problem.signature is not None:
                     result.extras["problem_signature"] = \
                         req.problem.signature
@@ -977,7 +971,7 @@ def submit_wave(requests, *, mesh=None, pop_axes=("data",),
         # static cap sizes the trace buffer only (slots gate on their
         # own cap); rounded up so cap mixes don't churn the compile key
         cap = max(64, -(-max(caps) // 64) * 64)
-        x0 = jnp.stack(x0s)
+        x0 = np.stack(x0s)
     pending = distributed._submit_batched(
         prob.jax_fn, enc0, mesh, x0,
         pop_axes=tuple(pop_axes), max_iters=cap,

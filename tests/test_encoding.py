@@ -7,8 +7,8 @@ import pytest
 from conftest import given, settings, st
 
 from repro.core.encoding import (
-    Encoding, binary_to_gray, decode, encode, gray_to_binary,
-    pack_bits, unpack_bits,
+    Encoding, binary_to_gray, decode, decode_np, encode, encode_np,
+    gray_to_binary, pack_bits, unpack_bits,
 )
 from repro.core.population import (
     generate_children, generate_population, segment_table,
@@ -129,3 +129,25 @@ def test_children_distinct_and_involutive_fixed(n):
     ids = jnp.arange(2 * n - 1)
     back = jax.vmap(lambda c, i: generate_children(c, i[None])[0])(pop, ids)
     assert jnp.array_equal(back, jnp.broadcast_to(parent, pop.shape))
+
+
+@pytest.mark.parametrize("n_vars,bits,lo,hi", [
+    (40, 16, -5.12, 5.12), (20, 12, -32.768, 32.768), (10, 16, -600.0, 600.0),
+    (4, 10, 0.0, 10.0), (9, 8, -10.0, 10.0),
+])
+def test_encode_np_is_bitwise_encode(n_vars, bits, lo, hi):
+    """The host twin of ``encode`` gives the same bits on random points,
+    on every lattice point of a coarser resolution (the schedule's best
+    points, re-encoded at the final one) and on the box's edges."""
+    enc = Encoding(n_vars=n_vars, bits=bits, lo=lo, hi=hi)
+    rng = np.random.default_rng(bits)
+    points = [rng.uniform(lo, hi, (64, n_vars)).astype(np.float32),
+              np.array([[lo] * n_vars, [hi] * n_vars], np.float32)]
+    for coarse in range(2, bits + 1, 2):
+        enc_c = enc.with_bits(coarse)
+        levels = rng.integers(0, enc_c.levels, (64, n_vars))
+        b = (levels[..., None] >> np.arange(coarse - 1, -1, -1)) & 1
+        points.append(decode_np(b.reshape(64, -1).astype(np.int8), enc_c))
+    for x in points:
+        assert np.array_equal(encode_np(x, enc),
+                              np.asarray(encode(jnp.asarray(x), enc)))

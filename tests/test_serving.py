@@ -1,6 +1,8 @@
 """The serving subsystem: queue/bucket semantics, solve_many parity with
 per-request solves (the acceptance contract), retry accounting on failed
 dispatches, straggler-fed wave sizing, and the metrics snapshot."""
+import dataclasses
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -89,6 +91,93 @@ def test_solve_many_parity_folded_schedule(problems):
         assert out.iterations == ref.iterations, req
         assert np.array_equal(np.asarray(out.trace),
                               np.asarray(ref.trace)), req
+
+
+# the sfu-suite configuration's five objectives at CI size, on the
+# configuration's boxes (bench/configs/sfu-suite.json)
+SFU_CI = {
+    "rastrigin:6": (("rastrigin", {"n": 6}), None),
+    "ackley:4": (("ackley", {"n": 4}), (-32.768, 32.768)),
+    "griewank:10": (("griewank", {"n": 10}), (-600.0, 600.0)),
+    "quadratic:9": (("quadratic", {"n": 9}), None),
+    "shekel5": (("shekel", {"m": 5}), None),
+}
+
+
+def _same_bits(a, b) -> bool:
+    a, b = np.asarray(a), np.asarray(b)
+    return a.dtype == b.dtype and a.shape == b.shape and \
+        a.tobytes() == b.tobytes()
+
+
+def _assert_same_result(out, ref, what):
+    assert _same_bits(out.best_x, ref.best_x), what
+    assert _same_bits(out.best_f, ref.best_f), what
+    assert out.iterations == ref.iterations, what
+    assert _same_bits(out.trace, ref.trace), what
+    assert _same_bits(out.extras["bits"], ref.extras["bits"][0]), what
+
+
+@pytest.mark.parametrize("spec", sorted(SFU_CI))
+def test_wave_slot_is_its_width_one_solve_at_any_fill(spec):
+    """The parent evaluation runs inside the wave engine, one row at a
+    time: a request is bitwise the same alone at width 1, in a 16-slot
+    wave with 5 active slots, and in a full wave (folded 8 -> 12 bits)."""
+    (name, kwargs), box = SFU_CI[spec]
+    prob = Problem.get(name, **kwargs)
+    if box is not None:
+        prob = prob.replace(encoding=dataclasses.replace(
+            prob.encoding, lo=box[0], hi=box[1]))
+    enc = prob.encoding
+    rng = np.random.default_rng(14)
+    reqs = [SolveRequest(prob, x0=rng.uniform(enc.lo, enc.hi, enc.n_vars),
+                         max_iters=12) for _ in range(16)]
+    full = solve_many(reqs, pad_to=16, max_bits=12)
+    part = solve_many(reqs[:5], pad_to=16, max_bits=12)
+    for i, req in enumerate(reqs):
+        alone = _reference(req, max_bits=12)
+        _assert_same_result(full[i], alone, (spec, "full wave", i))
+        if i < 5:
+            _assert_same_result(part[i], alone, (spec, "5 of 16", i))
+
+
+@pytest.mark.parametrize("max_bits", [None, 12])
+def test_served_result_fields_keep_dtypes_and_shapes(problems, max_bits):
+    """Served fields are numpy slices of the wave's one fetch, with the
+    dtypes and shapes they always had: ``bits`` int8 at the final
+    resolution, the hygiene flag, the slot and the wave width."""
+    prob = problems["quadratic"]
+    reqs = [SolveRequest(prob, seed=s, max_iters=8) for s in (3, 4)]
+    final_bits = max_bits or prob.encoding.bits
+    for slot, res in enumerate(solve_many(reqs, pad_to=4,
+                                          max_bits=max_bits)):
+        assert np.asarray(res.best_x).dtype == np.float32
+        assert np.shape(res.best_x) == (3,)
+        assert np.asarray(res.best_f).dtype == np.float32
+        assert np.shape(res.best_f) == ()
+        assert isinstance(res.iterations, int)
+        assert np.asarray(res.trace).dtype == np.float32
+        assert res.trace.shape == (res.iterations + 1,)
+        bits = res.extras["bits"]
+        assert bits.dtype == np.int8 and bits.shape == (3 * final_bits,)
+        assert res.extras["finite"] is True
+        assert res.extras["wave_slot"] == slot
+        assert res.extras["wave_size"] == 4
+
+
+def test_served_nan_objective_is_flagged():
+    """A NaN objective is still caught by the hygiene policy on the
+    host-assembled results: flagged by default, raised on request."""
+    from repro.core.encoding import Encoding
+    from repro.core.solver import NonFiniteResult
+
+    prob = Problem(fn=lambda x: jnp.sum(x) * jnp.nan,
+                   encoding=Encoding(n_vars=2, bits=8))
+    req = SolveRequest(prob, seed=1, max_iters=4)
+    [res] = solve_many([req], pad_to=2)
+    assert res.extras["finite"] is False
+    with pytest.raises(NonFiniteResult):
+        solve_many([req], pad_to=2, on_nonfinite="raise")
 
 
 def test_solve_many_heterogeneous_caps_share_one_wave(problems):
@@ -442,3 +531,15 @@ def test_enable_compile_cache_directory(monkeypatch, tmp_path, from_env):
         jax.config.update("jax_compilation_cache_dir", saved[0])
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
                           saved[1])
+
+
+def test_chip_smoke_serving_phase_runs_on_cpu(monkeypatch):
+    """``chip_smoke.py``'s serving phase and its wave-parity check at CI
+    size on the CPU: the helpers it shares with the serve CLI (its loop
+    and its report) keep one signature."""
+    import chip_smoke
+
+    monkeypatch.setattr(chip_smoke, "WAVE", 4)
+    handles = chip_smoke.serve_closed_loop("quadratic:2,shekel", 2, 4)
+    assert len(handles) == 4
+    chip_smoke.check_wave_parity(handles)

@@ -18,7 +18,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from repro.compat import mesh_from_devices
 from repro.core import distributed
-from repro.core.objectives import quadratic_nd, rastrigin, shekel
+from repro.core.objectives import (
+    ackley, griewank, quadratic_nd, rastrigin, shekel,
+)
 from repro.kernels.popstep.kernel import popstep
 from repro.kernels.popstep.ops import _convert_objective, _tile
 
@@ -98,15 +100,32 @@ def test_folded_batched_engine_partitions_over_four_v5e(topo):
     obj = rastrigin(40)
     mesh = mesh_from_devices(np.array(topo.devices), ("data",))
     engine = distributed.make_distributed_engine_batched(
-        jax.vmap(obj.fn), obj.encoding, mesh, 16,
-        res_bits=(8, 10, 12, 14, 16))
+        obj.fn, obj.encoding, mesh, 16, res_bits=(8, 10, 12, 14, 16))
     shape = _replicated(mesh)
     lowered = engine.lower(
-        shape((16, 40), jnp.float32), shape((16,), jnp.float32),
-        shape((4,), jnp.bool_), shape((16,), jnp.bool_),
-        shape((16,), jnp.int32))
+        shape((16, 40), jnp.float32), shape((4,), jnp.bool_),
+        shape((16,), jnp.bool_), shape((16,), jnp.int32))
     assert "all_gather" in lowered.as_text()
     text = lowered.compile().as_text()
     collectives = [ln for ln in text.splitlines()
                    if " all-gather(" in ln or " all-reduce(" in ln]
     assert collectives, "no cross-chip collective in the 4-chip program"
+
+
+@pytest.mark.parametrize("make_obj", [
+    lambda: rastrigin(40), lambda: ackley(20), lambda: griewank(10),
+    lambda: quadratic_nd(9), lambda: shekel(5),
+], ids=["rastrigin40", "ackley20", "griewank10", "quadratic9", "shekel5"])
+def test_serving_engine_compiles_for_one_v5e(topo, make_obj):
+    """The 16-slot wave engine of each served objective, its start-point
+    evaluation a per-row ``lax.map`` inside it, compiles for one chip."""
+    obj = make_obj()
+    mesh = mesh_from_devices(np.array(topo.devices[:1]), ("data",))
+    engine = distributed.make_distributed_engine_batched(
+        obj.fn, obj.encoding, mesh, 16, res_bits=(8, 10, 12, 14, 16))
+    shape = _replicated(mesh)
+    text = engine.lower(
+        shape((16, obj.encoding.n_vars), jnp.float32),
+        shape((1,), jnp.bool_), shape((16,), jnp.bool_),
+        shape((16,), jnp.int32)).compile().as_text()
+    assert "dgo.parent_eval" in text

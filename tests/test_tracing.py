@@ -5,26 +5,28 @@ from pathlib import Path
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.core import distributed
-from repro.core.solver import Problem, SolveRequest, resolve_mesh
+from repro.core.solver import (
+    Problem, SolveRequest, resolve_mesh, submit_wave,
+)
 from repro.runtime.failure import FaultPlan
 from repro.serving import PipelinedScheduler, Scheduler
 
 MAX_ITERS = 8
 
 SPANS = ("dgo.pop", "dgo.dispatch", "dgo.submit_wave.prepare",
-         "dgo.submit_wave.parent_vals", "dgo.submit_wave.place",
-         "dgo.submit_wave.engine", "dgo.finalize.fetch",
-         "dgo.finalize.post", "dgo.finalize.assemble", "dgo.complete")
+         "dgo.submit_wave.place", "dgo.submit_wave.engine",
+         "dgo.finalize.fetch", "dgo.finalize.post", "dgo.finalize.assemble",
+         "dgo.complete")
 
 # the span each one nests in, on its own thread (None: top level)
 PARENTS = {
     "pipelined": {
         "dgo.pop": None, "dgo.dispatch": None, "dgo.finalize": None,
         "dgo.submit_wave.prepare": "dgo.dispatch",
-        "dgo.submit_wave.parent_vals": "dgo.dispatch",
         "dgo.submit_wave.place": "dgo.dispatch",
         "dgo.submit_wave.engine": "dgo.dispatch",
         "dgo.finalize.fetch": "dgo.finalize",
@@ -35,7 +37,6 @@ PARENTS = {
     "synchronous": {
         "dgo.pop": None, "dgo.dispatch": None, "dgo.complete": None,
         "dgo.submit_wave.prepare": "dgo.dispatch",
-        "dgo.submit_wave.parent_vals": "dgo.dispatch",
         "dgo.submit_wave.place": "dgo.dispatch",
         "dgo.submit_wave.engine": "dgo.dispatch",
         "dgo.finalize.fetch": "dgo.dispatch",
@@ -178,9 +179,10 @@ def test_phase_counters_cover_the_timed_waves(kind):
 
 
 @pytest.mark.parametrize("res_bits,scopes", [
-    (None, ("children", "decode", "evaluate", "select", "trace")),
-    ((8, 10), ("children", "decode", "evaluate", "select", "trace",
-               "escalate")),
+    (None, ("parent_eval", "children", "decode", "evaluate", "select",
+            "trace")),
+    ((8, 10), ("parent_eval", "children", "decode", "evaluate", "select",
+               "trace", "escalate")),
 ])
 def test_engine_program_names_its_phases(res_bits, scopes):
     """The wave engine compiles as ``jit_dgo_wave_engine``, and its HLO
@@ -188,23 +190,81 @@ def test_engine_program_names_its_phases(res_bits, scopes):
     prob = Problem.get("rastrigin", n=3)
     enc = prob.encoding.with_bits(8)
     engine = distributed.make_distributed_engine_batched(
-        jax.vmap(prob.jax_fn), enc, resolve_mesh(None), 2,
+        prob.jax_fn, enc, resolve_mesh(None), 2,
         max_iters=MAX_ITERS, res_bits=res_bits)
-    args = (jnp.zeros((2, 3)), jnp.zeros(2), jnp.ones(1, bool),
-            jnp.ones(2, bool), jnp.full(2, MAX_ITERS, jnp.int32))
+    args = (jnp.zeros((2, 3)), jnp.ones(1, bool), jnp.ones(2, bool),
+            jnp.full(2, MAX_ITERS, jnp.int32))
     text = engine.lower(*args).compile().as_text()
     assert text.startswith("HloModule jit_dgo_wave_engine")
     for name in scopes:
         assert f"/dgo.{name}/" in text, name
 
 
-def test_parent_evaluator_program_name():
-    prob = Problem.get("quadratic", n=2)
-    distributed._parent_vals(prob.jax_fn, jnp.zeros((2, 2)))
-    ev = distributed._PARENT_EVALS.get(("parent_eval", prob.jax_fn),
-                                       lambda: None)
-    text = ev.lower(jnp.zeros(2)).as_text()
-    assert "jit_dgo_parent_eval" in text
+_PROGRAMS_BUILT = []
+
+
+def _count_programs():
+    """Programs built from here on (backend compiles and persistent-cache
+    loads, from JAX's monitoring events), as ``chip_smoke.CompileCounter``
+    counts them; returns a callable reading the count."""
+    if not _PROGRAMS_BUILT:
+        _PROGRAMS_BUILT.append(0)
+
+        def on_duration(event, duration, **_):
+            if event == "/jax/core/compile/backend_compile_duration":
+                _PROGRAMS_BUILT[0] += 1
+
+        def on_event(event, **_):
+            if event == "/jax/compilation_cache/cache_hits":
+                _PROGRAMS_BUILT[0] += 1
+
+        jax.monitoring.register_event_duration_secs_listener(on_duration)
+        jax.monitoring.register_event_listener(on_event)
+    start = _PROGRAMS_BUILT[0]
+    return lambda: _PROGRAMS_BUILT[0] - start
+
+
+def host_events(trace_dir):
+    """Names of every event on the profiler's host plane."""
+    [path] = Path(trace_dir).rglob("*.xplane.pb")
+    data = jax.profiler.ProfileData.from_file(str(path))
+    return [e.name for plane in data.planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events]
+
+
+@pytest.mark.timeout(300)
+@pytest.mark.parametrize("warm,then", [(16, 3), (3, 16)])
+def test_warm_wave_of_a_new_slot_count_builds_no_program(warm, then,
+                                                         tmp_path):
+    """After one wave of a signature, a wave with another number of
+    active slots builds no program and runs exactly one: the wave
+    engine. Its host path is one engine call and one fetch, with no
+    per-slot device work."""
+    prob = Problem.get("griewank", n=4)
+    rng = np.random.default_rng(warm)
+    enc = prob.encoding
+
+    def requests(n):
+        return [SolveRequest(prob, x0=rng.uniform(enc.lo, enc.hi, 4),
+                             max_iters=MAX_ITERS) for _ in range(n)]
+
+    submit_wave(requests(warm), max_bits=10, pad_to=16).finalize()
+    wave = requests(then)
+    built = _count_programs()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        results = submit_wave(wave, max_bits=10, pad_to=16).finalize()
+    finally:
+        jax.profiler.stop_trace()
+    assert built() == 0
+    assert [r.extras["wave_slot"] for r in results] == list(range(then))
+    events = host_events(tmp_path)
+    calls = {e for e in events if e.startswith("PjitFunction(")}
+    assert calls == {"PjitFunction(dgo_wave_engine)"}, calls
+    assert sum(e.endswith("Executable::Execute") for e in events) == 1
 
 
 @pytest.mark.timeout(300)
