@@ -6,11 +6,11 @@ and it has to come out as not correct.
 
     python3 bench/control.py --workload sfu-suite.open --seeds 1,2,3
 
-For each seed the cell's own traffic is drawn (the open loop's window at
-the mix's rate), the check's sample
-is taken from it, and each sampled request is answered by the bfloat16
-reference and compared, as a run compares the program, with the float32
-reference. One JSON line per seed: the numbers, their limits, and
+For each seed the cell's own traffic is drawn (an open loop's window at
+the mix's rate; a closed loop's first ``check_sample + 1`` starts), the
+check's sample is taken from it, and each sampled request is answered by
+the bfloat16 reference and compared, as a run compares the program, with
+the float32 reference. One JSON line per seed: the numbers, their limits, and
 whether the control was (wrongly) found correct. Numpy only: no program,
 no chip.
 """
@@ -30,11 +30,21 @@ import traffic as traffic_gen  # noqa: E402
 from drive import Answer  # noqa: E402
 
 
+def starts(cell, seed: int, seconds: float) -> list:
+    """The requests of the cell's traffic, unanswered."""
+    cfg, mix = cell.config, cell.traffic
+    if mix["loop"] == "closed":
+        [entry] = cfg["problems"]
+        return [Answer(0, traffic_gen.closed_loop_start(entry, seed, i))
+                for i in range(int(cfg["check_sample"]) + 1)]
+    return [Answer(a.problem, a.x0) for a in
+            traffic_gen.open_loop(mix, cfg["problems"], seed, seconds)]
+
+
 def control_numbers(cell, seed: int, seconds: float):
     """(numbers, limits) of the bfloat16 control on one seed."""
-    cfg, mix = cell.config, cell.traffic
-    answers = [Answer(a.problem, a.x0) for a in
-               traffic_gen.open_loop(mix, cfg["problems"], seed, seconds)]
+    cfg = cell.config
+    answers = starts(cell, seed, seconds)
     for a in answers:              # every request counts as answered
         a.best_f, a.iterations = 0.0, 0
     idx = check.sample(answers, cfg["problems"], seed,

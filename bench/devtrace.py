@@ -9,7 +9,13 @@ synthetic trace with known answers:
   control flow (``while``, ``conditional``, ``call``), whose events span
   the operations of their bodies;
 * idle gaps, each labelled with the benchmark's own host spans (names
-  starting ``bench.``) that were open at the gap's middle.
+  starting ``bench.``) that were open at the gap's middle;
+* the exposed time of collectives: on each device, the union of its
+  collective operations' intervals (HLO opcode ``all-gather*``,
+  ``all-reduce*``, ``collective-permute*``, ``reduce-scatter*`` or
+  ``all-to-all*``) inside the window, less the part that any other
+  operation on that device covers (control flow left out, as its events
+  span their bodies), averaged over the devices.
 """
 from __future__ import annotations
 
@@ -25,6 +31,9 @@ WINDOW_SPAN = SPAN_PREFIX + "window"
 OPS_LINE = "XLA Ops"
 # control flow whose trace event spans the operations of its body
 _CONTAINER = re.compile(r"\s(while|conditional|call)\(")
+# collectives by opcode, with their -start/-done halves
+_COLLECTIVE = re.compile(r"\s(all-gather|all-reduce|collective-permute"
+                         r"|reduce-scatter|all-to-all)(-start|-done)?\(")
 
 
 @dataclass(frozen=True)
@@ -50,6 +59,8 @@ class Summary:
     top_ops: list                       # [[name, seconds]] mean/device
     idle_gaps: list                     # [[label, seconds]] first device
     n_devices: int
+    collective_s: float = 0.0           # union of collectives, mean
+    collective_exposed_s: float = 0.0   # mean over devices
 
 
 # ---------------------------------------------------------------------------
@@ -73,6 +84,21 @@ def clip(intervals, lo: float, hi: float) -> list[tuple[float, float]]:
 
 def length(intervals) -> float:
     return sum(e - s for s, e in intervals)
+
+
+def overlap(a, b) -> float:
+    """Length of the intersection of two sorted, disjoint interval lists
+    (as ``union`` returns them)."""
+    total, i, j = 0.0, 0, 0
+    while i < len(a) and j < len(b):
+        lo, hi = max(a[i][0], b[j][0]), min(a[i][1], b[j][1])
+        if hi > lo:
+            total += hi - lo
+        if a[i][1] < b[j][1]:
+            i += 1
+        else:
+            j += 1
+    return total
 
 
 def gaps(busy, lo: float, hi: float) -> list[tuple[float, float]]:
@@ -101,6 +127,24 @@ def is_container(name: str) -> bool:
     return bool(_CONTAINER.search(name))
 
 
+def is_collective(name: str) -> bool:
+    return bool(_COLLECTIVE.search(name))
+
+
+def collective_time(ops, lo: float, hi: float) -> tuple[float, float]:
+    """``(all, exposed)``: nanoseconds in ``[lo, hi]`` in which a
+    collective of ``ops`` runs, and those in which no other operation
+    (control flow aside) runs with it."""
+    coll = union(clip([(e.start_ns, e.end_ns) for e in ops
+                       if is_collective(e.name)], lo, hi))
+    if not coll:
+        return 0.0, 0.0
+    other = union(clip([(e.start_ns, e.end_ns) for e in ops
+                        if not is_collective(e.name)
+                        and not is_container(e.name)], lo, hi))
+    return length(coll), length(coll) - overlap(coll, other)
+
+
 def window_of(raw: RawTrace) -> tuple[float, float]:
     marks = [s for s in raw.spans if s.name == WINDOW_SPAN]
     if marks:
@@ -124,6 +168,7 @@ def reduce(raw: RawTrace, n_top: int = 10) -> Summary:
     if not names or window_ns <= 0:
         raise ValueError("trace holds no device events in its window")
     busy = []
+    coll_ns = exposed_ns = 0.0
     op_time: dict[str, float] = {}
     first_gaps = []
     for i, dev in enumerate(names):
@@ -131,6 +176,9 @@ def reduce(raw: RawTrace, n_top: int = 10) -> Summary:
         ops = lines.get(OPS_LINE, [])
         iv = clip([(e.start_ns, e.end_ns) for e in ops], lo, hi)
         busy.append(length(union(iv)))
+        c_all, c_exposed = collective_time(ops, lo, hi)
+        coll_ns += c_all
+        exposed_ns += c_exposed
         for e in ops:
             d = min(e.end_ns, hi) - max(e.start_ns, lo)
             if d > 0 and not is_container(e.name):
@@ -148,7 +196,9 @@ def reduce(raw: RawTrace, n_top: int = 10) -> Summary:
         top_ops=[[k, v / n * 1e-9] for k, v in top],
         idle_gaps=[[label_at(raw, (s + e) / 2), (e - s) * 1e-9]
                    for s, e in longest],
-        n_devices=n)
+        n_devices=n,
+        collective_s=coll_ns / n * 1e-9,
+        collective_exposed_s=exposed_ns / n * 1e-9)
 
 
 # ---------------------------------------------------------------------------

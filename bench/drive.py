@@ -4,6 +4,10 @@
 ``step``) -> ``submit_wave`` -> the batched folded engine, under the
 traffic mix's open loop.
 
+``solve`` drives ``solve(problem, Distributed(...))`` -> the folded
+single-solve engine over the cell's chips, in a closed loop with one
+solve in flight.
+
 Set-up (imports, the chip, warm-up of every program the window uses) ends
 where the window starts. Latencies are timed by the benchmark's own
 clock: from each request's due time to the moment the benchmark sees its
@@ -51,6 +55,7 @@ class Run:
     trace: object = None              # trace.Summary
     memory_peak_bytes: int = 0
     notes: list = field(default_factory=list)
+    traced: list = field(default_factory=list)  # answers run wholly traced
 
 
 class CompileCounter:
@@ -289,3 +294,127 @@ def serve(cell, seed: int, seconds: float, trace_on: bool,
                answers=answers, counters=window, compiles_in_window=n_comp,
                trace=summary, memory_peak_bytes=peak, notes=notes)
 
+
+# ---------------------------------------------------------------------------
+# solve: one global solve at a time, in a closed loop
+# ---------------------------------------------------------------------------
+
+def solve_entry(cfg: dict, mesh):
+    """``(problem entry, x0 -> SolveResult)``: the cell's one problem and
+    a call of the program's ``solve`` on it."""
+    from repro.core.solver import Distributed, solve as program_solve
+
+    [entry] = cfg["problems"]
+    problem = make_problem(entry)
+    strategy = Distributed(mesh=mesh, max_bits=int(cfg["max_bits"]),
+                           bits_step=int(cfg["bits_step"]))
+    cap = int(cfg["max_iters"])
+
+    def one(x0):
+        return program_solve(problem, strategy, x0=x0, max_iters=cap)
+    return entry, one
+
+
+def answer(a: Answer, res) -> None:
+    """Fill ``a`` from a SolveResult: host values, which wait on the
+    device."""
+    a.best_x = np.asarray(res.best_x, np.float32)
+    a.best_f = float(res.best_f)
+    a.iterations = int(res.iterations)
+
+
+def warm_solve(one, entry: dict) -> None:
+    """One solve; then, at every resolution of the schedule, what the
+    program does after the engine: it slices the best parent's bits out
+    of the engine's widest buffer at the resolution they were found at
+    and decodes them there, in programs of that resolution's own. One
+    solve ends on one resolution; the window's may end on any."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.encoding import decode
+
+    with span("warmup"):
+        [x0] = traffic_gen.warmup_starts(entry, 1)
+        res = one(x0)
+        answer(Answer(0, x0), res)
+        bits, enc = res.extras["bits"], make_problem(entry).encoding
+        schedule = res.extras["schedule"]
+        widest = jax.device_put(
+            jnp.zeros(enc.n_vars * max(schedule), bits.dtype),
+            bits.sharding)
+        for b in schedule:
+            enc_b = enc.with_bits(b)
+            np.asarray(decode(widest[: enc_b.n_bits], enc_b))
+    settle()
+
+
+def closed_loop(one, entry: dict, seed: int, seconds: float,
+                compiles: CompileCounter, trace_s: float | None = None):
+    """Solve after solve, each due when the previous answer reached the
+    host, until the window closes; the solve in flight then runs to its
+    end. With ``trace_s`` the last ``trace_s`` seconds are traced, and the
+    solve in flight at the close with them. Returns ``(answers, start,
+    end, ok, indices of the answered solves traced whole, programs built
+    in the window, trace summary, first error)``."""
+    answers, start, end, ok = [], [], [], []
+    error = capture = None
+    t0 = time.perf_counter()
+    close = t0 + seconds
+    trace_from = close - trace_s if trace_s else np.inf
+    p_start = compiles.count
+    try:
+        while time.perf_counter() < close:
+            if capture is None and time.perf_counter() >= trace_from:
+                capture = Capture().__enter__()
+                trace_from = time.perf_counter()
+            a = Answer(0, traffic_gen.closed_loop_start(entry, seed,
+                                                        len(answers)))
+            answers.append(a)
+            start.append(time.perf_counter())
+            try:
+                with span("solve"):
+                    answer(a, one(a.x0))
+                ok.append(True)
+            except Exception as e:     # counted as failed; the run goes on
+                ok.append(False)
+                error = error or f"{type(e).__name__}: {e}"
+            end.append(time.perf_counter())
+        n_comp = compiles.count - p_start
+    finally:
+        if capture is not None:
+            capture.stop()
+    start, end, ok = np.array(start), np.array(end), np.array(ok, bool)
+    traced = [int(i) for i in np.flatnonzero(ok & (start >= trace_from))]
+    return (answers, start, end, ok, traced, n_comp,
+            capture.summary() if capture is not None else None, error)
+
+
+def solve(cell, seed: int, seconds: float, trace_on: bool,
+          compiles: CompileCounter, t_start: float) -> Run:
+    cfg, mix = cell.config, cell.traffic
+    if mix["loop"] != "closed" or int(mix["in_flight"]) != 1:
+        raise ValueError(f"the solve entry drives a closed loop with one "
+                         f"solve in flight, not {mix!r}")
+    mesh = make_mesh(cell.chips)
+    entry, one = solve_entry(cfg, mesh)
+    warm_solve(one, entry)
+    setup_s = time.perf_counter() - t_start
+    answers, start, end, ok, traced, n_comp, summary, error = closed_loop(
+        one, entry, seed, seconds, compiles,
+        float(mix["trace_window_s"]) if trace_on else None)
+    close = start[0] + seconds
+    in_window = int((ok & (end <= close)).sum())
+    notes = [f"solves due {len(answers)}, answered {int(ok.sum())}, by "
+             f"the close {in_window}; programs built in the window "
+             f"{n_comp}"]
+    if summary is not None:
+        notes.append(f"solves traced whole {len(traced)}")
+    if error is not None:
+        notes.append(f"first error: {error}")
+    return Run(window_s=seconds, setup_s=setup_s, attempted=len(answers),
+               failed=int((~ok).sum()), completed_in_window=in_window,
+               latencies_s=e2e.latencies(start, end, ok, close, GRACE_S),
+               answers=answers, compiles_in_window=n_comp, trace=summary,
+               memory_peak_bytes=memory_peak(mesh), notes=notes,
+               traced=traced)

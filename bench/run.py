@@ -8,7 +8,7 @@ Set-up (JAX, the chip, the persistent compile cache in the checkout,
 warm-up of every program the cell's traffic uses) is timed as
 ``setup_s``; then the cell's traffic runs for ``--seconds``. With
 ``--trace 0`` the last line of standard output reports the cell's
-end-to-end metrics; with ``--trace 1`` the first part of the window is
+end-to-end metrics; with ``--trace 1`` the last part of the window is
 traced and the line reports its per-layer metrics and a ``breakdown``.
 Either way the answers are checked against the plain reference, and the
 numbers compared are printed with their limits as the last lines of
@@ -38,6 +38,9 @@ import drive  # noqa: E402
 import e2e  # noqa: E402
 import harness  # noqa: E402
 import peaks  # noqa: E402
+
+
+DRIVERS = {"serve": drive.serve, "solve": drive.solve}
 
 
 @dataclass
@@ -84,10 +87,11 @@ def execute(cell: harness.Cell, seed: int, seconds: float, trace: bool,
         else {}
     enable_compile_cache()
     compiles = drive.CompileCounter()
-    if cell.config["entry"] != "serve":
+    if cell.config["entry"] not in DRIVERS:
         raise ValueError(f"{cell.name}: no driver for entry "
                          f"{cell.config['entry']!r}")
-    run = drive.serve(cell, seed, seconds, trace, compiles, t_start)
+    run = DRIVERS[cell.config["entry"]](cell, seed, seconds, trace,
+                                        compiles, t_start)
     correct, compared = check.check(cell.config, run, seed)
     out = {"correct": correct, "attempted": run.attempted,
            "failed": run.failed}

@@ -2,6 +2,9 @@
 and whole runs on the CPU (small shapes) with the timed path sound and
 with it broken underneath, for each fault a cell can have."""
 import copy
+import json
+import os
+import subprocess
 import sys
 import time
 from pathlib import Path
@@ -18,6 +21,7 @@ import harness  # noqa: E402
 import reference  # noqa: E402
 
 SERVE = "sfu-suite.open"
+SOLVE = "rastrigin40-dgo.solve-4chip"
 
 RASTRIGIN6 = {"name": "rastrigin:6", "objective": "rastrigin", "n": 6,
               "lo": -5.12, "hi": 5.12, "bits": 8,
@@ -68,6 +72,15 @@ def test_bfloat16_control_is_not_correct():
     seconds = 10 / c.traffic.get("rate_per_s", 1.0)
     got, limits = control.control_numbers(c, seed=2**31 + 3,
                                           seconds=seconds)
+    assert any(got[k] > limits[k] for k in got), got
+
+
+def test_bfloat16_control_of_a_solve_cell_is_not_correct():
+    c = harness.load_cell(SOLVE)
+    c.config["check_sample"] = 4
+    # the first five starts of the cell's closed loop, at its shapes
+    got, limits = control.control_numbers(c, seed=-(2**31) - 5,
+                                          seconds=1.0)
     assert any(got[k] > limits[k] for k in got), got
 
 
@@ -176,4 +189,58 @@ def test_serve_fault_is_not_correct(fresh, fault):
         fresh.setattr(distributed, "_build_shard_schedule_step_batched",
                       wrap(distributed._build_shard_schedule_step_batched))
     out = run_small(SERVE)
+    assert not out["correct"], out["check"]
+
+
+# ---------------------------------------------------------------------------
+# the solve cell on four virtual devices, sound and broken
+# ---------------------------------------------------------------------------
+
+SOLVE_FAULTS = ["unchanged_state", "half_population", "no_exchange",
+                "altered_answer"]
+
+
+@pytest.fixture(scope="module")
+def solve_runs(tmp_path_factory):
+    """One process with four CPU devices runs the solve cell small, sound
+    and with each fault planted (``solve_faults.py``); its result lines by
+    case."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(
+                   tmp_path_factory.mktemp("jax-cache")))
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " "
+                        "--xla_force_host_platform_device_count=4").strip()
+    cases = ["sound", "sound_1chip", "sound_traced", *SOLVE_FAULTS]
+    out = subprocess.run(
+        [sys.executable, str(BENCH / "tests" / "solve_faults.py"), *cases],
+        capture_output=True, text=True, env=env, timeout=600)
+    assert out.returncode == 0, out.stderr[-4000:]
+    lines = [json.loads(x) for x in out.stdout.splitlines()
+             if x.startswith("{")]
+    return {r["case"]: r for r in lines}
+
+
+@pytest.mark.parametrize("case,chips", [("sound", 4), ("sound_1chip", 1)])
+def test_sound_solve_run_is_correct(solve_runs, case, chips):
+    out = solve_runs[case]
+    assert out["correct"], out["check"]
+    assert out["failed"] == 0 and out["attempted"] >= 10
+    assert out["device"]["count"] == chips
+    assert set(out["metrics"]) == {"solve_ms", "solve_p95_ms", "setup_s"}
+    assert out["metrics"]["solve_p95_ms"]["value"] >= \
+        out["metrics"]["solve_ms"]["value"] > 0
+    assert list(out)[-1] == "check"
+    assert "programs built in the window 0" in out["notes"][0]
+
+
+def test_traced_solve_run_is_correct(solve_runs):
+    out = solve_runs["sound_traced"]
+    assert out["correct"], out["check"]
+    # the CPU's trace holds no device plane: every reader finds nothing
+    assert out["metrics"] == {}
+
+
+@pytest.mark.parametrize("fault", SOLVE_FAULTS)
+def test_solve_fault_is_not_correct(solve_runs, fault):
+    out = solve_runs[fault]
     assert not out["correct"], out["check"]

@@ -28,7 +28,7 @@ def test_cell_resolves_to_its_files(cell):
     assert c.config["name"] == c.workload["config"]
     assert c.traffic["loop"] in ("open", "closed")
     assert c.chips in (1, 4)
-    assert c.config["entry"] in ("serve",)
+    assert c.config["entry"] in ("serve", "solve")
     assert c.config["dtype"] == "float32"
     assert set(c.config["limits"]) == {"f_gap", "x_gap", "iters_gap"}
     assert any(m["name"] == "setup_s" for m in c.end_to_end)
@@ -91,6 +91,20 @@ def test_contract_keys_names_and_limits():
     for e in SPEC["per_layer"]:
         assert "\n" not in e["layer"] and len(e["layer"]) <= 200
     assert (BENCH.parent / "BENCHMARK.json").stat().st_size <= 64 * 1024
+
+
+@pytest.mark.parametrize("cell,chips", [("rastrigin40-dgo.solve-4chip", 4),
+                                        ("rastrigin40-dgo.solve-1chip", 1)])
+def test_solve_cells_resolve_to_one_configuration(cell, chips):
+    c = harness.load_cell(cell)
+    assert c.chips == chips
+    assert c.config["entry"] == "solve" and len(c.config["problems"]) == 1
+    assert c.traffic["loop"] == "closed" and c.traffic["in_flight"] == 1
+    assert {m["name"] for m in c.end_to_end} == {"solve_ms", "solve_p95_ms",
+                                                 "setup_s"}
+    layer = {m["name"] for m in c.per_layer}
+    assert {"device_idle_share.solve", "engine_roofline"} <= layer
+    assert ("collective_exposed_share" in layer) == (chips == 4)
 
 
 def test_a_missing_cell_or_file_is_an_error():

@@ -94,3 +94,59 @@ def test_interval_arithmetic():
     assert devtrace.clip([(0, 3), (5, 9), (10, 12)], 2, 8) == [(2, 3),
                                                                 (5, 8)]
     assert devtrace.gaps([(2, 3), (5, 6)], 0, 8) == [(0, 2), (3, 5), (6, 8)]
+
+
+def test_collective_exposed_time():
+    s = devtrace.reduce(two_devices())
+    # device 0: all-reduce 25-40, compute 10-30 covers 25-30 (the while
+    # loop 10-40 spans its body and covers nothing): 15 ms, 10 exposed;
+    # device 1: all-reduce 50-55 after compute 0-50: 5 ms, 5 exposed
+    assert s.collective_s == pytest.approx((0.015 + 0.005) / 2)
+    assert s.collective_exposed_s == pytest.approx((0.010 + 0.005) / 2)
+
+
+def test_collective_exposed_time_overlapped_in_part_on_each_device():
+    ag = ("%all-gather-start.1 = (f32[2]{0}, f32[8]{0}) "
+          "all-gather-start(f32[2]{0} %p), dimensions={0}")
+    raw = RawTrace()
+    raw.devices["/device:TPU:0"] = {devtrace.OPS_LINE: [
+        op(0, 10, ag), op(5, 8), op(20, 30, AR), op(15, 22), op(28, 40)]}
+    raw.devices["/device:TPU:1"] = {devtrace.OPS_LINE: [
+        op(0, 10, ag), op(2, 4, ag), op(40, 50, AR)]}
+    raw.spans = [Event(0, 100 * MS, devtrace.WINDOW_SPAN)]
+    s = devtrace.reduce(raw)
+    # device 0: collectives 0-10 and 20-30 (20 ms), others cover 5-8,
+    # 20-22 and 28-30: 13 exposed; device 1: 0-10 and 40-50, all exposed
+    assert s.collective_s == pytest.approx((0.020 + 0.020) / 2)
+    assert s.collective_exposed_s == pytest.approx((0.013 + 0.020) / 2)
+
+
+def test_no_collectives_expose_nothing():
+    raw = two_devices()
+    for lines in raw.devices.values():
+        lines[devtrace.OPS_LINE] = [e for e in lines[devtrace.OPS_LINE]
+                                    if not devtrace.is_collective(e.name)]
+    s = devtrace.reduce(raw)
+    assert s.collective_s == 0.0 and s.collective_exposed_s == 0.0
+
+
+@pytest.mark.parametrize("name,want", [
+    (AR, True),
+    ("%all-reduce = f32[8]{0:T(128)S(1)} all-reduce(%maximum_dynamic-"
+     "update-slice_fusion), channel_id=2, replica_groups={{0,1,2,3}}", True),
+    ("%all-gather-done.2 = f32[8]{0} all-gather-done((f32[2]{0}) %s)", True),
+    ("%cp.1 = f32[8]{0} collective-permute(f32[8]{0} %x)", True),
+    ("%rs = f32[2]{0} reduce-scatter(f32[8]{0} %x), dimensions={0}", True),
+    ("%a2a = f32[8]{0} all-to-all(f32[8]{0} %x), dimensions={0}", True),
+    ("%fusion.2 = f32[8]{0} fusion(f32[8]{0} %all-reduce.3)", False),
+    ("%all-reduce.9 = f32[8]{0} fusion(f32[8]{0} %a)", False),
+    (LOOP, False),
+])
+def test_collectives_by_opcode(name, want):
+    assert devtrace.is_collective(name) is want
+
+
+def test_interval_overlap():
+    assert devtrace.overlap([(0, 10), (20, 30)], [(5, 25)]) == 10
+    assert devtrace.overlap([(0, 1)], [(2, 3)]) == 0
+    assert devtrace.overlap([], [(0, 5)]) == 0

@@ -12,7 +12,9 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 import e2e  # noqa: E402
 import harness  # noqa: E402
 import peaks  # noqa: E402
+import reference  # noqa: E402
 import traffic  # noqa: E402
+import work  # noqa: E402
 
 R40 = {"objective": "rastrigin", "n": 40, "lo": -5.12, "hi": 5.12,
        "bits": 8}
@@ -109,3 +111,91 @@ def test_rates_over_the_window():
     assert e2e.METRICS["solves_per_s"](run) == 25.0
     assert e2e.METRICS["setup_s"](run) == 12.5
     assert e2e.METRICS["setup_s"](run) == 12.5
+
+
+# ---------------------------------------------------------------------------
+# the closed loop and the work of a solve
+# ---------------------------------------------------------------------------
+
+def test_closed_loop_start_is_deterministic_and_uniform_in_the_box():
+    seed = 2**31 + 17
+    a = [traffic.closed_loop_start(R40, seed, i) for i in range(500)]
+    b = [traffic.closed_loop_start(R40, seed, i) for i in range(500)]
+    assert all(np.array_equal(x, y) for x, y in zip(a, b))
+    assert not np.array_equal(a[0], a[1])
+    assert not np.array_equal(a[0], traffic.closed_loop_start(R40, -seed, 0))
+    x = np.stack(a)
+    assert x.dtype == np.float32 and x.shape == (500, 40)
+    assert x.min() >= -5.12 and x.max() <= 5.12
+    # uniform: each quarter of the box holds a quarter of the coordinates
+    counts = np.histogram(x, bins=4, range=(-5.12, 5.12))[0] / x.size
+    np.testing.assert_allclose(counts, 0.25, atol=0.01)
+
+
+def test_solve_times_are_nearest_rank_over_every_solve():
+    lat = np.arange(1, 101) * 1e-3            # 1 .. 100 ms
+    run = SimpleNamespace(latencies_s=lat)
+    assert e2e.METRICS["solve_ms"](run) == pytest.approx(50.0)
+    assert e2e.METRICS["solve_p95_ms"](run) == pytest.approx(95.0)
+    # a failed solve counts beyond every answer
+    failed = e2e.latencies([0.0, 1.0], [0.2, np.nan], [True, False],
+                           close=2.0, grace_s=60.0)
+    run = SimpleNamespace(latencies_s=failed)
+    assert e2e.METRICS["solve_p95_ms"](run) > 60e3
+
+
+def test_work_of_a_solve_is_the_hand_count():
+    spec = dict(R40, n=2)
+    w = work.iteration(spec, 3)
+    # N = 6 bits, P = 11 children, rastrigin 6 ops a variable
+    assert w.ops == 11 * (6 + 12 + 6 * 2) == 330
+    assert w.bytes == 6 + 11 * 8 == 94
+    w4 = work.iteration(spec, 4)               # N = 8, P = 15
+    assert (w4.ops, w4.bytes) == (15 * (8 + 16 + 12), 8 + 15 * 8)
+    total = work.solve(spec, (3, 4), (2, 1))
+    assert (total.ops, total.bytes) == (2 * 330 + 540, 2 * 94 + 128)
+    peak = {"flops_per_s": 100.0, "hbm_bytes_per_s": 10.0}
+    assert total.least_s(peak) == pytest.approx(316 / 10.0)
+    assert total.binds(peak) == "bytes"
+    assert total.binds({"flops_per_s": 1.0, "hbm_bytes_per_s": 1e9}) == "ops"
+    shekel = {"objective": "shekel", "n": 4, "kwargs": {"m": 5}}
+    assert work.iteration(shekel, 1).ops == 7 * (4 + 8 + 3 * 5 * 4 + 15)
+
+
+def test_solve_readers_read_the_trace():
+    idle = harness.metric_reader("device_idle_share.solve")
+    assert idle(reader_ctx(trace=SimpleNamespace(idle_share=0.25))) == \
+        pytest.approx(25.0)
+    coll = harness.metric_reader("collective_exposed_share")
+    t = SimpleNamespace(collective_s=0.3, collective_exposed_s=0.1,
+                        window_s=2.0)
+    assert coll(reader_ctx(trace=t)) == pytest.approx(5.0)
+    t.collective_s = 0.0                       # no collective in the trace
+    assert coll(reader_ctx(trace=t)) is None
+
+
+def test_engine_roofline_is_least_time_over_busy_time():
+    roof = harness.metric_reader("engine_roofline")
+    spec = dict(R40, n=3)
+    cfg = {"problems": [spec], "max_bits": 12, "bits_step": 2,
+           "max_iters": 64}
+    x0 = traffic.closed_loop_start(spec, 3, 0)
+    ref = reference.run(spec, x0, max_bits=12, bits_step=2, max_iters=64)
+    least = work.solve(spec, (8, 10, 12), ref.per_resolution).least_s(
+        peaks.peaks("TPU v5 lite"))
+    trace = SimpleNamespace(busy_s=1e-3, n_devices=4)
+    run = SimpleNamespace(trace=trace, answers=[SimpleNamespace(
+        problem=0, x0=x0)] * 2, traced=[0, 1])
+    ctx = SimpleNamespace(run=run, cell=SimpleNamespace(config=cfg),
+                          peak=peaks.peaks("TPU v5 lite"))
+    assert roof(ctx) == pytest.approx(100.0 * 2 * least / 4 / 1e-3)
+    run.traced = []                            # no solve traced whole
+    assert roof(ctx) is None
+    run.traced, ctx.peak = [0], {}             # no peaks: not a chip
+    assert roof(ctx) is None
+
+
+@pytest.mark.parametrize("metric", ["device_idle_share.solve",
+                                    "collective_exposed_share"])
+def test_a_solve_reader_that_finds_nothing_returns_none(metric):
+    assert harness.metric_reader(metric)(reader_ctx()) is None

@@ -8,6 +8,13 @@ configuration, in its order (largest remainders); the seed only orders
 gaps and problems and draws the start points. So every seed offers the same amount and kind of work,
 with Poisson-like arrivals.
 
+Closed loop (``"loop": "closed"``): one solve in flight, each due the
+moment the previous answer reached the host, so the window holds as many
+solves as the system finishes. Solve ``i`` starts from
+``closed_loop_start(problem, seed, i)``, drawn from ``(seed, 4, i)``
+alone: the same seed gives the same starts in the same order, whatever
+the pace.
+
 Start points are uniform in the problem's box, in float32.
 """
 from __future__ import annotations
@@ -70,6 +77,12 @@ def open_loop(traffic: dict, problems: list[dict], seed: int,
     x0_rng = np.random.default_rng(x0_ss)
     return [Arrival(float(t), int(k), start_point(problems[k], x0_rng))
             for t, k in zip(due, kinds)]
+
+
+def closed_loop_start(problem: dict, seed: int, i: int) -> np.ndarray:
+    """The start point of solve ``i`` of a closed-loop window."""
+    return start_point(problem,
+                       np.random.default_rng(seed_sequence(seed, 4, i)))
 
 
 def warmup_starts(problem: dict, count: int) -> list[np.ndarray]:
