@@ -1,4 +1,5 @@
-"""Decides ``correct``: the program's answers against the plain reference.
+"""The check of a DGO configuration: the program's answers against the
+plain numpy reference, ``bench/reference.py``.
 
 After the window has closed, a sample of the answered requests, drawn
 from the seed (an equal share of each problem, and the request that took
@@ -12,8 +13,10 @@ sample, each with its limit from the configuration file:
 * ``iters_gap``: |iterations - reference iterations| / reference
   iterations.
 
-``correct`` also needs every request due in the window answered without
-an error.
+The control is the same reference in bfloat16, the precision below the
+float32 the configurations state, put in the program's place on the
+cell's own traffic; it has to fail. That every request due in the window
+was answered without an error is ``run.py``'s rule, for every check.
 """
 from __future__ import annotations
 
@@ -23,6 +26,7 @@ import numpy as np
 
 import reference
 import traffic as traffic_gen
+from drive import Answer
 
 
 def sample(answers, problems: list, seed: int, size: int) -> list[int]:
@@ -72,13 +76,43 @@ def numbers(config: dict, answers, refs: dict) -> dict:
 
 
 def check(config: dict, run, seed: int) -> tuple[bool, dict]:
-    """``(correct, {name: (number, limit)})`` for a finished run."""
+    """``(verdict, {name: (number, limit)})`` for a finished run."""
     idx = sample(run.answers, config["problems"], seed,
                  int(config["check_sample"]))
     got = numbers(config, run.answers,
                   reference_answers(config, run.answers, idx))
     limits = config["limits"]
     compared = {k: (v, float(limits[k])) for k, v in got.items()}
-    correct = (run.failed == 0 and bool(idx)
-               and all(v <= lim for v, lim in compared.values()))
-    return correct, compared
+    verdict = bool(idx) and all(v <= lim for v, lim in compared.values())
+    return verdict, compared
+
+
+def starts(cell, seed: int, seconds: float) -> list:
+    """The requests of the cell's traffic, unanswered: an open loop's
+    window at the mix's rate, a closed loop's first ``check_sample + 1``
+    starts."""
+    cfg, mix = cell.config, cell.traffic
+    if mix["loop"] == "closed":
+        [entry] = cfg["problems"]
+        return [Answer(0, traffic_gen.closed_loop_start(entry, seed, i))
+                for i in range(int(cfg["check_sample"]) + 1)]
+    return [Answer(a.problem, a.x0) for a in
+            traffic_gen.open_loop(mix, cfg["problems"], seed, seconds)]
+
+
+def control_numbers(cell, seed: int, seconds: float):
+    """(numbers, limits) of the bfloat16 control on one seed: the check's
+    sample of the cell's traffic, each answered by the bfloat16 reference
+    and compared, as a run compares the program, with the float32 one."""
+    cfg = cell.config
+    answers = starts(cell, seed, seconds)
+    for a in answers:              # every request counts as answered
+        a.best_f, a.iterations = 0.0, 0
+    idx = sample(answers, cfg["problems"], seed, int(cfg["check_sample"]))
+    low = reference_answers(cfg, answers, idx, dtype="bfloat16")
+    for i, ref in low.items():
+        answers[i].best_x = ref.best_x
+        answers[i].best_f = float(ref.best_f)
+        answers[i].iterations = ref.iterations
+    got = numbers(cfg, answers, reference_answers(cfg, answers, idx))
+    return got, {k: float(v) for k, v in cfg["limits"].items()}

@@ -4,7 +4,12 @@
   (``bench/configs/<c>.json``);
 * traffic mix ``<t>``: ``bench/traffic/<t>.json``;
 * per-layer metric ``<m>``: the reader ``bench/metrics/<m>.py``, whose
-  ``read(run)`` returns a number, or None where it finds nothing to read.
+  ``read(run)`` returns a number, or None where it finds nothing to read;
+* a configuration's check, the name under its ``"check"`` key: the module
+  ``bench/checks/<name>.py``, whose ``check(config, run, seed)`` returns
+  ``(verdict, {name: (number, limit)})`` for a finished run and whose
+  ``control_numbers(cell, seed, seconds)`` returns ``(numbers, limits)``
+  of its control.
 
 A cell reports the end-to-end metrics that list it under ``workloads``
 (or list no cells), and the per-layer metrics that list it, or that list
@@ -30,6 +35,7 @@ class Cell:
     traffic: dict
     end_to_end: list          # BENCHMARK.json entries
     per_layer: list           # BENCHMARK.json entries
+    root: Path = CHECKOUT     # the checkout its files came from
 
     @property
     def chips(self) -> int:
@@ -66,16 +72,27 @@ def load_cell(name: str, root: Path = CHECKOUT) -> Cell:
     per_layer = [m for m in spec["per_layer"]
                  if (name in m["workloads"] if "workloads" in m
                      else m["moves"] in e2e_names)]
-    return Cell(name, workload, config, traffic, e2e, per_layer)
+    return Cell(name, workload, config, traffic, e2e, per_layer, root)
+
+
+def _load_module(path: Path, kind: str):
+    if not path.is_file():
+        raise FileNotFoundError(f"missing {kind} {path}")
+    mod_spec = importlib.util.spec_from_file_location(
+        f"bench_{kind.replace(' ', '_')}_"
+        + path.stem.replace(".", "_").replace("-", "_"), path)
+    module = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(module)
+    return module
 
 
 def metric_reader(name: str, root: Path = CHECKOUT):
     """The ``read(run)`` function of per-layer metric ``name``."""
-    path = root / "bench" / "metrics" / f"{name}.py"
-    if not path.is_file():
-        raise FileNotFoundError(f"missing metric reader {path}")
-    mod_spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    module = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(module)
-    return module.read
+    return _load_module(root / "bench" / "metrics" / f"{name}.py",
+                        "metric reader").read
+
+
+def check_module(name: str, root: Path = CHECKOUT):
+    """The module of check ``name``: its ``check`` and
+    ``control_numbers``."""
+    return _load_module(root / "bench" / "checks" / f"{name}.py", "check")

@@ -10,9 +10,11 @@ warm-up of every program the cell's traffic uses) is timed as
 ``--trace 0`` the last line of standard output reports the cell's
 end-to-end metrics; with ``--trace 1`` the last part of the window is
 traced and the line reports its per-layer metrics and a ``breakdown``.
-Either way the answers are checked against the plain reference, and the
-numbers compared are printed with their limits as the last lines of
-standard error and under ``check`` in the result line.
+Either way the answers are checked by the check the configuration names
+(``"check"``: ``bench/checks/<name>.py``), and the numbers compared are
+printed with their limits as the last lines of standard error and under
+``check`` in the result line. A run is correct where every request due
+was answered without an error and the check finds the answers right.
 
 The run fails, printing no result, where JAX finds no TPU or fewer chips
 than the cell asks for.
@@ -33,7 +35,6 @@ BENCH = Path(__file__).resolve().parent
 sys.path.insert(0, str(BENCH))
 sys.path.insert(0, str(BENCH.parent / "src"))
 
-import check  # noqa: E402
 import drive  # noqa: E402
 import e2e  # noqa: E402
 import harness  # noqa: E402
@@ -90,9 +91,13 @@ def execute(cell: harness.Cell, seed: int, seconds: float, trace: bool,
     if cell.config["entry"] not in DRIVERS:
         raise ValueError(f"{cell.name}: no driver for entry "
                          f"{cell.config['entry']!r}")
+    checker = harness.check_module(cell.config["check"], cell.root)
     run = DRIVERS[cell.config["entry"]](cell, seed, seconds, trace,
                                         compiles, t_start)
-    correct, compared = check.check(cell.config, run, seed)
+    t_check = time.perf_counter()
+    verdict, compared = checker.check(cell.config, run, seed)
+    run.notes.append(f"check_s {time.perf_counter() - t_check!r}")
+    correct = run.failed == 0 and verdict
     out = {"correct": correct, "attempted": run.attempted,
            "failed": run.failed}
     dev = {"platform": device.platform, "kind": device.device_kind,
@@ -102,7 +107,7 @@ def execute(cell: harness.Cell, seed: int, seconds: float, trace: bool,
         ctx = Context(run, cell, peak)
         metrics = {}
         for m in cell.per_layer:
-            value = harness.metric_reader(m["name"])(ctx)
+            value = harness.metric_reader(m["name"], cell.root)(ctx)
             if value is not None:
                 metrics[m["name"]] = {"value": value, "unit": m["unit"]}
         out["metrics"] = metrics
@@ -111,6 +116,10 @@ def execute(cell: harness.Cell, seed: int, seconds: float, trace: bool,
             dev["window_s"] = run.trace.window_s
             out["breakdown"] = {"device_ops": run.trace.top_ops,
                                 "idle_gaps": run.trace.idle_gaps}
+            run.notes.append(
+                f"trace read_s {run.trace.read_s!r}; module runs whole "
+                f"{len(run.trace.module_runs)}; device s by scope "
+                f"{run.trace.scope_time!r}")
     else:
         out["metrics"] = {m["name"]: {"value": e2e.METRICS[m["name"]](run),
                                       "unit": m["unit"]}

@@ -84,6 +84,52 @@ def test_bfloat16_control_of_a_solve_cell_is_not_correct():
     assert any(got[k] > limits[k] for k in got), got
 
 
+# The numbers of the check and of its control on fixed seeds, as the
+# harness computed them before each configuration named its check (the
+# functions then in bench/check.py and bench/control.py): eight of each
+# cell's sample, every third answer cut to two iterations a resolution.
+SAME_AS_BEFORE = {
+    SERVE: (2**31 + 101, 10 / 128,
+            {"f_gap": 1.3417367935180664, "x_gap": 0.0965706294600526,
+             "iters_gap": 0.7669491525423728},
+            {"f_gap": 19.3273286819458, "x_gap": 1.0055956809473798e-06,
+             "iters_gap": 0.9494949494949495}),
+    SOLVE: (-(2**31) - 103, 1.0,
+            {"f_gap": 0.9472942637893917, "x_gap": 0.14344287317755441,
+             "iters_gap": 0.757201646090535},
+            {"f_gap": 13.586138149523796, "x_gap": 1.0001309276426109e-06,
+             "iters_gap": 0.9615384615384616}),
+}
+
+
+@pytest.mark.parametrize("cell_name", sorted(SAME_AS_BEFORE))
+def test_dgo_reference_check_reads_the_numbers_it_read_before(cell_name):
+    from types import SimpleNamespace
+
+    seed, seconds, want_control, want_check = SAME_AS_BEFORE[cell_name]
+    c = harness.load_cell(cell_name)
+    assert c.config["check"] == "dgo_reference"
+    c.config["check_sample"] = 8
+    cfg = c.config
+    module = harness.check_module("dgo_reference")
+    got, limits = control.control_numbers(c, seed, seconds)
+    assert got == want_control
+    assert limits == {"f_gap": 1e-3, "x_gap": 1e-3, "iters_gap": 0.3}
+    answers = module.starts(c, seed, seconds)
+    for i, a in enumerate(answers):
+        r = reference.run(cfg["problems"][a.problem], a.x0,
+                          max_bits=int(cfg["max_bits"]),
+                          bits_step=int(cfg["bits_step"]),
+                          max_iters=2 if i % 3 == 0
+                          else int(cfg["max_iters"]))
+        a.best_x, a.best_f, a.iterations = r.best_x, float(r.best_f), \
+            r.iterations
+    verdict, compared = module.check(
+        cfg, SimpleNamespace(answers=answers, failed=0), seed)
+    assert compared == {k: (v, limits[k]) for k, v in want_check.items()}
+    assert verdict is False
+
+
 # ---------------------------------------------------------------------------
 # whole runs on the CPU, sound and broken
 # ---------------------------------------------------------------------------

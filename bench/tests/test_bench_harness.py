@@ -112,3 +112,105 @@ def test_a_missing_cell_or_file_is_an_error():
         harness.load_cell("no-such-cell")
     with pytest.raises(FileNotFoundError):
         harness.metric_reader("no_such_metric")
+
+
+# ---------------------------------------------------------------------------
+# a configuration's check, found by the name under its "check" key
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("config", [c["name"] for c in SPEC["configs"]])
+def test_every_configuration_names_a_check_that_resolves(config):
+    [entry] = [c for c in SPEC["configs"] if c["name"] == config]
+    cfg = json.loads((BENCH.parent / entry["file"]).read_text())
+    module = harness.check_module(cfg["check"])
+    assert callable(module.check) and callable(module.control_numbers)
+
+
+STUB_CHECK = '''
+def check(config, run, seed):
+    return config["verdict"], {"gap": (float(seed), 1.0)}
+
+
+def control_numbers(cell, seed, seconds):
+    return {"gap": 2.0 * seed}, {"gap": 1.0}
+'''
+
+
+def stub_checkout(root: Path, check: str = "stub") -> Path:
+    """A checkout holding one cell whose configuration names ``check``,
+    and the module ``bench/checks/stub.py``."""
+    (root / "bench" / "configs").mkdir(parents=True)
+    (root / "bench" / "traffic").mkdir()
+    (root / "bench" / "checks").mkdir()
+    (root / "bench" / "checks" / "stub.py").write_text(STUB_CHECK)
+    (root / "bench" / "configs" / "toy.json").write_text(json.dumps(
+        {"name": "toy", "entry": "stub", "check": check, "verdict": True,
+         "reduced": []}))
+    (root / "bench" / "traffic" / "once.json").write_text('{"loop": "open"}')
+    (root / "BENCHMARK.json").write_text(json.dumps({
+        "configs": [{"name": "toy", "file": "bench/configs/toy.json"}],
+        "workloads": [{"name": "toy.once", "config": "toy",
+                       "traffic": "once", "chips": 1}],
+        "end_to_end": [], "per_layer": []}))
+    return root
+
+
+@pytest.fixture
+def stub_run(monkeypatch, tmp_path):
+    """``run.execute`` on the stub checkout's cell, with a driver that
+    returns a run of ``failed`` failures and runs no program."""
+    from types import SimpleNamespace
+
+    import drive
+    import run
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jax"))
+
+    def execute(cell, failed=0):
+        def driver(cell, seed, seconds, trace, compiles, t_start):
+            return drive.Run(window_s=seconds, setup_s=0.5, attempted=4,
+                             failed=failed, completed_in_window=4,
+                             latencies_s=None, answers=[])
+        monkeypatch.setitem(run.DRIVERS, "stub", driver)
+        return run.execute(cell, 7, 1.0, False,
+                           SimpleNamespace(platform="cpu", device_kind="cpu"),
+                           1, 0.0)
+    return execute
+
+
+@pytest.mark.parametrize("verdict", [True, False])
+def test_a_run_takes_the_verdict_of_the_check_its_configuration_names(
+        stub_run, tmp_path, verdict):
+    cell = harness.load_cell("toy.once", stub_checkout(tmp_path / "co"))
+    assert cell.root == tmp_path / "co"
+    cell.config["verdict"] = verdict
+    out = stub_run(cell)
+    assert out["correct"] is verdict
+    assert out["check"] == {"gap": {"value": 7.0, "limit": 1.0}}
+    assert list(out)[-2:] == ["check", "_notes"]
+    assert any(n.startswith("check_s ") for n in out["_notes"])
+
+
+def test_a_run_with_a_failed_request_is_not_correct_whatever_the_check(
+        stub_run, tmp_path):
+    cell = harness.load_cell("toy.once", stub_checkout(tmp_path / "co"))
+    out = stub_run(cell, failed=1)
+    assert out["check"] == {"gap": {"value": 7.0, "limit": 1.0}}
+    assert out["correct"] is False
+
+
+def test_an_unknown_check_is_an_error(stub_run, tmp_path):
+    with pytest.raises(FileNotFoundError, match="check"):
+        harness.check_module("no_such_check")
+    cell = harness.load_cell("toy.once",
+                             stub_checkout(tmp_path / "co", "no_such_check"))
+    with pytest.raises(FileNotFoundError, match="no_such_check"):
+        stub_run(cell)
+
+
+def test_the_control_is_the_check_modules_own(tmp_path):
+    import control
+
+    cell = harness.load_cell("toy.once", stub_checkout(tmp_path / "co"))
+    assert control.control_numbers(cell, 3, 1.0) == ({"gap": 6.0},
+                                                     {"gap": 1.0})
