@@ -63,13 +63,14 @@ from typing import Callable, NamedTuple, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import tracing
 from repro.compat import axis_size, process_index, shard_map
 from repro.core.cache import get_cache
 from repro.core.encoding import (
-    Encoding, decode, decode_np, encode, encode_np,
+    Encoding, decode, decode_np, encode, encode_np, lattice_step,
+    place_levels,
 )
 from repro.core.population import generate_children, segment_patterns
 from repro.kernels.popstep.ops import backend, population_step_ids
@@ -90,8 +91,6 @@ def _place_inputs(mesh: Mesh, *arrays):
     me = process_index()
     if all(d.process_index == me for d in mesh.devices.flat):
         return arrays
-    from jax.sharding import NamedSharding
-
     sharding = NamedSharding(mesh, P())
     return tuple(jax.device_put(a, sharding) for a in arrays)
 
@@ -469,7 +468,7 @@ def make_distributed_engine(f_batch: Callable[[jax.Array], jax.Array],
     traced inside ``shard_map``.
 
     Fixed resolution (``res_bits`` None or a single entry): returns
-    ``engine(x0, quorum_mask) -> (bits, val, iters, trace)`` with
+    ``engine(x0, quorum_mask) -> (bits, val, iters, trace, x)`` with
     ``trace`` a (max_iters + 1,) monotone best-value history (``trace[0]``
     the starting value; entries past ``iters`` padded with the final
     value). The initial encode/evaluation happens inside the program, so
@@ -479,12 +478,17 @@ def make_distributed_engine(f_batch: Callable[[jax.Array], jax.Array],
     iteration and no per-iteration host round-trip exists.
 
     Folded schedule (``res_bits`` with several resolutions): returns
-    ``engine(x0, quorum_mask) -> (best_bits, best_val, best_res_idx,
-    iters, trace)`` where ``best_bits`` is the max-width bit buffer of the
-    best parent found (live prefix ``n_vars * res_bits[best_res_idx]``)
-    and ``trace`` has capacity ``len(res_bits) * max_iters + 1`` (raw
-    per-iteration parent values; escalation re-encodes are not recorded,
-    matching the historical host-chained history).  The resolution counter
+    ``engine(x0, quorum_mask) -> (bits_at, best_val, best_res_idx, iters,
+    trace, best_x)`` where ``bits_at[r]`` is the best parent's bit string
+    at resolution ``res_bits[r]`` (the live prefix of the max-width
+    buffer, ``n_vars * res_bits[r]`` bits; ``bits_at[best_res_idx]`` is
+    the one it was found at), ``best_x`` that parent decoded at its own
+    resolution, and ``trace`` has capacity ``len(res_bits) * max_iters +
+    1`` (raw per-iteration parent values; escalation re-encodes are not
+    recorded, matching the historical host-chained history).  The
+    fixed-resolution engine likewise ends its outputs with ``x``, the
+    final parent decoded: a solve needs no device program after the
+    engine's, and no transfer back to the device.  The resolution counter
     rides the while_loop state and indexes the stacked
     ``population.schedule_tables`` — the whole schedule is still ONE
     dispatch and ONE compilation.  The schedule path always uses the
@@ -505,6 +509,8 @@ def make_distributed_engine(f_batch: Callable[[jax.Array], jax.Array],
         n_shards = plan.n_shards
         n_res = tables.n_res
         t_max = n_res * max_iters + 1
+        steps = np.asarray([lattice_step(enc.with_bits(b))
+                            for b in schedule], np.float32)
 
         def shard_schedule_engine(x0, quorum_mask):
             r0 = jnp.int32(0)
@@ -566,13 +572,18 @@ def make_distributed_engine(f_batch: Callable[[jax.Array], jax.Array],
              trace) = s
             idx = jnp.arange(t_max)
             trace = jnp.where(idx <= iters, trace, val)
-            return best_bits, best_val, best_res, iters, trace
+            # the reported point, rounded as encoding.decode rounds it
+            levels = best_bits.astype(jnp.float32) @ tables.wmat[best_res]
+            best_x = place_levels(levels, enc.lo,
+                                  jnp.asarray(steps)[best_res])
+            bits_at = tuple(best_bits[: enc.n_vars * b] for b in schedule)
+            return bits_at, best_val, best_res, iters, trace, best_x
 
         replicated = P()
         mapped = shard_map(
             shard_schedule_engine, mesh=mesh,
             in_specs=(replicated, replicated),
-            out_specs=(replicated,) * 5,
+            out_specs=((replicated,) * n_res,) + (replicated,) * 5,
             check_vma=False)
         return jax.jit(mapped)
 
@@ -610,13 +621,16 @@ def make_distributed_engine(f_batch: Callable[[jax.Array], jax.Array],
         bits, val, _, iters, trace = jax.lax.while_loop(cond, body, s0)
         idx = jnp.arange(max_iters + 1)
         trace = jnp.where(idx <= iters, trace, val)   # pad for clean plots
-        return bits, val, iters, trace
+        # the reported point, rounded as encoding.decode rounds it
+        levels = bits.astype(jnp.float32) @ jnp.asarray(_decode_matrix(enc))
+        x = place_levels(levels, enc.lo, lattice_step(enc))
+        return bits, val, iters, trace, x
 
     replicated = P()
     mapped = shard_map(
         shard_engine, mesh=mesh,
         in_specs=(replicated, replicated),
-        out_specs=(replicated,) * 4,
+        out_specs=(replicated,) * 5,
         check_vma=False)
     return jax.jit(mapped)
 
@@ -627,6 +641,8 @@ def make_distributed_engine(f_batch: Callable[[jax.Array], jax.Array],
 # compiled program; unhashable objectives build uncached instead of raising,
 # and hit/miss counters surface in BENCH_distributed.json
 _ENGINES = get_cache("distributed.engine")
+# the all-alive quorum mask per (mesh, population axes), already placed
+_QUORUMS = get_cache("distributed.quorum")
 
 
 def _step_for(f, enc, mesh, pop_axes, virtual_block, inner, interpret,
@@ -663,25 +679,31 @@ def _batched_engine_for(f, enc, mesh, n_restarts, pop_axes, max_iters,
                                                 res_bits=res_bits))
 
 
+def _full_quorum(mesh: Mesh, pop_axes: tuple) -> jax.Array:
+    """The all-alive quorum mask of ``pop_axes``, placed on ``mesh`` once
+    and reused by every solve there."""
+    return _QUORUMS.get(
+        (mesh, pop_axes),
+        lambda: jax.device_put(np.ones((_axis_prod(mesh, pop_axes),), bool),
+                               NamedSharding(mesh, P())))
+
+
+class DistributedRun(NamedTuple):
+    """One distributed optimization's best parent and its history."""
+
+    x: jax.Array           # (n_vars,) the best parent, decoded
+    bits: jax.Array        # its bit string at its own resolution
+    val: jax.Array         # () its value
+    history: list          # raw per-iteration parent values (floats)
+    bits_resolution: int   # bits per variable of ``bits``
+
+
 def _run_fixed_resolution(f, enc, mesh, x0, pop_axes, max_iters,
                           virtual_block, quorum_mask, inner, interpret,
-                          driver, injector, tile_p):
-    """One fixed-resolution distributed run at ``enc.bits``; returns
-    ``(bits, val, history)`` — the per-resolution unit the host driver
-    chains (the device driver folds the whole schedule instead)."""
-
+                          injector, tile_p):
+    """One host-stepped run at ``enc.bits``; returns ``(bits, val,
+    history)`` — the per-resolution unit the host driver chains."""
     n_shards = _axis_prod(mesh, pop_axes)
-
-    if driver == "device":
-        engine = _engine_for(f, enc, mesh, pop_axes, max_iters,
-                             virtual_block, inner, interpret, tile_p)
-        bits, val, iters, trace = engine(jnp.asarray(x0, jnp.float32),
-                                         quorum_mask)
-        # ONE device->host transfer for the whole history
-        iters_h, trace_h = jax.device_get((iters, trace))
-        history = [float(v) for v in trace_h[: int(iters_h) + 1]]
-        return bits, val, history
-
     bits = encode(jnp.asarray(x0, jnp.float32), enc)
     val = f(decode(bits, enc))
     step = _step_for(f, enc, mesh, pop_axes, virtual_block, inner,
@@ -715,10 +737,46 @@ def _run_fixed_resolution(f, enc, mesh, x0, pop_axes, max_iters,
     return bits, val, history
 
 
+def _run_on_device(f, enc, mesh, x0, pop_axes, max_iters, virtual_block,
+                   quorum_mask, inner, interpret, tile_p,
+                   schedule: tuple) -> DistributedRun:
+    """The device driver: one engine call and one ``device_get``, and no
+    other device program or transfer.  The engine decodes the best parent
+    and cuts its bit string at every resolution itself, so ``x``, ``val``
+    and ``bits`` are its own output arrays; the fetch caches the host
+    values of ``x`` and ``val``, and the history is cut in numpy."""
+    folded = len(schedule) > 1
+    engine = _engine_for(f, enc.with_bits(schedule[0]), mesh, pop_axes,
+                         max_iters, virtual_block, inner, interpret, tile_p,
+                         res_bits=schedule if folded else None)
+    with tracing.span("solve.place"):
+        # a JAX start point stays one; anything else goes as numpy, which
+        # the engine call transfers itself on one process
+        x0 = (jnp.asarray(x0, jnp.float32) if isinstance(x0, jax.Array)
+              else np.asarray(x0, np.float32))
+        quorum = (_full_quorum(mesh, pop_axes) if quorum_mask is None
+                  else quorum_mask)
+        x0, quorum = _place_inputs(mesh, x0, quorum)
+    outs = engine(x0, quorum)
+    with tracing.span("solve.fetch"):
+        if folded:
+            bits, val, res, iters, trace, x = outs
+        else:
+            bits, val, iters, trace, x = outs
+            res = 0
+        _, _, res_h, iters_h, trace_h = jax.device_get(
+            (x, val, res, iters, trace))
+    with tracing.span("solve.assemble"):
+        history = trace_h[: int(iters_h) + 1].tolist()
+        if folded:
+            bits = bits[int(res_h)]
+    return DistributedRun(x, bits, val, history, schedule[int(res_h)])
+
+
 def _run_distributed(f: Callable[[jax.Array], jax.Array],
                     enc: Encoding,
                     mesh: Mesh,
-                    x0: jax.Array,
+                    x0,
                     pop_axes: Sequence[str] = ("data",),
                     max_iters: int = 256,
                     virtual_block: int = 256,
@@ -728,7 +786,7 @@ def _run_distributed(f: Callable[[jax.Array], jax.Array],
                     driver: str = "device",
                     injector=None,
                     tile_p: int | None = None,
-                    res_bits: Sequence[int] | None = None):
+                    res_bits: Sequence[int] | None = None) -> DistributedRun:
     """Distributed DGO over the resolution schedule ``res_bits`` (``None``
     -> fixed at ``enc.bits``).
 
@@ -736,7 +794,13 @@ def _run_distributed(f: Callable[[jax.Array], jax.Array],
     multi-resolution ``res_bits`` is folded into the single compiled
     ``lax.while_loop`` (see ``make_distributed_engine``), so one
     optimization stays ONE dispatch regardless of how many resolutions it
-    escalates through, and the value history is fetched in one transfer.
+    escalates through.  Such a run touches the device twice: the engine
+    call, which takes the start point as it is (the full-quorum mask is
+    placed on the mesh once), and ONE ``device_get`` of every value the
+    result needs.  The engine decodes the best parent and cuts its bit
+    string itself, so ``x``, ``val`` and ``bits`` are the engine's own
+    arrays, the host values of ``x`` and ``val`` already fetched; the
+    history is cut in numpy.
     ``driver="host"`` keeps the Python-stepped loop (chaining resolutions
     from the host) so host-side policy can interpose between iterations:
     an optional ``injector`` (``runtime.failure.FailureInjector``; host
@@ -754,11 +818,12 @@ def _run_distributed(f: Callable[[jax.Array], jax.Array],
     consecutive non-improving rounds) before a child can be declared
     unreachable.
 
-    Returns ``(bits, val, history, bits_resolution)``: the best parent's
-    bit string at its own resolution ``bits_resolution`` (bits per
+    Returns a :class:`DistributedRun`: the best parent decoded (``x``),
+    its bit string at its own resolution ``bits_resolution`` (bits per
     variable), its value, and the raw per-iteration value history
     (``history[0]`` the starting value; escalation re-encodes are not
-    recorded).
+    recorded) — JAX arrays for ``x``, ``bits`` and ``val`` from either
+    driver.
     """
     if driver not in ("device", "host"):
         raise ValueError(f"driver must be 'device' or 'host', got {driver!r}")
@@ -766,28 +831,14 @@ def _run_distributed(f: Callable[[jax.Array], jax.Array],
         raise ValueError("failure injection requires driver='host' — the "
                          "on-device loop cannot interpose host policy")
     pop_axes = tuple(pop_axes)
-    n_shards = _axis_prod(mesh, pop_axes)
-    if quorum_mask is None:
-        quorum_mask = jnp.ones((n_shards,), bool)
     schedule = _resolve_res_bits(enc, res_bits)
+    if driver == "device":
+        return _run_on_device(f, enc, mesh, x0, pop_axes, max_iters,
+                              virtual_block, quorum_mask, inner, interpret,
+                              tile_p, schedule)
 
-    if driver == "device" and len(schedule) > 1:
-        # the folded path: schedule escalation inside the while_loop —
-        # one engine build + one dispatch per schedule signature
-        engine = _engine_for(f, enc.with_bits(schedule[0]), mesh, pop_axes,
-                             max_iters, virtual_block, inner, interpret,
-                             tile_p, res_bits=schedule)
-        x0_d, quorum_d = _place_inputs(
-            mesh, jnp.asarray(x0, jnp.float32), quorum_mask)
-        best_bits, best_val, best_res, iters, trace = engine(
-            x0_d, quorum_d)
-        iters_h, trace_h, best_res_h = jax.device_get(
-            (iters, trace, best_res))
-        history = [float(v) for v in trace_h[: int(iters_h) + 1]]
-        b = schedule[int(best_res_h)]
-        bits = best_bits[: enc.n_vars * b]      # live prefix of the buffer
-        return bits, best_val, history, b
-
+    if quorum_mask is None:
+        quorum_mask = _full_quorum(mesh, pop_axes)
     (x,) = _place_inputs(mesh, jnp.asarray(x0, jnp.float32))
     history: list[float] = []
     best = None   # (float val, device val, bits, bits-per-var)
@@ -795,13 +846,14 @@ def _run_distributed(f: Callable[[jax.Array], jax.Array],
         enc_b = enc.with_bits(b)
         bits, val, hist = _run_fixed_resolution(
             f, enc_b, mesh, x, pop_axes, max_iters, virtual_block,
-            quorum_mask, inner, interpret, driver, injector, tile_p)
+            quorum_mask, inner, interpret, injector, tile_p)
         history.extend(hist if i == 0 else hist[1:])
         if best is None or float(val) < best[0]:
             best = (float(val), val, bits, b)
         x = decode(bits, enc_b)
     _, best_val, best_bits, best_b = best
-    return best_bits, best_val, history, best_b
+    return DistributedRun(decode(best_bits, enc.with_bits(best_b)),
+                          best_bits, best_val, history, best_b)
 
 
 # ---------------------------------------------------------------------------

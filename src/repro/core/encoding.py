@@ -61,13 +61,33 @@ def encode(x: jax.Array, enc: Encoding) -> jax.Array:
     return bits.reshape(*x.shape[:-1], enc.n_bits).astype(jnp.int8)
 
 
+def lattice_step(enc: Encoding) -> float:
+    """The spacing of ``enc``'s lattice, as :func:`decode` scales by it."""
+    return (enc.hi - enc.lo) / (enc.levels - 1)
+
+
 def decode(bits: jax.Array, enc: Encoding) -> jax.Array:
     """Bit string (..., n_vars * bits) -> float vector (..., n_vars)."""
     b = bits.reshape(*bits.shape[:-1], enc.n_vars, enc.bits).astype(jnp.uint32)
     weights = (jnp.uint32(1) << jnp.arange(enc.bits - 1, -1, -1, dtype=jnp.uint32))
     level = jnp.sum(b * weights, axis=-1).astype(jnp.float32)
-    span = enc.hi - enc.lo
-    return enc.lo + level * (span / (enc.levels - 1))
+    return enc.lo + level * lattice_step(enc)
+
+
+def place_levels(level: jax.Array, lo: float, step) -> jax.Array:
+    """``lo + level * step`` inside a compiled program, rounded as
+    :func:`decode` rounds it when its operations run one at a time: the
+    product to float32, then the sum, so an engine reports the point an
+    eager :func:`decode` of its bits gives.  Where the product meets the
+    sum directly, XLA's CPU backend contracts the two into one fused
+    multiply-add, which rounds once and moves the last bit of about half
+    the points (an optimization barrier is gone by then); the select
+    keeps them apart.  It returns the product unchanged unless that is
+    NaN, and so only while the compiler cannot prove the product a
+    number: ``level`` must come from a computation it cannot see through
+    (a matmul), not from integer bits converted in the same fusion."""
+    p = level * step
+    return lo + jnp.where(p == p, p, 0.0)
 
 
 def encode_np(x, enc: Encoding) -> np.ndarray:
@@ -90,8 +110,7 @@ def decode_np(bits, enc: Encoding) -> np.ndarray:
     b = b.reshape(*b.shape[:-1], enc.n_vars, enc.bits).astype(np.uint32)
     weights = (np.uint32(1) << np.arange(enc.bits - 1, -1, -1)).astype(np.uint32)
     level = (b * weights).sum(axis=-1).astype(np.float32)
-    span = enc.hi - enc.lo
-    return enc.lo + level * np.float32(span / (enc.levels - 1))
+    return enc.lo + level * np.float32(lattice_step(enc))
 
 
 def reencode(bits: jax.Array, enc_from: Encoding, enc_to: Encoding) -> jax.Array:

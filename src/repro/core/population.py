@@ -175,8 +175,11 @@ class ScheduleTables(NamedTuple):
     def decode(self, bits: jax.Array, res_idx: jax.Array) -> jax.Array:
         """(..., n_max) bit buffer -> (..., n_vars) floats at resolution
         ``res_idx``.  The integer matmul is exact in f32 (weights are
-        powers of two < 2^24) and the affine map is applied afterwards, so
-        rounding matches ``encoding.decode`` bit-for-bit."""
+        powers of two < 2^24) and the affine map is applied afterwards.
+        The step is the float32 quotient of float32 operands, and XLA
+        may fuse the map into one multiply-add, so a point can differ in
+        its last bit from ``encoding.decode``'s; the engines report their
+        best point through ``encoding.place_levels`` instead."""
         levels = bits.astype(jnp.float32) @ self.wmat[res_idx]
         return self.lo + levels * self.scale[res_idx]
 

@@ -50,7 +50,7 @@ from repro.compat import AxisType, make_mesh, pure_callback
 from repro.core import objectives as objectives_registry
 from repro.core.cache import get_cache
 from repro.core.dgo import DGOConfig
-from repro.core.encoding import Encoding, decode, decode_np
+from repro.core.encoding import Encoding, decode_np
 from repro.core.objectives import Objective
 
 __all__ = [
@@ -559,9 +559,17 @@ class Distributed(Strategy):
     dispatch for the whole schedule — ``driver="host"`` chains
     resolutions from Python instead so policy can interpose).
 
-    extras: ``bits`` (final parent bit string at the best resolution),
-    ``history`` (raw per-iteration parent values, list of floats),
-    ``schedule`` (resolutions run), ``bits_resolution``.
+    A ``driver="device"`` solve is one engine call and one fetch, and no
+    other device program: the engine takes the start point as it is (the
+    full-quorum mask is placed on the mesh once), decodes the best point
+    and cuts its bit string itself, and one ``device_get`` brings back
+    every value the result needs.  ``best_x``, ``best_f`` and
+    ``extras["bits"]`` are the engine's own JAX arrays, the host values
+    of the first two already fetched; the types are those of before.
+
+    extras: ``bits`` (final parent bit string at the best resolution, a
+    JAX int8 array), ``history`` (raw per-iteration parent values, list
+    of floats), ``schedule`` (resolutions run), ``bits_resolution``.
     """
 
     name: ClassVar[str] = "distributed"
@@ -589,21 +597,19 @@ class Distributed(Strategy):
         # folds it into its single compiled while_loop, the host driver
         # chains resolutions internally — no facade-level dispatch loop
         schedule = _resolution_schedule(enc0, self.max_bits, self.bits_step)
-        bits, val, history, best_b = distributed._run_distributed(
-            problem.jax_fn, enc0, mesh, jnp.asarray(x0, jnp.float32),
+        run = distributed._run_distributed(
+            problem.jax_fn, enc0, mesh, x0,
             pop_axes=tuple(self.pop_axes), max_iters=mi,
             virtual_block=self.virtual_block, quorum_mask=self.quorum_mask,
             inner=self.inner, interpret=self.interpret, driver=self.driver,
             injector=self.injector, tile_p=self.tile_p,
             res_bits=tuple(schedule))
-        best_enc = enc0.with_bits(best_b)
-        trace = np.minimum.accumulate(np.asarray(history, np.float32))
-        return SolveResult(best_x=decode(bits, best_enc),
-                           best_f=val,
-                           iterations=len(history) - 1, trace=trace,
-                           extras={"bits": bits,
-                                   "bits_resolution": best_b,
-                                   "history": history,
+        trace = np.minimum.accumulate(np.asarray(run.history, np.float32))
+        return SolveResult(best_x=run.x, best_f=run.val,
+                           iterations=len(run.history) - 1, trace=trace,
+                           extras={"bits": run.bits,
+                                   "bits_resolution": run.bits_resolution,
+                                   "history": run.history,
                                    "schedule": tuple(schedule)})
 
 
@@ -721,21 +727,23 @@ def solve(problem, strategy="fused", *, seed: int | jax.Array = 0,
     result, ``"raise"`` raises :class:`NonFiniteResult` — a NaN
     objective can never masquerade as an optimum either way.
 
-    Every strategy returns the same :class:`SolveResult` pytree.
+    Every strategy returns the same :class:`SolveResult` pytree.  The
+    call runs under the host span ``dgo.solve``.
     """
-    prob = as_problem(problem)
-    strat = as_strategy(strategy)
-    if x0 is not None:
-        key = None               # pinned start: skip key construction
-    elif isinstance(seed, (jax.Array, np.ndarray)):
-        key = jnp.asarray(seed)
-    else:
-        key = jax.random.PRNGKey(int(seed))
-    res = strat._solve(prob, key=key, x0=x0, max_iters=max_iters)
-    if prob.signature is not None:      # subspace-family extras key
-        res.extras["problem_signature"] = prob.signature
-    return _apply_result_hygiene(res, on_nonfinite,
-                                 f"solve({prob.name!r}, {strat.name!r})")
+    with tracing.span("solve"):
+        prob = as_problem(problem)
+        strat = as_strategy(strategy)
+        if x0 is not None:
+            key = None               # pinned start: skip key construction
+        elif isinstance(seed, (jax.Array, np.ndarray)):
+            key = jnp.asarray(seed)
+        else:
+            key = jax.random.PRNGKey(int(seed))
+        res = strat._solve(prob, key=key, x0=x0, max_iters=max_iters)
+        if prob.signature is not None:      # subspace-family extras key
+            res.extras["problem_signature"] = prob.signature
+        return _apply_result_hygiene(res, on_nonfinite,
+                                     f"solve({prob.name!r}, {strat.name!r})")
 
 
 # ---------------------------------------------------------------------------

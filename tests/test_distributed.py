@@ -7,6 +7,8 @@ import sys
 import textwrap
 from pathlib import Path
 
+import pytest
+
 
 ROOT = Path(__file__).resolve().parents[1]
 
@@ -347,3 +349,125 @@ def test_subspace_dgo_train_step_descends():
         print(json.dumps({"ok": True, "v0": v0, "v": float(val)}))
     """)
     assert json.loads(out.splitlines()[-1])["ok"]
+
+
+# A device-driver solve's answers as the commit before it became one
+# engine call and one fetch returned them (1 and 4 devices alike): start
+# points and schedules of _ONE_FETCH_CODE; histories and traces as the
+# length and a SHA-256 prefix of their float32 bytes.
+_PINNED = {
+    "quadratic:3": {
+        "best_x": [1.2156867980957031, 1.2941179275512695,
+                   1.2156867980957031],
+        "best_f": 0.00426219729706645, "iterations": 15,
+        "bits": "100011111001000010001111", "bits_resolution": 8,
+        "history": [16, "a91bb710a415770a"], "trace": "89b5a6a76c3867bb"},
+    "rastrigin:2": {
+        "best_x": [0.993992805480957, 0.993992805480957],
+        "best_f": 1.9902877807617188, "iterations": 12,
+        "bits": "100110001101100110001101", "bits_resolution": 12,
+        "history": [13, "a992ee4ba2e1ba1a"], "trace": "a96fe71670a27d3a"},
+    "rastrigin:6": {
+        "best_x": [-0.0003123283386230469, 0.00031280517578125,
+                   -0.0003123283386230469, -1.9898087978363037,
+                   0.00031280517578125, -0.0003123283386230469],
+        "best_f": 3.9799270629882812, "iterations": 43,
+        "bits": "01111111111111100000000000000111111111111101001110"
+                "0100001000000000000001111111111111",
+        "bits_resolution": 14,
+        "history": [44, "492af11f16d89361"], "trace": "f43b96410b18403f"},
+    "ackley:3": {
+        "best_x": [0.019608020782470703, -0.0196075439453125,
+                   -0.0196075439453125],
+        "best_f": 0.0988006591796875, "iterations": 12,
+        "bits": "100000000111111101111111", "bits_resolution": 8,
+        "history": [13, "e11224ba01f2db4c"], "trace": "e11224ba01f2db4c"},
+}
+
+_ONE_FETCH_CODE = """
+import hashlib, json
+import jax, numpy as np
+from jax._src import array
+from repro.core.encoding import decode
+from repro.core.solver import Distributed, Problem, solve
+
+CASES = [("quadratic", 3, [4.0, -3.0, 6.5], 12),
+         ("rastrigin", 2, [3.1, -2.2], 12),
+         ("rastrigin", 6, [2.5, -1.25, 0.3, 4.4, -3.9, 1.7], 14),
+         ("ackley", 3, [2.0, -4.0, 1.0], None)]     # fixed resolution
+
+def digest(values):
+    return hashlib.sha256(
+        np.asarray(values, np.float32).tobytes()).hexdigest()[:16]
+
+# every device_get, and every host read of an array's value that is not
+# one: the CPU's transfer guard lets implicit reads pass, and keeps no
+# copy of a fetched value (a chip's fetch caches it), so count reads of
+# arrays that no fetch covered
+fetches, implicit, inside = [], [], [False]
+device_get, value = jax.device_get, array.ArrayImpl._value
+
+def counted_get(x):
+    fetches.append(jax.tree.leaves(x))
+    inside[0] = True
+    try:
+        return device_get(x)
+    finally:
+        inside[0] = False
+
+def watched(self):
+    if not inside[0] and not any(a is self for f in fetches for a in f):
+        implicit.append(self.shape)
+    return value.fget(self)
+
+out = {}
+for name, n, x0, max_bits in CASES:
+    prob, strat = Problem.get(name, n=n), Distributed(max_bits=max_bits)
+    x0 = np.asarray(x0, np.float32)
+    solve(prob, strat, x0=x0 + 0.5, max_iters=64)          # warm-up
+    jax.device_get, array.ArrayImpl._value = counted_get, property(watched)
+    try:
+        with jax.transfer_guard_device_to_host("disallow"):
+            res = solve(prob, strat, x0=x0, max_iters=64)
+            in_solve = list(implicit)
+            best_f, best_x = float(res.best_f), np.asarray(res.best_x)
+    finally:
+        jax.device_get, array.ArrayImpl._value = device_get, value
+    b = res.extras["bits_resolution"]
+    ref = decode(res.extras["bits"], prob.encoding.with_bits(b))
+    out[f"{name}:{n}"] = {
+        "fetches": len(fetches), "implicit": in_solve,
+        "arrays": [isinstance(a, jax.Array) for a in
+                   (res.best_x, res.best_f, res.extras["bits"])],
+        "decoded": bool(np.array_equal(best_x, np.asarray(ref))),
+        "pinned": {
+            "best_x": best_x.tolist(), "best_f": best_f,
+            "iterations": res.iterations,
+            "bits": "".join(map(str, np.asarray(res.extras["bits"]))),
+            "bits_resolution": b,
+            "history": [len(res.extras["history"]),
+                        digest(res.extras["history"])],
+            "trace": digest(res.trace)}}
+    fetches.clear()
+    implicit.clear()
+print(json.dumps({"n_dev": jax.device_count(), "cases": out}))
+"""
+
+
+@pytest.mark.parametrize("n_dev", [1, 4])
+def test_device_solve_is_one_engine_call_and_one_fetch(n_dev):
+    """A warmed device-driver solve (the folded schedule and the fixed
+    resolution) fetches once and reads no value outside that fetch, even
+    under the transfer guard; ``best_x``, ``best_f`` and ``bits`` stay
+    JAX arrays, ``best_x`` is bitwise the eager decode of ``bits``, and
+    every answer is bitwise what the commit before returned."""
+    got = json.loads(run_with_devices(_ONE_FETCH_CODE, n_dev)
+                     .splitlines()[-1])
+    assert got["n_dev"] == n_dev
+    assert set(got["cases"]) == set(_PINNED)
+    for case, r in got["cases"].items():
+        assert r["fetches"] == 1, (case, r["fetches"])
+        assert r["implicit"] == [], (case, r["implicit"])
+        assert r["arrays"] == [True, True, True], case
+        assert r["decoded"], case
+        assert r["pinned"] == _PINNED[case], case

@@ -93,6 +93,22 @@ def test_popstep_engine_compiles_for_one_v5e(topo):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("n_chips", [1, 4])
+def test_single_solve_engine_compiles_for_v5e(topo, n_chips):
+    """The folded single-solve engine at rastrigin:40 and a 8..16-bit
+    schedule, which now ends by decoding its best point: six outputs,
+    the last the (40,) point, on one chip and over a 2x2 mesh."""
+    obj = rastrigin(40)
+    mesh = mesh_from_devices(np.array(topo.devices[:n_chips]), ("data",))
+    engine = distributed.make_distributed_engine(
+        jax.vmap(obj.fn), obj.encoding, mesh, res_bits=(8, 10, 12, 14, 16))
+    shape = _replicated(mesh)
+    compiled = engine.lower(shape((40,), jnp.float32),
+                            shape((n_chips,), jnp.bool_)).compile()
+    outs = compiled.out_info
+    assert len(outs) == 6 and outs[-1].shape == (40,)
+
+
 def test_folded_batched_engine_partitions_over_four_v5e(topo):
     """The serving engine at rastrigin:40, 16 slots and a 8..16-bit
     schedule over a 2x2 mesh: the per-iteration gather of (value, id)
