@@ -1,20 +1,9 @@
-"""Distributed-driver benchmark: host-stepped loop vs on-device while_loop
-vs batched multi-start, on the paper's n=9 problem (bits=7 -> N=63,
-125 children — the config that filled MP-1's 128 PEs).
+"""Distributed-engine benchmark: the on-device while_loop vs batched
+multi-start, on the paper's n=9 problem (bits=7 -> N=63, 125 children —
+the config that filled MP-1's 128 PEs).
 
-Three loop forms are measured over the SAME optimization:
-
-* ``host_loop``   — the pre-PR form: one jitted step dispatch per iteration
-  plus a ``float(val)`` + ``bool(improved)`` host round-trip per iteration
-  (the dispatch-latency-dominated regime the Amdahl-style analysis in
-  ISSUE/PAPERS describes).
-* ``host_driver`` — the retained ``Distributed(driver="host")``: still
-  one dispatch + one convergence bool per iteration, but the value history
-  stays on device until the end.
-* ``device_loop`` — ``Distributed(driver="device")``: the entire loop
-  is one ``lax.while_loop`` inside ``shard_map``; one dispatch per
-  optimization.
-
+``device_loop`` is ``Distributed()``: the entire loop is one
+``lax.while_loop`` inside ``shard_map``; one dispatch per optimization.
 Plus ``Batched`` with R=8 restarts (one compiled loop for the whole batch)
 against R * single-run wall-clock, the ``Sequential`` strategy as the
 absolute baseline, and a chained-vs-folded resolution-schedule comparison:
@@ -68,8 +57,7 @@ def _median_time(fn, reps: int) -> float:
 def run(fast: bool = True):
     from repro.compat import AxisType, make_mesh
     from repro.core import cache
-    from repro.core.distributed import make_distributed_step
-    from repro.core.encoding import decode, encode
+    from repro.core.encoding import decode
     from repro.core.solver import (
         Batched,
         Distributed,
@@ -90,7 +78,6 @@ def run(fast: bool = True):
     problem = problem.replace(encoding=enc)
     obj_fn = problem.fn
     x0 = jnp.full((N_VARS,), 5.0)
-    quorum = jnp.ones((n_dev,), bool)
 
     # --- absolute baseline: numpy one-child-at-a-time -----------------------
     t0 = time.perf_counter()
@@ -98,45 +85,14 @@ def run(fast: bool = True):
                 max_iters=MAX_ITERS)
     t_seq = time.perf_counter() - t0
 
-    # --- host_loop: the pre-PR per-iteration-fetch form ---------------------
-    step = make_distributed_step(jax.vmap(obj_fn), enc, mesh)
-
-    def host_loop():
-        bits = encode(x0, enc)
-        val = obj_fn(decode(bits, enc))
-        history = [float(val)]            # <- the per-iteration host sync
-        for _ in range(MAX_ITERS):
-            bits, val, improved = step(bits, val, quorum)
-            history.append(float(val))
-            if not bool(improved):
-                break
-        return val, history
-
-    t_host_loop = _median_time(host_loop, reps)
-    v_host_loop, hist = host_loop()
-    iters = len(hist) - 1
-
-    # --- host_driver: retained driver="host" (batched history fetch) --------
-    def host_driver():
-        return solve(problem, Distributed(mesh=mesh, driver="host"),
-                     x0=x0, max_iters=MAX_ITERS)
-
-    t_host = _median_time(host_driver, reps)
-    r_host = host_driver()
-    v_host, h_host = r_host.best_f, r_host.extras["history"]
-
     # --- device_loop: the on-device while_loop engine -----------------------
     def device_loop():
-        return solve(problem, Distributed(mesh=mesh, driver="device"),
-                     x0=x0, max_iters=MAX_ITERS)
+        return solve(problem, Distributed(mesh=mesh), x0=x0,
+                     max_iters=MAX_ITERS)
 
     t_dev = _median_time(device_loop, reps)
     r_dev = device_loop()
-    v_dev, h_dev = r_dev.best_f, r_dev.extras["history"]
-
-    assert len(h_host) - 1 == iters and len(h_dev) - 1 == iters
-    assert np.isclose(float(v_host), float(v_dev), atol=1e-6)
-    assert np.isclose(float(v_host_loop), float(v_dev), atol=1e-6)
+    iters = r_dev.iterations
 
     # --- batched multi-start (R restarts, one compiled loop) ----------------
     x0s = x0[None] + jnp.linspace(-1.0, 1.0, N_RESTARTS)[:, None]
@@ -149,16 +105,12 @@ def run(fast: bool = True):
     res = batched().extras
     assert bool(jnp.all(res["values"] <= res["trace"][:, 0] + 1e-6))
 
-    ips_host_loop = iters / t_host_loop
-    ips_host = iters / t_host
     ips_dev = iters / t_dev
-    # sustained throughput: total population steps the on-device driver
+    # sustained throughput: total population steps the on-device engine
     # executes per second across concurrent restarts — the population-of-
-    # runs metric the distributed-GA literature calls for (see ISSUE /
-    # PAPERS "A Fresh Approach to Evaluate Performance in Distributed
-    # Parallel Genetic Algorithms"); the host-driven loop has no batched
-    # form (it would still sync per iteration), so its sustained rate IS
-    # its single-run rate
+    # runs metric the distributed-GA literature calls for (PAPERS "A
+    # Fresh Approach to Evaluate Performance in Distributed Parallel
+    # Genetic Algorithms")
     total_batched_iters = int(jnp.sum(res["restart_iterations"]))
     ips_dev_sustained = total_batched_iters / t_batched
 
@@ -221,36 +173,14 @@ def run(fast: bool = True):
         ("bench_distributed.sequential_wall_s", t_seq,
          "Sequential strategy end-to-end (numpy baseline)"),
         ("bench_distributed.iterations", iters,
-         "population steps to convergence (identical in all loop forms)"),
-        ("bench_distributed.host_loop_wall_s", t_host_loop,
-         "pre-PR loop: per-iteration dispatch + float(val)/bool sync"),
-        ("bench_distributed.host_loop_iters_per_s", ips_host_loop,
-         "iteration throughput of the host-driven loop"),
-        ("bench_distributed.host_driver_wall_s", t_host,
-         "retained driver='host' (single end-of-run history fetch)"),
-        ("bench_distributed.host_driver_iters_per_s", ips_host,
-         "host driver after the batched-history fix"),
+         "population steps to convergence"),
         ("bench_distributed.device_loop_wall_s", t_dev,
-         "driver='device': one lax.while_loop dispatch per optimization"),
+         "Distributed(): one lax.while_loop dispatch per optimization"),
         ("bench_distributed.device_loop_iters_per_s", ips_dev,
          "iteration throughput of the on-device engine"),
-        ("bench_distributed.speedup_device_vs_host_loop",
-         ips_dev / ips_host_loop,
-         "like-for-like: ONE trajectory timed under each driver (on this "
-         "container both loops sit on the same 8-thread collective-"
-         "rendezvous floor, which compresses this ratio)"),
-        ("bench_distributed.speedup_device_vs_host_driver",
-         ips_dev / ips_host,
-         "single-trajectory, on-device vs the retained host driver"),
         ("bench_distributed.device_sustained_iters_per_s", ips_dev_sustained,
          f"AGGREGATE population steps/s across {N_RESTARTS} concurrent "
          "restarts in ONE on-device while_loop"),
-        ("bench_distributed.speedup_device_sustained_vs_host_loop",
-         ips_dev_sustained / ips_host_loop,
-         ">= 5x acceptance metric: sustained on-device driver throughput "
-         "(concurrent restarts share one loop/collective) vs the host "
-         "loop, which cannot batch — the populations-of-runs measure the "
-         "ISSUE motivation cites from PAPERS"),
         ("bench_distributed.speedup_device_vs_sequential", t_seq / t_dev,
          "wall-clock vs the sequential baseline"),
         ("bench_distributed.batched_r8_wall_s", t_batched,
@@ -288,7 +218,7 @@ def run(fast: bool = True):
         ("bench_distributed.cache_engines_built", cstats["built"],
          "distinct engine compilations paid for during this bench"),
         ("bench_distributed.cache_hits", cstats["hits"],
-         "compiled-engine reuses across reps/drivers"),
+         "compiled-engine reuses across reps"),
         ("bench_distributed.cache_misses", cstats["misses"],
          "cache misses (hashable keys compiled + stored)"),
         ("bench_distributed.cache_uncached", cstats["uncached"],

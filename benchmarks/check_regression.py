@@ -49,9 +49,6 @@ GATED = {
     # 17.8 vs 53.3 across environments with identical code) — same
     # rationale as the wall-clock exemption
     "distributed": {
-        "bench_distributed.speedup_device_vs_host_loop": "higher",
-        "bench_distributed.speedup_device_vs_host_driver": "higher",
-        "bench_distributed.speedup_device_sustained_vs_host_loop": "higher",
         "bench_distributed.speedup_folded_vs_chained": "higher",
         "bench_distributed.batched_over_single": "lower",
     },

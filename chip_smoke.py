@@ -1,5 +1,5 @@
 #!/usr/bin/env python3
-"""Run the DGO serving path, and its Pallas step, on TPU chips.
+"""Run the DGO serving path and the single-solve mesh path on TPU chips.
 
     python chip_smoke.py             # one chip
     python chip_smoke.py --chips 4   # the cross-chip mesh path only
@@ -12,9 +12,7 @@ own helpers at BBOB-sized problems; then the checks
 * every handle completes (none failed, none expired, every result finite);
 * every served result is bitwise what the request's own
   ``solve_many([req], pad_to=16)`` returns (the serving contract);
-* ``quadratic:9`` reaches the value of the numpy ``Sequential`` reference;
-* ``Distributed()`` runs the compiled Pallas ``popstep`` kernel (a
-  ``tpu_custom_call`` in its program) and agrees with the XLA inner.
+* ``quadratic:9`` reaches the value of the numpy ``Sequential`` reference.
 
 Four chips: ``Distributed(max_bits=16)`` of ``quadratic:9`` and
 ``rastrigin:40`` and one 16-request ``solve_many`` wave, on the 4-chip
@@ -54,10 +52,6 @@ SUBSPACE_ITERS = 4
 # by one ULP can move the trajectory by an iteration without changing
 # where it stalls (on the CPU the two agree to 2e-6 relative).
 SEQUENTIAL_RTOL = 1e-4
-# popstep (Mosaic) against the fused XLA inner: same children, same
-# decode; the objective is evaluated by two compilers, whose float32
-# reductions and reciprocal (shekel's 1/(d+c)) may differ in the last ULP
-POPSTEP_RTOL = 1e-5
 
 
 class SmokeFailure(AssertionError):
@@ -210,41 +204,6 @@ def check_sequential(handles) -> None:
               f"Sequential {f_ref!r} (rtol {SEQUENTIAL_RTOL})")
 
 
-def check_popstep() -> None:
-    """Distributed() on the TPU runs the compiled popstep kernel and
-    agrees with the fused XLA inner."""
-    import jax.numpy as jnp
-
-    from repro.core import distributed
-    from repro.core.solver import Distributed, Problem, resolve_mesh, solve
-
-    check(distributed._resolve_inner(None) == "popstep",
-          "the TPU's default inner is not popstep")
-    mesh = resolve_mesh(None)
-    for spec, n in (("quadratic", 9), ("shekel", None)):
-        prob = Problem.get(spec, n=n)
-        x0 = request_x0(prob, 0)
-        kern = solve(prob, Distributed(), x0=x0, max_iters=MAX_ITERS)
-        fused = solve(prob, Distributed(inner="fused"), x0=x0,
-                      max_iters=MAX_ITERS)
-        # the program solve() ran: same engine-cache key, so a cache hit
-        engine = distributed._engine_for(
-            prob.jax_fn, prob.encoding, mesh, ("data",), MAX_ITERS, 256,
-            None, None, None)
-        text = engine.lower(jnp.asarray(x0, jnp.float32),
-                            jnp.ones((mesh.size,), bool)).compile().as_text()
-        check("tpu_custom_call" in text,
-              f"{prob.name}: no tpu_custom_call in the Distributed() program")
-        f, f_ref = float(kern.best_f), float(fused.best_f)
-        bitwise = same_result(kern, fused)
-        print(f"{prob.name}: popstep {f!r} in {kern.iterations} iterations, "
-              f"fused {f_ref!r} in {fused.iterations}; bitwise {bitwise}",
-              flush=True)
-        check(bitwise or (kern.iterations == fused.iterations and abs(
-            f - f_ref) <= POPSTEP_RTOL * max(1.0, abs(f_ref))),
-            f"{prob.name}: popstep and fused inners disagree")
-
-
 def one_chip(counter: CompileCounter) -> None:
     with phase("serve-analytic", counter):
         handles = serve_closed_loop(ANALYTIC, SEEDS_PER_SPEC, MAX_ITERS)
@@ -254,8 +213,6 @@ def one_chip(counter: CompileCounter) -> None:
         check_wave_parity(handles)
     with phase("sequential-reference", counter):
         check_sequential(handles)
-    with phase("popstep-kernel", counter):
-        check_popstep()
 
 
 # ---------------------------------------------------------------------------
@@ -302,7 +259,7 @@ def four_chips(counter: CompileCounter) -> None:
         r40 = Problem.get("rastrigin", n=40)
         engine = distributed._engine_for(
             r40.jax_fn, r40.encoding, mesh4, ("data",), MAX_ITERS, 256,
-            None, None, None, res_bits=res4[1].extras["schedule"])
+            res_bits=res4[1].extras["schedule"])
         lowered = engine.lower(jnp.zeros((40,), jnp.float32),
                                jnp.ones((4,), bool))
         collectives = [ln.strip()[:160] for ln in

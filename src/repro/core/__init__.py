@@ -17,7 +17,6 @@ from repro.core.distributed import (
     BatchedResult,
     make_distributed_engine,
     make_distributed_engine_batched,
-    make_distributed_step,
 )
 from repro.core.solver import (
     Batched,
@@ -76,7 +75,6 @@ __all__ = [
     # engine builders (power users)
     "make_distributed_engine",
     "make_distributed_engine_batched",
-    "make_distributed_step",
     # subspace DGO (LM training path)
     "apply_subspace",
     "make_dgo_train_step",

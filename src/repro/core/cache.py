@@ -17,8 +17,9 @@ named :class:`CompileCache` instances:
 
 Engine builders key on everything that changes the compiled program:
 the objective callable, the encoding/config, the mesh, and every static
-knob (``inner``, ``interpret``, ``tile_p``, ...).  Keys are plain tuples;
-the first element names the engine family for readable stats.
+knob (``max_iters``, ``virtual_block``, the resolution schedule, ...).
+Keys are plain tuples; the first element names the engine family for
+readable stats.
 """
 from __future__ import annotations
 

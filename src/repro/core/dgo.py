@@ -30,10 +30,8 @@ Three engines live here, all reached through ``solver.solve()``:
 
 The multi-device population distribution (shard_map over the mesh) lives in
 ``core/distributed.py``; it folds the same stacked-table schedule into its
-on-device while_loop, and its per-shard inner loop can be the Pallas-fused
-population step in ``kernels/popstep`` (the static-shape kernel twin of the
-engine here — same generate -> decode -> evaluate -> argmin pass, tiled in
-VMEM).
+on-device while_loop, with the same XOR-pattern generate -> decode ->
+evaluate -> argmin pass as its per-shard step.
 """
 from __future__ import annotations
 
@@ -271,8 +269,7 @@ def make_fused_engine(f: Callable[[jax.Array], jax.Array],
     loop state gathers its table); decode is one exact matmul against the
     stacked weight tables; tail children beyond the live population
     2*n_vars*bits-1 are masked to +inf. This is the engine that the
-    ``fused`` strategy drives and ``clustered`` vmaps; ``kernels/popstep``
-    is its static-shape Pallas counterpart for the sharded path.
+    ``fused`` strategy drives and ``clustered`` vmaps.
     """
     st, tables, loop = _engine_loop(f, cfg)
 

@@ -19,41 +19,24 @@ Mapping (DESIGN.md §2):
                               +inf; the round proceeds and the missed
                               children are regenerated next round (DESIGN §6)
 
-Drivers (DESIGN §2/§6 mapping of the *outer* loop):
+The loop (DESIGN §2, the MP-1 running the whole generate->evaluate->rank
+loop on the PE array) is a ``lax.while_loop`` traced *inside*
+``shard_map``. Convergence ("no child improved") is decided on device from
+the replicated reduce result, and the monotone value history lives in a
+device trace buffer fetched once after the loop exits: one dispatch per
+optimization. The paper's cluster mode over concurrent requests is the
+batched engine: R independent restarts advance in lockstep inside ONE
+while_loop, the restart axis riding the shard-local inner loop as a
+leading batch dimension, with a single compilation and a single reduce
+per iteration.
 
-  MP-1 running the whole generate->evaluate->rank loop on the PE array
-    -> ``driver="device"`` (default): the iteration loop is a
-       ``lax.while_loop`` traced *inside* ``shard_map``, carrying
-       ``(bits, val, iters, trace)``. Convergence ("no child improved")
-       is decided on device from the replicated reduce result; the
-       monotone value history lives in a device trace buffer and is
-       fetched once after the loop exits. One dispatch per optimization
-       instead of one per iteration — the serial fraction that capped the
-       host-driven loop (dispatch latency + two scalar syncs/iter) is gone.
-  host-orchestrated stepping (checkpoint / failure-injection / elastic
-  re-mesh interposing between rounds)
-    -> ``driver="host"``: the retained per-iteration Python loop. Only the
-       ``bool(improved)`` convergence scalar syncs per iteration; the value
-       history is accumulated on device and fetched in ONE transfer at the
-       end. ``FailureInjector`` (runtime/failure.py) can interpose between
-       iterations; an injected failure drops one shard from the quorum via
-       ``runtime/elastic.py`` and the loop continues — DGO's native
-       elasticity (children on dead shards regenerate next round).
-  MP-1 cluster mode over concurrent requests
-    -> the batched engine (``Batched`` strategy): R independent restarts
-       (heterogeneous start points) advance in lockstep inside ONE
-       while_loop — the restart axis rides the shard-local inner loop as
-       a leading batch dimension, sharing a single compilation and a
-       single reduce per iteration (throughput measured over populations
-       of runs, not one trajectory).
-
-Resolution schedules (paper step 5) are FOLDED into the device engines:
+Resolution schedules (paper step 5) are FOLDED into both engines:
 ``res_bits`` stacks one XOR-pattern/decode table per resolution
 (``population.schedule_tables``) and the while_loop carries a resolution
 counter that indexes them, so escalation happens inside ``shard_map`` and
-a whole multi-resolution optimization — single or batched — is still one
-compiled dispatch.  The host driver chains resolutions from Python
-instead (it exists precisely so host policy can interpose per iteration).
+a whole multi-resolution optimization is one compiled dispatch.  A fixed
+resolution is the one-entry schedule ``(enc.bits,)``: the loop ends at
+its first stall and never escalates.
 """
 from __future__ import annotations
 
@@ -72,10 +55,7 @@ from repro.core.encoding import (
     Encoding, decode, decode_np, encode, encode_np, lattice_step,
     place_levels,
 )
-from repro.core.population import generate_children, segment_patterns
-from repro.kernels.popstep.ops import backend, population_step_ids
-
-_INNERS = ("fused", "popstep", "jnp")
+from repro.core.population import schedule_tables
 
 
 def _place_inputs(mesh: Mesh, *arrays):
@@ -93,32 +73,6 @@ def _place_inputs(mesh: Mesh, *arrays):
         return arrays
     sharding = NamedSharding(mesh, P())
     return tuple(jax.device_put(a, sharding) for a in arrays)
-
-
-def _resolve_inner(inner: str | None) -> str:
-    """``None`` -> backend default: the fused Pallas kernel on TPU
-    (VMEM-resident tiles, sequential-grid fold guaranteed by mosaic), the
-    hoisted-pattern XLA inner everywhere else (lowest per-iteration op
-    count — the while_loop body is latency-bound on CPU, and the compiled
-    Pallas path is not yet race-free on Triton, see
-    ``kernels.popstep.ops.resolve_interpret``)."""
-    if inner is None:
-        return "popstep" if backend() == "tpu" else "fused"
-    if inner not in _INNERS:
-        raise ValueError(f"inner must be one of {_INNERS}, got {inner!r}")
-    return inner
-
-
-def _decode_matrix(enc: Encoding) -> np.ndarray:
-    """(N, n_vars) weights: bit-string @ matrix = per-var lattice levels
-    (MSB-first powers of two < 2^24, exact in f32 — the affine map to
-    [lo, hi] is applied afterwards so rounding matches ``encoding.decode``
-    bit-for-bit and every inner picks identical argmin winners)."""
-    w = np.zeros((enc.n_bits, enc.n_vars), np.float32)
-    weights = 2.0 ** np.arange(enc.bits - 1, -1, -1)
-    for v in range(enc.n_vars):
-        w[v * enc.bits: (v + 1) * enc.bits, v] = weights
-    return w
 
 
 def _flat_axis_index(axis_names: Sequence[str]) -> jax.Array:
@@ -198,121 +152,22 @@ def _resolve_res_bits(enc: Encoding, res_bits) -> tuple:
     return res_bits or (enc.bits,)
 
 
-def _build_shard_step(f_batch: Callable[[jax.Array], jax.Array],
-                      enc: Encoding, plan: _ShardPlan,
-                      pop_axes: Sequence[str], inner: str,
-                      interpret: bool | None, tile_p: int | None):
-    """One DGO iteration as seen from inside ``shard_map``.
-
-    Returns ``prepare(quorum_mask) -> step(parent_bits, parent_val, it) ->
-    (new_bits, new_val, improved)``. The two-stage shape is deliberate:
-    the quorum lookup and (for the "fused" inner) the pattern/weight
-    tables are bound in ``prepare``, OUTSIDE the engine's while_loop, so
-    the per-iteration body is only generate-XOR, decode-matmul, evaluate,
-    argmin and one packed all_gather.
-
-    ``it`` rotates the virtual-processor assignment: on round ``it`` the
-    shard covers slot ``(shard + it) % n_shards``. With every shard alive
-    the union of slots is the whole population each round, so rotation is
-    invisible; with a dead shard it guarantees no child is *permanently*
-    shadowed — the missed children really are "regenerated next round"
-    (DESIGN §6) by a surviving shard, so a masked mesh still converges to
-    the all-alive optimum (just more slowly). Winner selection is
-    lexicographic (value, child id) so the result is independent of which
-    shard evaluated which slot.
-    """
-    pop, chunk, n_blocks, block = (plan.pop, plan.chunk, plan.n_blocks,
-                                   plan.block)
-    n_shards = plan.n_shards
-    step_kwargs = {} if tile_p is None else {"tile_p": tile_p}
-    if inner == "fused":
-        pat = jnp.asarray(segment_patterns(enc.n_bits))   # (2N-1, N)
-        wmat = jnp.asarray(_decode_matrix(enc))           # (N, n_vars)
-        scale = (enc.hi - enc.lo) / (enc.levels - 1)
-
-    def prepare(quorum_mask: jax.Array):
-        shard = _flat_axis_index(pop_axes)
-        alive = quorum_mask[shard]
-
-        def block_best(parent_bits, ids):
-            """(best value, best id) of one id block, ties -> smallest id."""
-            valid = (ids < pop) & alive
-            ids_c = jnp.minimum(ids, pop - 1)
-            if inner == "popstep":
-                return population_step_ids(f_batch, parent_bits, ids_c,
-                                           enc, valid=valid,
-                                           interpret=interpret,
-                                           **step_kwargs)
-            if inner == "fused":
-                children = jnp.bitwise_xor(parent_bits[None, :], pat[ids_c])
-                xs = enc.lo + (children.astype(jnp.float32) @ wmat) * scale
-            else:
-                children = generate_children(parent_bits, ids_c)
-                xs = decode(children, enc)                # (block, n)
-            vals = jnp.where(valid, f_batch(xs), jnp.inf)
-            v = jnp.min(vals)
-            gid = jnp.min(jnp.where(vals == v, ids_c, pop))
-            return v, gid
-
-        def local_best(parent_bits: jax.Array, it: jax.Array):
-            """This shard's (best value, best global child id) on round
-            ``it`` — covering slot (shard + it) % n_shards."""
-            base = jax.lax.rem(shard + it, n_shards) * chunk
-            if n_blocks == 1:   # no scan machinery for the common case
-                return block_best(parent_bits, base + jnp.arange(chunk))
-
-            def eval_block(carry, b):
-                best_val, best_id = carry
-                v, gid = block_best(parent_bits,
-                                    base + b * block + jnp.arange(block))
-                better = jnp.logical_or(
-                    v < best_val, (v == best_val) & (gid < best_id))
-                return (jnp.where(better, v, best_val),
-                        jnp.where(better, gid, best_id)), None
-
-            init = (jnp.asarray(jnp.inf, jnp.float32), jnp.int32(pop))
-            (v, gid), _ = jax.lax.scan(eval_block, init,
-                                       jnp.arange(n_blocks))
-            return v, gid
-
-        def step(parent_bits: jax.Array, parent_val: jax.Array,
-                 it: jax.Array):
-            local_val, local_id = local_best(parent_bits, it)
-
-            # cube-reduction analogue: ONE gather of packed (val, id) pairs
-            # over the pop axes — ids are < 2N-1 << 2^24 so the f32
-            # round-trip is exact, and a single collective halves the
-            # per-iteration rendezvous cost inside the engine's while_loop
-            packed = jnp.stack([local_val, local_id.astype(jnp.float32)])
-            for ax in pop_axes:
-                packed = jax.lax.all_gather(packed, ax)
-            packed = packed.reshape(-1, 2)
-            win_val = jnp.min(packed[:, 0])
-            ids = packed[:, 1].astype(jnp.int32)
-            win_id = jnp.min(jnp.where(packed[:, 0] == win_val, ids, pop))
-
-            improved = win_val < parent_val
-            # regenerate the winner locally from its id (no bit broadcast)
-            if inner == "fused":
-                win_bits = jnp.bitwise_xor(
-                    parent_bits, pat[jnp.minimum(win_id, pop - 1)])
-            else:
-                win_bits = generate_children(
-                    parent_bits, jnp.minimum(win_id, pop - 1)[None])[0]
-            new_bits = jnp.where(improved, win_bits,
-                                 parent_bits).astype(jnp.int8)
-            new_val = jnp.where(improved, win_val, parent_val)
-            return new_bits, new_val, improved
-
-        return step
-
-    return prepare
-
-
 def _build_shard_schedule_step(f_batch: Callable[[jax.Array], jax.Array],
                                tables, plan: _ShardPlan,
                                pop_axes: Sequence[str]):
-    """Schedule-aware twin of ``_build_shard_step`` for the folded engine.
+    """One DGO iteration of the single-solve engine, as seen from inside
+    ``shard_map``.
+
+    Returns ``prepare(quorum_mask) -> step(parent_bits, parent_val, it,
+    res_idx) -> (new_bits, new_val, improved)``: the quorum lookup is bound
+    in ``prepare``, outside the engine's while_loop.  ``it`` rotates the
+    virtual-processor assignment: on round ``it`` the shard covers slot
+    ``(shard + it) % n_shards``.  With every shard alive the union of slots
+    is the whole population each round, so rotation is invisible; with a
+    dead shard it guarantees no child is *permanently* shadowed — the
+    missed children are "regenerated next round" (DESIGN §6) by a
+    surviving shard.  Winner selection is lexicographic (value, child id),
+    so the result does not depend on which shard evaluated which slot.
 
     The step takes the resolution index carried in the engine's while_loop
     state and gathers the active resolution's XOR-pattern/decode tables
@@ -344,8 +199,7 @@ def _build_shard_schedule_step(f_batch: Callable[[jax.Array], jax.Array],
 
             def block_best(offs):
                 """(best value, best id) of one offset block, ties ->
-                smallest id — identical selection to the fixed-resolution
-                inners."""
+                smallest id."""
                 ids = base + offs
                 valid = (offs < chunk_r) & (ids < pop) & alive
                 ids_c = jnp.minimum(ids, p_max - 1)
@@ -371,7 +225,9 @@ def _build_shard_schedule_step(f_batch: Callable[[jax.Array], jax.Array],
                 (local_val, local_id), _ = jax.lax.scan(
                     eval_block, init, jnp.arange(n_blocks))
 
-            # same packed (val, id) cube-reduction as the fixed path
+            # cube-reduction analogue: ONE gather of packed (val, id)
+            # pairs over the pop axes — ids are < 2N-1 << 2^24, so the f32
+            # round-trip is exact
             packed = jnp.stack([local_val, local_id.astype(jnp.float32)])
             for ax in pop_axes:
                 packed = jax.lax.all_gather(packed, ax)
@@ -393,249 +249,124 @@ def _build_shard_schedule_step(f_batch: Callable[[jax.Array], jax.Array],
     return prepare
 
 
-def make_distributed_step(f_batch: Callable[[jax.Array], jax.Array],
-                          enc: Encoding,
-                          mesh: Mesh,
-                          pop_axes: Sequence[str] = ("data",),
-                          virtual_block: int = 256,
-                          donate: bool = False,
-                          inner: str | None = None,
-                          interpret: bool | None = None,
-                          tile_p: int | None = None):
-    """Build a jitted one-iteration DGO step sharded over ``pop_axes``.
-
-    Returns ``step(parent_bits, parent_val, quorum_mask, it) ->
-    (new_bits, new_val, improved)`` where ``quorum_mask`` is a (n_shards,)
-    bool array (all-True for the no-failure path) and ``it`` is the round
-    number, which rotates the shard->children assignment so a persistently
-    masked shard does not permanently shadow the same children (pass 0 for
-    a fixed assignment).
-
-    ``f_batch``: (B, n_vars) -> (B,), pure; evaluated inside each shard, so if
-    the objective itself is model-sharded its collectives must use *other*
-    mesh axes than ``pop_axes`` (the LM path passes a model-axis-sharded loss).
-
-    ``inner`` selects the per-shard engine for each virtual-processing
-    block: ``"fused"`` generates children by hoisted XOR patterns
-    (``population.segment_patterns``) and decodes with one matmul — pure
-    XLA, minimal op count; ``"popstep"`` runs the fused Pallas kernel —
-    generate, decode, evaluate and block-argmin in one VMEM pass per tile
-    (``kernels/popstep``); ``"jnp"`` keeps the literal unfused pipeline
-    (also the fallback for objectives whose jaxpr Pallas cannot trace).
-    ``inner=None`` picks per backend ("fused" on CPU, "popstep" on
-    TPU/GPU).
-
-    ``interpret=None`` autodetects per backend (interpret on CPU, compiled
-    mosaic/triton elsewhere); ``tile_p=None`` uses the kernel default — pass
-    ``kernels.popstep.ops.autotune_tile_p(...)`` output to pin a tuned tile.
-    """
-    inner = _resolve_inner(inner)
-    plan = _shard_plan(enc.population, mesh, pop_axes, virtual_block)
-    prepare = _build_shard_step(f_batch, enc, plan, pop_axes, inner,
-                                interpret, tile_p)
-
-    def one_step(parent_bits, parent_val, quorum_mask, it=jnp.int32(0)):
-        return prepare(quorum_mask)(parent_bits, parent_val, it)
-
-    replicated = P()
-    mapped = shard_map(
-        one_step, mesh=mesh,
-        in_specs=(replicated, replicated, replicated, replicated),
-        out_specs=(replicated, replicated, replicated),
-        check_vma=False)
-    jitted = jax.jit(mapped, donate_argnums=(0,) if donate else ())
-
-    def step(parent_bits, parent_val, quorum_mask, it=0):
-        return jitted(parent_bits, parent_val, quorum_mask,
-                      jnp.int32(it))
-
-    return step
-
-
 def make_distributed_engine(f_batch: Callable[[jax.Array], jax.Array],
                             enc: Encoding,
                             mesh: Mesh,
                             pop_axes: Sequence[str] = ("data",),
                             max_iters: int = 256,
                             virtual_block: int = 256,
-                            inner: str | None = None,
-                            interpret: bool | None = None,
-                            tile_p: int | None = None,
                             res_bits: Sequence[int] | None = None):
     """Build the on-device distributed engine: the ENTIRE optimization —
-    every population step AND, when ``res_bits`` names a multi-resolution
-    schedule, the paper's step-5 escalation — as one ``lax.while_loop``
-    traced inside ``shard_map``.
+    every population step and the paper's step-5 escalation through the
+    resolution schedule ``res_bits`` (``None`` -> the one resolution
+    ``enc.bits``) — as one ``lax.while_loop`` traced inside ``shard_map``.
 
-    Fixed resolution (``res_bits`` None or a single entry): returns
-    ``engine(x0, quorum_mask) -> (bits, val, iters, trace, x)`` with
-    ``trace`` a (max_iters + 1,) monotone best-value history (``trace[0]``
-    the starting value; entries past ``iters`` padded with the final
-    value). The initial encode/evaluation happens inside the program, so
-    one optimization is ONE dispatch; convergence — the all-gathered
-    winner failing to beat the parent — is decided on device from values
-    replicated across shards, so every shard exits the loop on the same
-    iteration and no per-iteration host round-trip exists.
-
-    Folded schedule (``res_bits`` with several resolutions): returns
-    ``engine(x0, quorum_mask) -> (bits_at, best_val, best_res_idx, iters,
-    trace, best_x)`` where ``bits_at[r]`` is the best parent's bit string
-    at resolution ``res_bits[r]`` (the live prefix of the max-width
+    Returns ``engine(x0, quorum_mask) -> (bits_at, best_val, best_res_idx,
+    iters, trace, best_x)`` where ``bits_at[r]`` is the best parent's bit
+    string at resolution ``res_bits[r]`` (the live prefix of the max-width
     buffer, ``n_vars * res_bits[r]`` bits; ``bits_at[best_res_idx]`` is
     the one it was found at), ``best_x`` that parent decoded at its own
     resolution, and ``trace`` has capacity ``len(res_bits) * max_iters +
-    1`` (raw per-iteration parent values; escalation re-encodes are not
-    recorded, matching the historical host-chained history).  The
-    fixed-resolution engine likewise ends its outputs with ``x``, the
-    final parent decoded: a solve needs no device program after the
-    engine's, and no transfer back to the device.  The resolution counter
-    rides the while_loop state and indexes the stacked
-    ``population.schedule_tables`` — the whole schedule is still ONE
-    dispatch and ONE compilation.  The schedule path always uses the
-    hoisted-pattern "fused" inner (``inner`` must be None or "fused").
+    1`` (raw per-iteration parent values, ``trace[0]`` the starting value
+    and entries past ``iters`` padded with the final value; escalation
+    re-encodes are not recorded).  The initial encode/evaluation happens
+    inside the program and the best point is decoded there, so a solve
+    needs no device program after the engine's, and no transfer back to
+    the device.  Convergence — the all-gathered winner failing to beat
+    the parent — is decided on device from values replicated across
+    shards, so every shard exits the loop on the same iteration.  The
+    resolution counter rides the while_loop state and indexes the stacked
+    ``population.schedule_tables``: the whole schedule is ONE dispatch and
+    ONE compilation.
     """
-    from repro.core.population import schedule_tables
-
     schedule = _resolve_res_bits(enc, res_bits)
-    if len(schedule) > 1:
-        if inner not in (None, "fused"):
-            raise ValueError(
-                f"the folded resolution schedule supports inner='fused' "
-                f"only (stacked XOR-pattern tables); got inner={inner!r}")
-        tables = schedule_tables(enc.n_vars, schedule, enc.lo, enc.hi)
-        plan = _shard_plan(tables.p_max, mesh, pop_axes, virtual_block)
-        prepare = _build_shard_schedule_step(f_batch, tables, plan,
-                                             pop_axes)
-        n_shards = plan.n_shards
-        n_res = tables.n_res
-        t_max = n_res * max_iters + 1
-        steps = np.asarray([lattice_step(enc.with_bits(b))
-                            for b in schedule], np.float32)
-
-        def shard_schedule_engine(x0, quorum_mask):
-            r0 = jnp.int32(0)
-            bits0 = tables.encode(x0, r0)
-            val0 = f_batch(tables.decode(bits0, r0)[None])[0]
-            val0 = val0.astype(jnp.float32)
-            one_step = prepare(quorum_mask)
-            stall_limit = jnp.where(jnp.all(quorum_mask), 1, n_shards)
-
-            def stalled(s):
-                stalls, it_in_res = s[6], s[7]
-                return jnp.logical_or(stalls >= stall_limit,
-                                      it_in_res >= max_iters)
-
-            def cond(s):
-                res_idx = s[0]
-                last = res_idx >= n_res - 1
-                return ~jnp.logical_and(last, stalled(s))
-
-            def iterate(s):
-                (res_idx, bits, val, best_val, best_bits, best_res,
-                 stalls, it_in_res, iters, trace) = s
-                new_bits, new_val, improved = one_step(bits, val,
-                                                       it_in_res, res_idx)
-                trace = trace.at[iters + 1].set(new_val)
-                stalls = jnp.where(improved, 0, stalls + 1)
-                better = new_val < best_val
-                best_val = jnp.where(better, new_val, best_val)
-                best_bits = jnp.where(better, new_bits, best_bits)
-                best_res = jnp.where(better, res_idx, best_res)
-                return (res_idx, new_bits, new_val, best_val, best_bits,
-                        best_res, stalls, it_in_res + 1, iters + 1, trace)
-
-            def escalate(s):
-                (res_idx, bits, val, best_val, best_bits, best_res,
-                 stalls, it_in_res, iters, trace) = s
-                nxt = jnp.minimum(res_idx + 1, n_res - 1)
-                bits2 = tables.reencode(bits, res_idx, nxt)  # paper step 5
-                val2 = f_batch(tables.decode(bits2, nxt)[None])[0]
-                val2 = val2.astype(jnp.float32)
-                # a finer quantization of the same parent can already beat
-                # the best — the chained path caught this via the next
-                # resolution's final value, so catch it here too
-                better = val2 < best_val
-                best_val = jnp.where(better, val2, best_val)
-                best_bits = jnp.where(better, bits2, best_bits)
-                best_res = jnp.where(better, nxt, best_res)
-                return (nxt, bits2, val2, best_val, best_bits, best_res,
-                        jnp.int32(0), jnp.int32(0), iters, trace)
-
-            def body(s):
-                return jax.lax.cond(stalled(s), escalate, iterate, s)
-
-            trace0 = jnp.full((t_max,), val0, jnp.float32)
-            s0 = (jnp.int32(0), bits0, val0, val0, bits0, jnp.int32(0),
-                  jnp.int32(0), jnp.int32(0), jnp.int32(0), trace0)
-            s = jax.lax.while_loop(cond, body, s0)
-            (_, _, val, best_val, best_bits, best_res, _, _, iters,
-             trace) = s
-            idx = jnp.arange(t_max)
-            trace = jnp.where(idx <= iters, trace, val)
-            # the reported point, rounded as encoding.decode rounds it
-            levels = best_bits.astype(jnp.float32) @ tables.wmat[best_res]
-            best_x = place_levels(levels, enc.lo,
-                                  jnp.asarray(steps)[best_res])
-            bits_at = tuple(best_bits[: enc.n_vars * b] for b in schedule)
-            return bits_at, best_val, best_res, iters, trace, best_x
-
-        replicated = P()
-        mapped = shard_map(
-            shard_schedule_engine, mesh=mesh,
-            in_specs=(replicated, replicated),
-            out_specs=((replicated,) * n_res,) + (replicated,) * 5,
-            check_vma=False)
-        return jax.jit(mapped)
-
-    inner = _resolve_inner(inner)
-    plan = _shard_plan(enc.population, mesh, pop_axes, virtual_block)
-    prepare = _build_shard_step(f_batch, enc, plan, pop_axes, inner,
-                                interpret, tile_p)
-
+    tables = schedule_tables(enc.n_vars, schedule, enc.lo, enc.hi)
+    plan = _shard_plan(tables.p_max, mesh, pop_axes, virtual_block)
+    prepare = _build_shard_schedule_step(f_batch, tables, plan,
+                                         pop_axes)
     n_shards = plan.n_shards
+    n_res = tables.n_res
+    t_max = n_res * max_iters + 1
+    steps = np.asarray([lattice_step(enc.with_bits(b))
+                        for b in schedule], np.float32)
 
-    def shard_engine(x0, quorum_mask):
-        # initial encode + evaluation on device too: the engine call is the
-        # ONLY dispatch of the whole optimization
-        bits0 = encode(x0, enc)
-        val0 = f_batch(decode(bits0, enc)[None])[0].astype(jnp.float32)
-        one_step = prepare(quorum_mask)   # loop-invariants hoisted here
-        # all shards alive -> one non-improving round proves a true stall;
-        # with dead shards a child may be shadowed this round, so require a
-        # full rotation cycle of failures before declaring convergence
+    def shard_schedule_engine(x0, quorum_mask):
+        r0 = jnp.int32(0)
+        bits0 = tables.encode(x0, r0)
+        val0 = f_batch(tables.decode(bits0, r0)[None])[0]
+        val0 = val0.astype(jnp.float32)
+        one_step = prepare(quorum_mask)
         stall_limit = jnp.where(jnp.all(quorum_mask), 1, n_shards)
 
-        def cond(s):
-            _, _, stalls, iters, _ = s
-            return jnp.logical_and(stalls < stall_limit, iters < max_iters)
+        def stalled(s):
+            stalls, it_in_res = s[6], s[7]
+            return jnp.logical_or(stalls >= stall_limit,
+                                  it_in_res >= max_iters)
 
-        def body(s):
-            bits, val, stalls, iters, trace = s
-            new_bits, new_val, improved = one_step(bits, val, iters)
+        def cond(s):
+            res_idx = s[0]
+            last = res_idx >= n_res - 1
+            return ~jnp.logical_and(last, stalled(s))
+
+        def iterate(s):
+            (res_idx, bits, val, best_val, best_bits, best_res,
+             stalls, it_in_res, iters, trace) = s
+            new_bits, new_val, improved = one_step(bits, val,
+                                                   it_in_res, res_idx)
             trace = trace.at[iters + 1].set(new_val)
             stalls = jnp.where(improved, 0, stalls + 1)
-            return (new_bits, new_val, stalls, iters + 1, trace)
+            better = new_val < best_val
+            best_val = jnp.where(better, new_val, best_val)
+            best_bits = jnp.where(better, new_bits, best_bits)
+            best_res = jnp.where(better, res_idx, best_res)
+            return (res_idx, new_bits, new_val, best_val, best_bits,
+                    best_res, stalls, it_in_res + 1, iters + 1, trace)
 
-        trace0 = jnp.full((max_iters + 1,), val0, jnp.float32)
-        s0 = (bits0, val0, jnp.int32(0), jnp.int32(0), trace0)
-        bits, val, _, iters, trace = jax.lax.while_loop(cond, body, s0)
-        idx = jnp.arange(max_iters + 1)
-        trace = jnp.where(idx <= iters, trace, val)   # pad for clean plots
+        def escalate(s):
+            (res_idx, bits, val, best_val, best_bits, best_res,
+             stalls, it_in_res, iters, trace) = s
+            nxt = jnp.minimum(res_idx + 1, n_res - 1)
+            bits2 = tables.reencode(bits, res_idx, nxt)  # paper step 5
+            val2 = f_batch(tables.decode(bits2, nxt)[None])[0]
+            val2 = val2.astype(jnp.float32)
+            # a finer quantization of the same parent can already beat
+            # the best — the chained path caught this via the next
+            # resolution's final value, so catch it here too
+            better = val2 < best_val
+            best_val = jnp.where(better, val2, best_val)
+            best_bits = jnp.where(better, bits2, best_bits)
+            best_res = jnp.where(better, nxt, best_res)
+            return (nxt, bits2, val2, best_val, best_bits, best_res,
+                    jnp.int32(0), jnp.int32(0), iters, trace)
+
+        def body(s):
+            return jax.lax.cond(stalled(s), escalate, iterate, s)
+
+        trace0 = jnp.full((t_max,), val0, jnp.float32)
+        s0 = (jnp.int32(0), bits0, val0, val0, bits0, jnp.int32(0),
+              jnp.int32(0), jnp.int32(0), jnp.int32(0), trace0)
+        s = jax.lax.while_loop(cond, body, s0)
+        (_, _, val, best_val, best_bits, best_res, _, _, iters,
+         trace) = s
+        idx = jnp.arange(t_max)
+        trace = jnp.where(idx <= iters, trace, val)
         # the reported point, rounded as encoding.decode rounds it
-        levels = bits.astype(jnp.float32) @ jnp.asarray(_decode_matrix(enc))
-        x = place_levels(levels, enc.lo, lattice_step(enc))
-        return bits, val, iters, trace, x
+        levels = best_bits.astype(jnp.float32) @ tables.wmat[best_res]
+        best_x = place_levels(levels, enc.lo,
+                              jnp.asarray(steps)[best_res])
+        bits_at = tuple(best_bits[: enc.n_vars * b] for b in schedule)
+        return bits_at, best_val, best_res, iters, trace, best_x
 
     replicated = P()
     mapped = shard_map(
-        shard_engine, mesh=mesh,
+        shard_schedule_engine, mesh=mesh,
         in_specs=(replicated, replicated),
-        out_specs=(replicated,) * 5,
+        out_specs=((replicated,) * n_res,) + (replicated,) * 5,
         check_vma=False)
     return jax.jit(mapped)
 
 
-# engine/step compilations go through the repo-wide keyed cache subsystem
+# engine compilations go through the repo-wide keyed cache subsystem
 # (core/cache.py): a (objective, mesh, config) pair compiles ONCE per
 # process — repeated serving calls (waves of requests, bench reps) reuse the
 # compiled program; unhashable objectives build uncached instead of raising,
@@ -645,27 +376,16 @@ _ENGINES = get_cache("distributed.engine")
 _QUORUMS = get_cache("distributed.quorum")
 
 
-def _step_for(f, enc, mesh, pop_axes, virtual_block, inner, interpret,
-              tile_p):
-    return _ENGINES.get(
-        ("step", f, enc, mesh, pop_axes, virtual_block, inner, interpret,
-         tile_p),
-        lambda: make_distributed_step(jax.vmap(f), enc, mesh, pop_axes,
-                                      virtual_block, inner=inner,
-                                      interpret=interpret, tile_p=tile_p))
-
-
-def _engine_for(f, enc, mesh, pop_axes, max_iters, virtual_block, inner,
-                interpret, tile_p, res_bits=None):
+def _engine_for(f, enc, mesh, pop_axes, max_iters, virtual_block,
+                res_bits=None):
     # the schedule signature is part of the key: ONE compilation covers the
     # whole folded resolution schedule, not one per resolution
     return _ENGINES.get(
-        ("engine", f, enc, mesh, pop_axes, max_iters, virtual_block, inner,
-         interpret, tile_p, res_bits),
+        ("engine", f, enc, mesh, pop_axes, max_iters, virtual_block,
+         res_bits),
         lambda: make_distributed_engine(jax.vmap(f), enc, mesh, pop_axes,
                                         max_iters, virtual_block,
-                                        inner=inner, interpret=interpret,
-                                        tile_p=tile_p, res_bits=res_bits))
+                                        res_bits=res_bits))
 
 
 def _batched_engine_for(f, enc, mesh, n_restarts, pop_axes, max_iters,
@@ -698,57 +418,40 @@ class DistributedRun(NamedTuple):
     bits_resolution: int   # bits per variable of ``bits``
 
 
-def _run_fixed_resolution(f, enc, mesh, x0, pop_axes, max_iters,
-                          virtual_block, quorum_mask, inner, interpret,
-                          injector, tile_p):
-    """One host-stepped run at ``enc.bits``; returns ``(bits, val,
-    history)`` — the per-resolution unit the host driver chains."""
-    n_shards = _axis_prod(mesh, pop_axes)
-    bits = encode(jnp.asarray(x0, jnp.float32), enc)
-    val = f(decode(bits, enc))
-    step = _step_for(f, enc, mesh, pop_axes, virtual_block, inner,
-                     interpret, tile_p)
-    if injector is not None:
-        from repro.runtime.elastic import drop_shard
-        from repro.runtime.failure import SimulatedFailure
-    full_quorum = bool(np.asarray(quorum_mask).all())
-    vals = [val]
-    stalls = 0
-    for it in range(max_iters):
-        if injector is not None:
-            try:
-                injector.maybe_fail(it)
-            except SimulatedFailure:
-                try:
-                    quorum_mask = drop_shard(quorum_mask)
-                    full_quorum = False
-                except RuntimeError:    # every shard lost: stop with the
-                    break               # best point found so far
-        bits, val, improved = step(bits, val, quorum_mask, it)
-        vals.append(val)
-        # same stall rule as the device engine: a degraded quorum needs a
-        # full rotation cycle of failures before convergence is declared
-        stalls = 0 if bool(improved) else stalls + 1
-        if stalls >= (1 if full_quorum else n_shards):
-            break
-    # ONE bulk device->host fetch of already-materialized scalars at the
-    # end instead of a float(val) round-trip inside the loop
-    history = [float(v) for v in jax.device_get(vals)]
-    return bits, val, history
+def _run_distributed(f: Callable[[jax.Array], jax.Array],
+                     enc: Encoding,
+                     mesh: Mesh,
+                     x0,
+                     pop_axes: Sequence[str] = ("data",),
+                     max_iters: int = 256,
+                     virtual_block: int = 256,
+                     quorum_mask=None,
+                     res_bits: Sequence[int] | None = None) -> DistributedRun:
+    """Distributed DGO over the resolution schedule ``res_bits`` (``None``
+    -> fixed at ``enc.bits``), the ENTIRE schedule on device in one
+    compiled ``lax.while_loop`` (see ``make_distributed_engine``).
 
+    A run touches the device twice: the engine call, which takes the
+    start point as it is (the full-quorum mask is placed on the mesh
+    once), and ONE ``device_get`` of every value the result needs, with
+    no other device program or transfer.  The engine decodes the best
+    parent and cuts its bit string at every resolution itself, so ``x``,
+    ``val`` and ``bits`` are the engine's own arrays, the host values of
+    ``x`` and ``val`` already fetched; the history is cut in numpy.  One
+    non-improving round ends a full-quorum resolution, while a degraded
+    ``quorum_mask`` needs a full rotation cycle (``n_shards`` consecutive
+    non-improving rounds) before a child can be declared unreachable.
 
-def _run_on_device(f, enc, mesh, x0, pop_axes, max_iters, virtual_block,
-                   quorum_mask, inner, interpret, tile_p,
-                   schedule: tuple) -> DistributedRun:
-    """The device driver: one engine call and one ``device_get``, and no
-    other device program or transfer.  The engine decodes the best parent
-    and cuts its bit string at every resolution itself, so ``x``, ``val``
-    and ``bits`` are its own output arrays; the fetch caches the host
-    values of ``x`` and ``val``, and the history is cut in numpy."""
-    folded = len(schedule) > 1
+    Returns a :class:`DistributedRun`: the best parent decoded (``x``),
+    its bit string at its own resolution ``bits_resolution`` (bits per
+    variable), its value, and the raw per-iteration value history
+    (``history[0]`` the starting value; escalation re-encodes are not
+    recorded).
+    """
+    pop_axes = tuple(pop_axes)
+    schedule = _resolve_res_bits(enc, res_bits)
     engine = _engine_for(f, enc.with_bits(schedule[0]), mesh, pop_axes,
-                         max_iters, virtual_block, inner, interpret, tile_p,
-                         res_bits=schedule if folded else None)
+                         max_iters, virtual_block, res_bits=schedule)
     with tracing.span("solve.place"):
         # a JAX start point stays one; anything else goes as numpy, which
         # the engine call transfers itself on one process
@@ -759,201 +462,25 @@ def _run_on_device(f, enc, mesh, x0, pop_axes, max_iters, virtual_block,
         x0, quorum = _place_inputs(mesh, x0, quorum)
     outs = engine(x0, quorum)
     with tracing.span("solve.fetch"):
-        if folded:
-            bits, val, res, iters, trace, x = outs
-        else:
-            bits, val, iters, trace, x = outs
-            res = 0
+        bits, val, res, iters, trace, x = outs
         _, _, res_h, iters_h, trace_h = jax.device_get(
             (x, val, res, iters, trace))
     with tracing.span("solve.assemble"):
         history = trace_h[: int(iters_h) + 1].tolist()
-        if folded:
-            bits = bits[int(res_h)]
+        bits = bits[int(res_h)]
     return DistributedRun(x, bits, val, history, schedule[int(res_h)])
-
-
-def _run_distributed(f: Callable[[jax.Array], jax.Array],
-                    enc: Encoding,
-                    mesh: Mesh,
-                    x0,
-                    pop_axes: Sequence[str] = ("data",),
-                    max_iters: int = 256,
-                    virtual_block: int = 256,
-                    quorum_mask=None,
-                    inner: str | None = None,
-                    interpret: bool | None = None,
-                    driver: str = "device",
-                    injector=None,
-                    tile_p: int | None = None,
-                    res_bits: Sequence[int] | None = None) -> DistributedRun:
-    """Distributed DGO over the resolution schedule ``res_bits`` (``None``
-    -> fixed at ``enc.bits``).
-
-    ``driver="device"`` (default) runs the ENTIRE schedule on device — a
-    multi-resolution ``res_bits`` is folded into the single compiled
-    ``lax.while_loop`` (see ``make_distributed_engine``), so one
-    optimization stays ONE dispatch regardless of how many resolutions it
-    escalates through.  Such a run touches the device twice: the engine
-    call, which takes the start point as it is (the full-quorum mask is
-    placed on the mesh once), and ONE ``device_get`` of every value the
-    result needs.  The engine decodes the best parent and cuts its bit
-    string itself, so ``x``, ``val`` and ``bits`` are the engine's own
-    arrays, the host values of ``x`` and ``val`` already fetched; the
-    history is cut in numpy.
-    ``driver="host"`` keeps the Python-stepped loop (chaining resolutions
-    from the host) so host-side policy can interpose between iterations:
-    an optional ``injector`` (``runtime.failure.FailureInjector``; host
-    driver only — the on-device loop cannot interpose host policy, so
-    pairing it with ``driver="device"`` raises) is polled each round and
-    an injected failure removes one shard from the quorum
-    (``runtime.elastic.drop_shard``) instead of aborting — the surviving
-    shards regenerate the lost children next round; if failures exhaust
-    the quorum the loop stops and returns the best point found so far.
-    Even the host path avoids the old per-iteration ``float(val)`` sync:
-    values accumulate on device and only the ``bool(improved)``
-    convergence scalar crosses per iteration. Both drivers share the
-    stall rule: one non-improving round ends a full-quorum resolution,
-    while a degraded quorum needs a full rotation cycle (``n_shards``
-    consecutive non-improving rounds) before a child can be declared
-    unreachable.
-
-    Returns a :class:`DistributedRun`: the best parent decoded (``x``),
-    its bit string at its own resolution ``bits_resolution`` (bits per
-    variable), its value, and the raw per-iteration value history
-    (``history[0]`` the starting value; escalation re-encodes are not
-    recorded) — JAX arrays for ``x``, ``bits`` and ``val`` from either
-    driver.
-    """
-    if driver not in ("device", "host"):
-        raise ValueError(f"driver must be 'device' or 'host', got {driver!r}")
-    if injector is not None and driver != "host":
-        raise ValueError("failure injection requires driver='host' — the "
-                         "on-device loop cannot interpose host policy")
-    pop_axes = tuple(pop_axes)
-    schedule = _resolve_res_bits(enc, res_bits)
-    if driver == "device":
-        return _run_on_device(f, enc, mesh, x0, pop_axes, max_iters,
-                              virtual_block, quorum_mask, inner, interpret,
-                              tile_p, schedule)
-
-    if quorum_mask is None:
-        quorum_mask = _full_quorum(mesh, pop_axes)
-    (x,) = _place_inputs(mesh, jnp.asarray(x0, jnp.float32))
-    history: list[float] = []
-    best = None   # (float val, device val, bits, bits-per-var)
-    for i, b in enumerate(schedule):
-        enc_b = enc.with_bits(b)
-        bits, val, hist = _run_fixed_resolution(
-            f, enc_b, mesh, x, pop_axes, max_iters, virtual_block,
-            quorum_mask, inner, interpret, injector, tile_p)
-        history.extend(hist if i == 0 else hist[1:])
-        if best is None or float(val) < best[0]:
-            best = (float(val), val, bits, b)
-        x = decode(bits, enc_b)
-    _, best_val, best_bits, best_b = best
-    return DistributedRun(decode(best_bits, enc.with_bits(best_b)),
-                          best_bits, best_val, history, best_b)
 
 
 # ---------------------------------------------------------------------------
 # batched multi-start engine (paper's cluster mode over the mesh)
 # ---------------------------------------------------------------------------
 
-def _build_shard_step_batched(f_batch: Callable[[jax.Array], jax.Array],
-                              enc: Encoding, plan: _ShardPlan,
-                              pop_axes: Sequence[str], n_restarts: int):
-    """Batched twin of ``_build_shard_step``: a leading restart axis R rides
-    the shard-local inner loop; ONE all_gather per iteration carries all R
-    (value, id) pairs. Always the hoisted-pattern "fused" inner — child
-    generation for all R parents is a single broadcast XOR against the
-    shard's static patterns, decode one (R*chunk, N) matmul."""
-    pop, chunk, n_blocks, block = (plan.pop, plan.chunk, plan.n_blocks,
-                                   plan.block)
-    n_shards = plan.n_shards
-    pat = jnp.asarray(segment_patterns(enc.n_bits))       # (2N-1, N)
-    wmat = jnp.asarray(_decode_matrix(enc))               # (N, n_vars)
-    scale = (enc.hi - enc.lo) / (enc.levels - 1)
-
-    def prepare(quorum_mask: jax.Array):
-        shard = _flat_axis_index(pop_axes)
-        alive = quorum_mask[shard]
-
-        def local_best_block(parent_bits, ids):
-            """Ties -> smallest id, matching the single-restart builder."""
-            valid = (ids < pop) & alive
-            ids_c = jnp.minimum(ids, pop - 1)
-            b = ids.shape[0]
-            with tracing.scope("children"):
-                children = jnp.bitwise_xor(parent_bits[:, None, :],
-                                           pat[ids_c][None])  # (R, b, N)
-                flat = children.reshape(n_restarts * b, -1)
-            with tracing.scope("decode"):
-                xs = enc.lo + (flat.astype(jnp.float32) @ wmat) * scale
-            with tracing.scope("evaluate"):
-                fx = f_batch(xs).reshape(n_restarts, b)   # (R, b)
-            with tracing.scope("select"):
-                vals = jnp.where(valid[None, :], fx, jnp.inf)
-                v = jnp.min(vals, axis=1)                 # (R,)
-                gid = jnp.min(jnp.where(vals == v[:, None], ids_c[None],
-                                        pop), axis=1)
-            return v, gid
-
-        def one_step(parent_bits: jax.Array,   # (R, N) int8
-                     parent_val: jax.Array,    # (R,) f32
-                     it: jax.Array):           # () i32 — rotation round
-            base = jax.lax.rem(shard + it, n_shards) * chunk
-            if n_blocks == 1:
-                local_val, local_id = local_best_block(
-                    parent_bits, base + jnp.arange(chunk))
-            else:
-                def eval_block(carry, b):
-                    best_val, best_id = carry  # (R,), (R,)
-                    v, gid = local_best_block(
-                        parent_bits, base + b * block + jnp.arange(block))
-                    with tracing.scope("select"):
-                        better = jnp.logical_or(
-                            v < best_val, (v == best_val) & (gid < best_id))
-                        return (jnp.where(better, v, best_val),
-                                jnp.where(better, gid, best_id)), None
-
-                init = (jnp.full((n_restarts,), jnp.inf, jnp.float32),
-                        jnp.full((n_restarts,), pop, jnp.int32))
-                (local_val, local_id), _ = jax.lax.scan(
-                    eval_block, init, jnp.arange(n_blocks))
-
-            with tracing.scope("select"):
-                # one packed gather for ALL R restarts (ids exact in f32,
-                # see the single-restart builder)
-                packed = jnp.stack([local_val,
-                                    local_id.astype(jnp.float32)])
-                for ax in pop_axes:
-                    packed = jax.lax.all_gather(packed, ax)
-                packed = packed.reshape(-1, 2, n_restarts)
-                all_vals = packed[:, 0, :]                # (S, R)
-                all_ids = packed[:, 1, :].astype(jnp.int32)
-                win_val = jnp.min(all_vals, axis=0)       # (R,)
-                win_id = jnp.min(jnp.where(all_vals == win_val[None],
-                                           all_ids, pop), axis=0)
-
-                improved = win_val < parent_val           # (R,)
-                win_bits = jnp.bitwise_xor(
-                    parent_bits, pat[jnp.minimum(win_id, pop - 1)])
-                new_bits = jnp.where(improved[:, None], win_bits,
-                                     parent_bits).astype(jnp.int8)
-                new_val = jnp.where(improved, win_val, parent_val)
-            return new_bits, new_val, improved
-
-        return one_step
-
-    return prepare
-
-
 def _build_shard_schedule_step_batched(
         f_batch: Callable[[jax.Array], jax.Array], tables,
         plan: _ShardPlan, pop_axes: Sequence[str], n_restarts: int):
-    """Schedule-aware twin of ``_build_shard_step_batched``: the restart
-    axis rides the shard-local loop AND the step gathers the active
+    """Batched twin of ``_build_shard_schedule_step``: a leading restart
+    axis R rides the shard-local loop, ONE all_gather per iteration
+    carries all R (value, id) pairs, AND the step gathers the active
     resolution's stacked tables from the carried resolution counter."""
     p_max, chunk, n_blocks, block = (plan.pop, plan.chunk, plan.n_blocks,
                                      plan.block)
@@ -1051,12 +578,12 @@ def make_distributed_engine_batched(
     """On-device engine over R lockstep restarts — one while_loop, one
     compilation, one reduce per iteration for the whole batch.
 
-    Every engine takes two per-slot call-time arrays alongside the start
+    The engine takes two per-slot call-time arrays alongside the start
     points (dynamic, so heterogeneous waves share one compilation):
     ``active`` (R,) bool — inactive slots are padding and never step (a
     partially-filled serving wave reuses the full-width engine) — and
-    ``slot_iters`` (R,) i32, each slot's own iteration cap (per resolution
-    on the schedule path).  A slot's trajectory is a pure function of its
+    ``slot_iters`` (R,) i32, each slot's own iteration cap (per
+    resolution).  A slot's trajectory is a pure function of its
     own x0/cap: it is bitwise independent of which other slots ride the
     wave, which is what lets the serving scheduler promise per-request
     results identical to individual solves.
@@ -1066,179 +593,115 @@ def make_distributed_engine_batched(
     row (:func:`_parent_values`), so ``trace[0]`` does not depend on the
     compiled batch width; the population steps evaluate ``vmap(f)``.
 
-    Fixed resolution (``res_bits`` None or a single entry): returns
-    ``engine(x0s (R, n_vars), quorum_mask, active, slot_iters) ->
-    (bits (R,N), vals (R,), iters (R,), trace (R, max_iters+1))``.
-    Restarts that stall (or hit their slot cap) stop mutating — their
-    bits/val/trace freeze and their iteration counter stops — while the
-    loop continues until every active restart is done or ``max_iters``
-    (the static trace-capacity cap) is hit.
-
-    Folded schedule (``res_bits`` with several resolutions): the whole
-    batch escalates in lockstep inside the same while_loop — when every
-    active restart has stalled or hit its per-resolution slot cap (or the
-    static per-resolution cap is hit), all restarts re-encode onto the
-    next lattice and resume.  Returns ``engine(x0s, quorum_mask, active,
-    slot_iters) -> (bits (R, n_max), vals (R,), best_vals (R,),
+    The whole batch escalates through the resolution schedule
+    ``res_bits`` (``None`` -> the one resolution ``enc.bits``) in lockstep
+    inside the same while_loop — when every active restart has stalled or
+    hit its per-resolution slot cap (or the static per-resolution cap is
+    hit), all restarts re-encode onto the next lattice and resume; at the
+    last resolution that ends the loop.  Restarts that stall (or hit
+    their slot cap) stop mutating meanwhile: their bits/val/trace freeze
+    and their iteration counter stops.  Returns ``engine(x0s, quorum_mask,
+    active, slot_iters) -> (bits (R, n_max), vals (R,), best_vals (R,),
     best_bits (R, n_max), best_res (R,), iters (R,),
     trace (R, len(res_bits)*max_iters + 1))`` where ``best_*`` track each
     restart's best parent across resolutions and ``trace`` holds the raw
-    per-iteration values (escalation re-encodes not recorded).  Still ONE
+    per-iteration values (escalation re-encodes not recorded).  ONE
     compilation and ONE dispatch for the entire batch and schedule.
     """
-    from repro.core.population import schedule_tables
-
     f_batch = jax.vmap(f)
     schedule = _resolve_res_bits(enc, res_bits)
-    if len(schedule) > 1:
-        tables = schedule_tables(enc.n_vars, schedule, enc.lo, enc.hi)
-        plan = _shard_plan(tables.p_max, mesh, pop_axes, virtual_block)
-        prepare = _build_shard_schedule_step_batched(
-            f_batch, tables, plan, pop_axes, n_restarts)
-        n_shards = plan.n_shards
-        n_res = tables.n_res
-        t_max = n_res * max_iters + 1
-        rows = jnp.arange(n_restarts)
-
-        def shard_schedule_engine(x0s, quorum_mask, active, slot_iters):
-            r0 = jnp.int32(0)
-            bits0 = tables.encode(x0s, r0)                   # (R, n_max)
-            vals0 = _parent_values(f, enc.with_bits(schedule[0]), x0s,
-                                   active)
-            one_step = prepare(quorum_mask)
-            stall_limit = jnp.where(jnp.all(quorum_mask), 1, n_shards)
-
-            def live_of(stalls, it_in_res):
-                # a slot steps while it is real, unstalled and under its
-                # own per-resolution cap (the static max_iters only sizes
-                # the trace buffer / backstops the loop)
-                return active & (stalls < stall_limit) & \
-                    (it_in_res < slot_iters)
-
-            def res_done(s):
-                stalls, it_in_res = s[6], s[7]
-                return jnp.logical_or(~jnp.any(live_of(stalls, it_in_res)),
-                                      it_in_res >= max_iters)
-
-            def cond(s):
-                return ~jnp.logical_and(s[0] >= n_res - 1, res_done(s))
-
-            def iterate(s):
-                (res_idx, bits, vals, best_vals, best_bits, best_res,
-                 stalls, it_in_res, pos, trace) = s
-                live = live_of(stalls, it_in_res)            # (R,)
-                nb, nv, improved = one_step(bits, vals, it_in_res, res_idx)
-                with tracing.scope("select"):
-                    bits = jnp.where(live[:, None], nb, bits)
-                    vals = jnp.where(live, nv, vals)
-                with tracing.scope("trace"):
-                    pos = pos + live.astype(jnp.int32)
-                    trace = trace.at[rows, jnp.clip(pos, 0, t_max - 1)].set(
-                        vals)
-                with tracing.scope("select"):
-                    stalls = jnp.where(live & improved, 0,
-                                       stalls + live.astype(jnp.int32))
-                    better = vals < best_vals
-                    best_vals = jnp.where(better, vals, best_vals)
-                    best_bits = jnp.where(better[:, None], bits, best_bits)
-                    best_res = jnp.where(better, res_idx, best_res)
-                return (res_idx, bits, vals, best_vals, best_bits,
-                        best_res, stalls, it_in_res + 1, pos, trace)
-
-            def escalate(s):
-                (res_idx, bits, vals, best_vals, best_bits, best_res,
-                 stalls, it_in_res, pos, trace) = s
-                with tracing.scope("escalate"):
-                    nxt = jnp.minimum(res_idx + 1, n_res - 1)
-                    bits2 = tables.reencode(bits, res_idx, nxt)  # step 5
-                    vals2 = f_batch(tables.decode(bits2, nxt)).astype(
-                        jnp.float32)
-                    better = vals2 < best_vals
-                    best_vals = jnp.where(better, vals2, best_vals)
-                    best_bits = jnp.where(better[:, None], bits2, best_bits)
-                    best_res = jnp.where(better, nxt, best_res)
-                    return (nxt, bits2, vals2, best_vals, best_bits,
-                            best_res, jnp.zeros_like(stalls), jnp.int32(0),
-                            pos, trace)
-
-            def body(s):
-                return jax.lax.cond(res_done(s), escalate, iterate, s)
-
-            with tracing.scope("trace"):
-                trace0 = jnp.tile(vals0[:, None], (1, t_max))
-            s0 = (jnp.int32(0), bits0, vals0, vals0, bits0,
-                  jnp.zeros((n_restarts,), jnp.int32),
-                  jnp.zeros((n_restarts,), jnp.int32), jnp.int32(0),
-                  jnp.zeros((n_restarts,), jnp.int32), trace0)
-            s = jax.lax.while_loop(cond, body, s0)
-            (_, bits, vals, best_vals, best_bits, best_res, _, _, pos,
-             trace) = s
-            with tracing.scope("trace"):
-                idx = jnp.arange(t_max)[None, :]
-                trace = jnp.where(idx <= pos[:, None], trace, vals[:, None])
-            return bits, vals, best_vals, best_bits, best_res, pos, trace
-
-        replicated = P()
-        mapped = shard_map(
-            shard_schedule_engine, mesh=mesh,
-            in_specs=(replicated,) * 4,
-            out_specs=(replicated,) * 7,
-            check_vma=False)
-        return jax.jit(_named(mapped, "dgo_wave_engine"))
-
-    plan = _shard_plan(enc.population, mesh, pop_axes, virtual_block)
-    prepare = _build_shard_step_batched(f_batch, enc, plan, pop_axes,
-                                        n_restarts)
-
+    tables = schedule_tables(enc.n_vars, schedule, enc.lo, enc.hi)
+    plan = _shard_plan(tables.p_max, mesh, pop_axes, virtual_block)
+    prepare = _build_shard_schedule_step_batched(
+        f_batch, tables, plan, pop_axes, n_restarts)
     n_shards = plan.n_shards
+    n_res = tables.n_res
+    t_max = n_res * max_iters + 1
+    rows = jnp.arange(n_restarts)
 
-    def shard_engine(x0s, quorum_mask, active, slot_iters):
-        bits0 = encode(x0s, enc)                          # (R, N)
-        vals0 = _parent_values(f, enc, x0s, active)
+    def shard_schedule_engine(x0s, quorum_mask, active, slot_iters):
+        r0 = jnp.int32(0)
+        bits0 = tables.encode(x0s, r0)                   # (R, n_max)
+        vals0 = _parent_values(f, enc.with_bits(schedule[0]), x0s,
+                               active)
         one_step = prepare(quorum_mask)
-        # same stall rule as the single-restart engine, per restart
         stall_limit = jnp.where(jnp.all(quorum_mask), 1, n_shards)
 
-        def live_of(stalls, iters):
-            return active & (stalls < stall_limit) & (iters < slot_iters)
+        def live_of(stalls, it_in_res):
+            # a slot steps while it is real, unstalled and under its
+            # own per-resolution cap (the static max_iters only sizes
+            # the trace buffer / backstops the loop)
+            return active & (stalls < stall_limit) & \
+                (it_in_res < slot_iters)
+
+        def res_done(s):
+            stalls, it_in_res = s[6], s[7]
+            return jnp.logical_or(~jnp.any(live_of(stalls, it_in_res)),
+                                  it_in_res >= max_iters)
 
         def cond(s):
-            _, _, stalls, it, iters, _ = s
-            return jnp.logical_and(jnp.any(live_of(stalls, iters)),
-                                   it < max_iters)
+            return ~jnp.logical_and(s[0] >= n_res - 1, res_done(s))
 
-        def body(s):
-            bits, vals, stalls, it, iters, trace = s
-            live = live_of(stalls, iters)                 # (R,)
-            nb, nv, improved = one_step(bits, vals, it)
+        def iterate(s):
+            (res_idx, bits, vals, best_vals, best_bits, best_res,
+             stalls, it_in_res, pos, trace) = s
+            live = live_of(stalls, it_in_res)            # (R,)
+            nb, nv, improved = one_step(bits, vals, it_in_res, res_idx)
             with tracing.scope("select"):
                 bits = jnp.where(live[:, None], nb, bits)
                 vals = jnp.where(live, nv, vals)
-                iters = iters + live.astype(jnp.int32)
             with tracing.scope("trace"):
-                trace = trace.at[:, it + 1].set(
-                    jnp.where(live, vals, trace[:, it]))
+                pos = pos + live.astype(jnp.int32)
+                trace = trace.at[rows, jnp.clip(pos, 0, t_max - 1)].set(
+                    vals)
             with tracing.scope("select"):
                 stalls = jnp.where(live & improved, 0,
                                    stalls + live.astype(jnp.int32))
-            return bits, vals, stalls, it + 1, iters, trace
+                better = vals < best_vals
+                best_vals = jnp.where(better, vals, best_vals)
+                best_bits = jnp.where(better[:, None], bits, best_bits)
+                best_res = jnp.where(better, res_idx, best_res)
+            return (res_idx, bits, vals, best_vals, best_bits,
+                    best_res, stalls, it_in_res + 1, pos, trace)
+
+        def escalate(s):
+            (res_idx, bits, vals, best_vals, best_bits, best_res,
+             stalls, it_in_res, pos, trace) = s
+            with tracing.scope("escalate"):
+                nxt = jnp.minimum(res_idx + 1, n_res - 1)
+                bits2 = tables.reencode(bits, res_idx, nxt)  # step 5
+                vals2 = f_batch(tables.decode(bits2, nxt)).astype(
+                    jnp.float32)
+                better = vals2 < best_vals
+                best_vals = jnp.where(better, vals2, best_vals)
+                best_bits = jnp.where(better[:, None], bits2, best_bits)
+                best_res = jnp.where(better, nxt, best_res)
+                return (nxt, bits2, vals2, best_vals, best_bits,
+                        best_res, jnp.zeros_like(stalls), jnp.int32(0),
+                        pos, trace)
+
+        def body(s):
+            return jax.lax.cond(res_done(s), escalate, iterate, s)
 
         with tracing.scope("trace"):
-            trace0 = jnp.tile(vals0[:, None], (1, max_iters + 1))
-        s0 = (bits0, vals0,
+            trace0 = jnp.tile(vals0[:, None], (1, t_max))
+        s0 = (jnp.int32(0), bits0, vals0, vals0, bits0,
+              jnp.zeros((n_restarts,), jnp.int32),
               jnp.zeros((n_restarts,), jnp.int32), jnp.int32(0),
               jnp.zeros((n_restarts,), jnp.int32), trace0)
-        bits, vals, _, _, iters, trace = jax.lax.while_loop(cond, body, s0)
+        s = jax.lax.while_loop(cond, body, s0)
+        (_, bits, vals, best_vals, best_bits, best_res, _, _, pos,
+         trace) = s
         with tracing.scope("trace"):
-            idx = jnp.arange(max_iters + 1)[None, :]
-            trace = jnp.where(idx <= iters[:, None], trace, vals[:, None])
-        return bits, vals, iters, trace
+            idx = jnp.arange(t_max)[None, :]
+            trace = jnp.where(idx <= pos[:, None], trace, vals[:, None])
+        return bits, vals, best_vals, best_bits, best_res, pos, trace
 
     replicated = P()
     mapped = shard_map(
-        shard_engine, mesh=mesh,
+        shard_schedule_engine, mesh=mesh,
         in_specs=(replicated,) * 4,
-        out_specs=(replicated,) * 4,
+        out_specs=(replicated,) * 7,
         check_vma=False)
     return jax.jit(_named(mapped, "dgo_wave_engine"))
 
@@ -1252,8 +715,8 @@ class BatchedResult(NamedTuple):
     iterations: np.ndarray  # (R,) i32 — population steps taken, per restart
     trace: np.ndarray      # (R, T) f32 — monotone value history per restart
     best: int              # index of the winning (active) restart
-    best_xs: np.ndarray | None = None   # (R, n_vars) — schedule path only:
-    #                       each restart's best point at its own resolution
+    best_xs: np.ndarray    # (R, n_vars) — each restart's best point at its
+    #                        own resolution
 
 
 def _prefetch(*arrs) -> None:
@@ -1338,14 +801,14 @@ def _submit_batched(f: Callable[[jax.Array], jax.Array],
                     active=None,
                     slot_iters=None) -> PendingBatched:
     """Batched multi-start distributed DGO: R restarts from ``x0s``
-    (R, n_vars) share one compiled on-device while_loop — including, when
-    ``res_bits`` names a schedule, every resolution escalation (the whole
-    batch and schedule is ONE dispatch).
+    (R, n_vars) share one compiled on-device while_loop — including every
+    escalation of the resolution schedule ``res_bits`` (the whole batch
+    and schedule is ONE dispatch).
 
     ``active`` (R,) bool marks padding slots (False = never stepped —
     a partially-filled serving wave reuses the full-width compilation);
     ``slot_iters`` (R,) i32 gives each slot its own iteration cap (per
-    resolution on the schedule path).  Both are call-time arrays: they do
+    resolution).  Both are call-time arrays: they do
     not enter the compile-cache key, so heterogeneous waves share one
     engine.  Defaults: all slots active, every cap = ``max_iters``.
 
@@ -1358,8 +821,7 @@ def _submit_batched(f: Callable[[jax.Array], jax.Array],
     itself, and ONE ``device_get`` of every output the results need.
     Everything between is numpy on the host.  Returns WITHOUT blocking:
     JAX dispatch is asynchronous, so the engine call hands back in-flight
-    device arrays and the fetch (plus the schedule path's history
-    post-processing) is deferred to ``PendingBatched.finish()``.
+    device arrays and the fetch (plus the history post-processing) is deferred to ``PendingBatched.finish()``.
     """
     x0s = np.asarray(x0s, np.float32)
     if x0s.ndim != 2:
@@ -1384,22 +846,6 @@ def _submit_batched(f: Callable[[jax.Array], jax.Array],
     # on one process jit transfers the host arrays itself
     with tracing.span("submit_wave.place"):
         args = _place_inputs(mesh, x0s, quorum_mask, active, slot_iters)
-
-    if len(schedule) == 1:
-        with tracing.span("submit_wave.engine"):
-            engine = _batched_engine_for(f, enc0, mesh,
-                                         n_restarts, pop_axes, max_iters,
-                                         virtual_block)
-            outs = engine(*args)                 # bits, vals, iters, trace
-            _prefetch(*outs)
-
-        def post(fetched) -> BatchedResult:
-            bits_h, vals_h, iters_h, trace_h = fetched
-            return BatchedResult(
-                bits=bits_h, values=vals_h, iterations=iters_h,
-                trace=trace_h[:, : int(iters_h.max()) + 1],
-                best=_best_active(vals_h, active))
-        return PendingBatched(lambda: jax.device_get(outs), post)
 
     with tracing.span("submit_wave.engine"):
         engine = _batched_engine_for(f, enc0, mesh,

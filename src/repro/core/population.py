@@ -76,8 +76,8 @@ def segment_patterns(n_bits: int) -> np.ndarray:
 
     Hence ``child = parent ^ segment_patterns(N)[c]`` — one XOR, no Gray
     round-trip, no per-child prefix scan. This is the loop-invariant form
-    the distributed engines hoist out of their on-device while_loop
-    (``core/distributed.py`` inner="fused"); ``generate_children`` remains
+    the engines stack per resolution (``schedule_tables``) and gather
+    inside their on-device while_loop; ``generate_children`` remains
     the literal three-step reference it is verified against.
     """
     n_bits = int(n_bits)

@@ -17,8 +17,8 @@ Strategies (string key -> class, see ``strategy_names()``):
   ``sequential``   one-child-at-a-time numpy loop (SPARC baseline)
   ``fused``        whole optimization in one jitted lax.while_loop
   ``clustered``    vmap of the fused engine over multi-starts (MP-1 cluster)
-  ``distributed``  shard_map population distribution over a mesh
-                   (``driver="device"`` one-dispatch loop, or ``"host"``)
+  ``distributed``  shard_map population distribution over a mesh, the
+                   whole loop in one dispatch
   ``batched``      R lockstep restarts in one compiled distributed loop
                    (the serving path)
 
@@ -50,7 +50,7 @@ from repro.compat import AxisType, make_mesh, pure_callback
 from repro.core import objectives as objectives_registry
 from repro.core.cache import get_cache
 from repro.core.dgo import DGOConfig
-from repro.core.encoding import Encoding, decode_np
+from repro.core.encoding import Encoding
 from repro.core.objectives import Objective
 
 __all__ = [
@@ -550,17 +550,15 @@ def resolve_mesh(mesh=None):
 @dataclasses.dataclass(frozen=True)
 class Distributed(Strategy):
     """Population distribution over a mesh (MP-1/NCUBE): the 2N-1
-    children are sharded over ``pop_axes``; ``driver="device"`` runs the
-    whole loop as one dispatch, ``driver="host"`` steps from Python so
-    failure injection / elastic policy can interpose.
+    children are sharded over ``pop_axes`` and the whole loop runs as one
+    dispatch.
 
-    Fixed-resolution by default; setting ``max_bits`` folds the paper's
-    step-5 escalation INTO the on-device while_loop (one compiled
-    dispatch for the whole schedule — ``driver="host"`` chains
-    resolutions from Python instead so policy can interpose).
+    Fixed-resolution by default (the one-entry schedule ``[enc.bits]``);
+    setting ``max_bits`` folds the paper's step-5 escalation INTO the
+    on-device while_loop (one compiled dispatch for the whole schedule).
 
-    A ``driver="device"`` solve is one engine call and one fetch, and no
-    other device program: the engine takes the start point as it is (the
+    A solve is one engine call and one fetch, and no other device
+    program: the engine takes the start point as it is (the
     full-quorum mask is placed on the mesh once), decodes the best point
     and cuts its bit string itself, and one ``device_get`` brings back
     every value the result needs.  ``best_x``, ``best_f`` and
@@ -575,15 +573,10 @@ class Distributed(Strategy):
     name: ClassVar[str] = "distributed"
     mesh: Any = None                  # None -> all devices on ("data",)
     pop_axes: tuple = ("data",)
-    driver: str = "device"
-    inner: str | None = None
     virtual_block: int = 256
-    interpret: bool | None = None
-    tile_p: int | None = None
     max_bits: int | None = None       # None -> fixed resolution
     bits_step: int = 2
     quorum_mask: Any = None
-    injector: Any = None
 
     def _solve(self, problem, *, key, x0, max_iters):
         from repro.core import distributed
@@ -593,16 +586,13 @@ class Distributed(Strategy):
         if x0 is None:
             x0 = problem.random_x0(key)
 
-        # the whole schedule goes down in one call: the device driver
-        # folds it into its single compiled while_loop, the host driver
-        # chains resolutions internally — no facade-level dispatch loop
+        # the whole schedule goes down in one call, folded into the
+        # engine's single compiled while_loop — no facade-level loop
         schedule = _resolution_schedule(enc0, self.max_bits, self.bits_step)
         run = distributed._run_distributed(
             problem.jax_fn, enc0, mesh, x0,
             pop_axes=tuple(self.pop_axes), max_iters=mi,
             virtual_block=self.virtual_block, quorum_mask=self.quorum_mask,
-            inner=self.inner, interpret=self.interpret, driver=self.driver,
-            injector=self.injector, tile_p=self.tile_p,
             res_bits=tuple(schedule))
         trace = np.minimum.accumulate(np.asarray(run.history, np.float32))
         return SolveResult(best_x=run.x, best_f=run.val,
@@ -626,7 +616,7 @@ class Batched(Strategy):
     escalates in lockstep), like :class:`Distributed`.
 
     extras: ``bits`` ((R, N) per-restart best points as final-resolution
-    strings — the engine's final parents on the fixed-resolution path),
+    strings),
     ``values`` ((R,) per-restart best), ``restart_iterations`` ((R,)),
     ``trace`` ((R, T) per-restart monotone histories), ``best`` (winner
     index), ``schedule``.
@@ -664,7 +654,7 @@ class Batched(Strategy):
             quorum_mask=self.quorum_mask, res_bits=tuple(schedule))
         winner = res.best
         return SolveResult(
-            best_x=jnp.asarray(_best_x(res, winner, enc0)),
+            best_x=jnp.asarray(res.best_xs[winner]),
             best_f=jnp.asarray(res.values[winner]),
             iterations=int(res.iterations.max()),
             trace=res.trace[winner],
@@ -847,14 +837,6 @@ def _request_x0(prob: Problem, req: SolveRequest) -> np.ndarray:
     return np.asarray(prob.random_x0(key, batch=1))[0]
 
 
-def _best_x(res, slot: int, enc0: Encoding) -> np.ndarray:
-    """A restart's best point: carried by the schedule path, decoded
-    from its final bits on the fixed-resolution path."""
-    if res.best_xs is not None:
-        return res.best_xs[slot]
-    return decode_np(res.bits[slot], enc0)
-
-
 def _slot_result(res, slot: int, enc0: Encoding, schedule: tuple,
                  wave_size: int) -> SolveResult:
     """Per-slot SolveResult assembly — the same post-processing
@@ -863,7 +845,7 @@ def _slot_result(res, slot: int, enc0: Encoding, schedule: tuple,
     field is a numpy slice of the wave's fetched arrays."""
     iters = int(res.iterations[slot])
     return SolveResult(
-        best_x=_best_x(res, slot, enc0),
+        best_x=res.best_xs[slot],
         best_f=res.values[slot],
         iterations=iters,
         trace=res.trace[slot][: iters + 1],

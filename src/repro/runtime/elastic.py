@@ -20,30 +20,9 @@ def remesh(n_devices: int, model_parallel: int = 1):
     """Largest (data, model) mesh over the surviving devices."""
     usable = (n_devices // model_parallel) * model_parallel
     devices = jax.devices()[:usable]
-    import numpy as np
     arr = np.array(devices).reshape(usable // model_parallel, model_parallel)
     return mesh_from_devices(arr, ("data", "model"),
                              axis_types=(AxisType.Auto, AxisType.Auto))
-
-
-def drop_shard(quorum_mask, victim: int | None = None):
-    """Remove one shard from a DGO quorum mask (lowest alive index by
-    default) — the elastic response to an injected/observed shard failure
-    in ``Distributed(driver="host")``: no re-mesh, no restart; the
-    survivors regenerate the lost children next round.
-
-    Raises ``RuntimeError`` when the drop would leave an empty quorum.
-    """
-    alive = np.asarray(quorum_mask).copy()
-    if victim is None:
-        if not alive.any():
-            raise RuntimeError("quorum already empty")
-        victim = int(np.argmax(alive))
-    alive[victim] = False
-    if not alive.any():
-        raise RuntimeError("dropping shard %d empties the quorum" % victim)
-    import jax.numpy as jnp
-    return jnp.asarray(alive)
 
 
 def elastic_population_plan(n_bits: int, n_shards: int) -> dict:

@@ -8,12 +8,10 @@ hardware; a 1000-node pod does not, so restart-from-checkpoint is the
 baseline fault-tolerance mechanism; DGO additionally tolerates losing
 children mid-iteration via the quorum reduce, core/distributed.py).
 
-For DGO the injector also plugs straight into the *host-stepped* driver:
-``Distributed(driver="host", injector=...)`` polls ``maybe_fail`` each
-round and answers an injected failure by shrinking the quorum
-(``runtime.elastic.drop_shard``) instead of restarting — the on-device
-``driver="device"`` loop cannot interpose host policy mid-run, which is
-exactly why the host path is retained.
+For DGO serving, the ``serving.Scheduler`` takes an injector too and
+polls it at each wave.  A single solve runs its whole loop on the
+device, where no host policy can interpose; shard loss there is the
+``quorum_mask`` that ``Distributed`` and ``Batched`` take.
 
 ``FaultPlan`` is the serving-layer substrate: a deterministic, seeded
 plan of dispatch exceptions, per-request poison, latency spikes and

@@ -4,7 +4,8 @@ DGO-specific: a round's reduce can proceed with any quorum of shards —
 children on missing shards are simply not considered this round and are
 regenerated deterministically next round (no state is lost because the
 population is a pure function of the parent string). The quorum mask is
-plumbed through core/distributed.make_distributed_step.
+plumbed through the ``quorum_mask`` of the engines in
+core/distributed.py.
 
 This module hosts the host-side policy: tracking per-shard completion
 times and deciding which shards to mask next round.

@@ -44,7 +44,6 @@ PUBLIC_API = [
     # engine builders (power users)
     "make_distributed_engine",
     "make_distributed_engine_batched",
-    "make_distributed_step",
     # subspace DGO (LM training path)
     "apply_subspace",
     "make_dgo_train_step",

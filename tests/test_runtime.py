@@ -58,26 +58,6 @@ def test_straggler_cooldown_expiry_restores_full_quorum():
     assert pol.quorum_fraction == 1.0           # exact, not approx
 
 
-def test_drop_shard_on_minimal_quorum():
-    """Dropping the last alive shard must refuse, not return an empty
-    quorum (an all-False mask would make the device reduce meaningless)."""
-    import pytest
-
-    from repro.runtime.elastic import drop_shard
-
-    mask = drop_shard(np.asarray([True, True, False, False]))
-    assert np.asarray(mask).tolist() == [False, True, False, False]
-    minimal = np.asarray([False, True, False, False])
-    with pytest.raises(RuntimeError, match="empties the quorum"):
-        drop_shard(minimal)
-    with pytest.raises(RuntimeError, match="empties the quorum"):
-        drop_shard(minimal, victim=1)
-    with pytest.raises(RuntimeError, match="quorum already empty"):
-        drop_shard(np.zeros(4, bool))
-    # the refused drops left the caller's mask untouched (copy semantics)
-    assert minimal.tolist() == [False, True, False, False]
-
-
 def test_elastic_plan_matches_paper_formula():
     plan = elastic_population_plan(n_bits=63, n_shards=64)
     assert plan["population"] == 125

@@ -26,10 +26,7 @@ def _strategies():
         ("sequential", Sequential(max_bits=MAX_BITS), False),
         ("fused", Fused(max_bits=MAX_BITS), False),
         ("clustered", Clustered(n_clusters=2, max_bits=MAX_BITS), True),
-        ("distributed-device", Distributed(max_bits=MAX_BITS,
-                                           driver="device"), False),
-        ("distributed-host", Distributed(max_bits=MAX_BITS,
-                                         driver="host"), False),
+        ("distributed", Distributed(max_bits=MAX_BITS), False),
         ("batched", Batched(max_bits=MAX_BITS), True),
     ]
 

@@ -80,17 +80,18 @@ def _replicated(mesh):
 
 
 def test_popstep_engine_compiles_for_one_v5e(topo):
-    """The fixed-resolution distributed engine with the kernel as its
-    inner step — what ``Distributed()`` runs on a TPU."""
+    """The fixed-resolution engine — the one-entry schedule ``(enc.bits,)``
+    that ``Distributed()`` runs on a TPU — compiles for one chip as an
+    XLA program, with no Pallas kernel in it."""
     obj = quadratic_nd(9)
     mesh = mesh_from_devices(np.array(topo.devices[:1]), ("data",))
     engine = distributed.make_distributed_engine(
-        jax.vmap(obj.fn), obj.encoding, mesh, inner="popstep",
-        interpret=False)
+        jax.vmap(obj.fn), obj.encoding, mesh)
     shape = _replicated(mesh)
     compiled = engine.lower(shape((9,), jnp.float32),
                             shape((1,), jnp.bool_)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    assert len(compiled.out_info) == 6
+    assert "tpu_custom_call" not in compiled.as_text()
 
 
 @pytest.mark.parametrize("n_chips", [1, 4])
